@@ -1,0 +1,57 @@
+"""The one place that decides "is this the chip", and says so out loud.
+
+Every Pallas call site asks :func:`on_tpu` (Mosaic kernels compile only
+for the TPU; any other backend runs the kernel body in interpret mode or
+takes the lax fallback). A process that was ASKED for a device tier calls
+:func:`require_device` first: a machine without a chip is an error there,
+never a quiet CPU run. :func:`configure_compile_cache` gives every
+process of a checkout the same persistent compilation cache — the path
+is part of the cache key, so it is fixed, never temporary.
+
+jax imports are deferred: tools that never touch the device can import
+this module for free.
+"""
+
+from __future__ import annotations
+
+import os
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+COMPILE_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def on_tpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def require_device() -> tuple[str, int, str]:
+    """(platform, count, device_kind) of ``jax.devices()``. Raises unless
+    the platform is ``tpu`` or ``JAX_PLATFORMS`` explicitly names ``cpu``
+    (tests and the tools/check_*.py CPU gates set it; nothing else may)."""
+    import jax
+
+    devs = jax.devices()
+    platform, kind = devs[0].platform, devs[0].device_kind
+    named = os.environ.get("JAX_PLATFORMS", "").lower().split(",")
+    if platform != "tpu" and "cpu" not in [p.strip() for p in named]:
+        raise RuntimeError(
+            f"a device tier was requested but jax found platform "
+            f"{platform!r} ({len(devs)} x {kind}); run on a TPU, or set "
+            "JAX_PLATFORMS=cpu to choose the CPU explicitly"
+        )
+    return platform, len(devs), kind
+
+
+def configure_compile_cache() -> str:
+    """Persistent compilation cache: ``JAX_COMPILATION_CACHE_DIR`` wins
+    (jax reads it itself; nothing is touched here); otherwise
+    ``<checkout>/.jax_cache``. Returns the directory in effect."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR

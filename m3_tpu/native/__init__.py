@@ -57,8 +57,14 @@ def _cpu_signature() -> str:
 
 
 def _build() -> bool:
+    """Compile native/m3tsz.cc and move the result into place atomically:
+    g++ writes a private temporary name and ``os.replace`` publishes it
+    (then the .buildinfo), so a concurrent loader — xdist workers, or a
+    dbnode and a coordinator starting on a fresh checkout — sees the old
+    file, no file, or a whole one, never a half-written one."""
     if not os.path.exists(_SRC_PATH):
         return False
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             [
@@ -69,18 +75,48 @@ def _build() -> bool:
                 "-fPIC",
                 "-std=c++17",
                 "-o",
-                _LIB_PATH,
+                tmp,
                 _SRC_PATH,
                 "-lpthread",
             ],
             check=True,
             capture_output=True,
         )
-        with open(_LIB_PATH + ".buildinfo", "w") as f:
+        os.replace(tmp, _LIB_PATH)
+        with open(tmp, "w") as f:
             f.write(_cpu_signature())
+        os.replace(tmp, _LIB_PATH + ".buildinfo")
         return True
     except (subprocess.CalledProcessError, FileNotFoundError, OSError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
+def _needs_build() -> bool:
+    if not os.path.exists(_LIB_PATH):
+        return True
+    if (
+        os.path.exists(_SRC_PATH)
+        and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_LIB_PATH)
+    ):
+        return True
+    # a -march=native .so copied from a wider-ISA host would SIGILL
+    # (uncatchably) on first call: rebuild unless the recorded CPU
+    # signature matches this host
+    try:
+        with open(_LIB_PATH + ".buildinfo") as f:
+            return f.read() != _cpu_signature()
+    except OSError:
+        return True
+
+
+def _open():
+    try:
+        return ctypes.CDLL(_LIB_PATH)
+    except OSError:
+        return None
 
 
 def load():
@@ -88,26 +124,17 @@ def load():
     global _lib
     if _lib is not None:
         return _lib
-    stale = (
-        os.path.exists(_LIB_PATH)
-        and os.path.exists(_SRC_PATH)
-        and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_LIB_PATH)
-    )
-    if os.path.exists(_LIB_PATH) and not stale:
-        # a -march=native .so copied from a wider-ISA host would SIGILL
-        # (uncatchably) on first call: rebuild unless the recorded CPU
-        # signature matches this host
-        try:
-            with open(_LIB_PATH + ".buildinfo") as f:
-                stale = f.read() != _cpu_signature()
-        except OSError:
-            stale = True
-    if (not os.path.exists(_LIB_PATH) or stale) and not _build():
-        return None
-    try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        return None
+    lib = None
+    if not _needs_build():
+        lib = _open()
+    if lib is None:
+        # missing, stale, or an existing file that would not load (left
+        # half-written by a build that was not atomic): build, then retry
+        if not _build():
+            return None
+        lib = _open()
+        if lib is None:
+            return None
     lib.m3tsz_encode_batch.restype = ctypes.c_int64
     lib.m3tsz_encode_series.restype = ctypes.c_int64
     lib.m3tsz_prescan.restype = ctypes.c_int32

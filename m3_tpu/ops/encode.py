@@ -342,7 +342,7 @@ class EncodeResult(NamedTuple):
     resident pool admits it without re-upload); everything else is
     small host metadata."""
 
-    words: object  # device uint32[M, W]
+    words: object  # device uint32[M_pad, W]; rows past M are padding lanes
     total_bits: np.ndarray  # int64[M], EOS included
     nbytes: np.ndarray  # int64[M] finalized stream length
     chunk_offs: np.ndarray  # int64[Cmax, M] bit offset at each chunk start
@@ -358,7 +358,8 @@ class EncodeResult(NamedTuple):
         the admission hot path."""
         host = np.asarray(self.words).astype(">u4")
         return [
-            host[m].tobytes()[: int(self.nbytes[m])] for m in range(host.shape[0])
+            host[m].tobytes()[: int(self.nbytes[m])]
+            for m in range(len(self.nbytes))
         ]
 
 
@@ -378,20 +379,25 @@ def encode_lanes(
     kinds = np.asarray(kinds, np.int8)
     counts = np.asarray([len(t) for t, _ in lanes], np.int32)
     T = int(counts.max())
-    # pad T to buckets so the jit cache stays small
+    # pad T and M to pow2 buckets so the jit cache stays small: every
+    # shard seals a different lane count, and one compile of this program
+    # for the TPU is ~25 s. Padding lanes are all-invalid and sliced off.
     T_pad = max(8, 1 << int(np.ceil(np.log2(T))))
+    M_pad = max(8, 1 << int(np.ceil(np.log2(M))))
     W = words_bound(T_pad, round_words_to)
 
-    t0 = np.zeros(M, np.uint64)
-    dod = np.zeros((T_pad, M), np.int32)
-    valid = np.zeros((T_pad, M), bool)
-    absval = np.zeros((T_pad, M), np.uint32)
-    negbit = np.zeros((T_pad, M), np.uint32)
-    int_repeat = np.zeros((T_pad, M), bool)
-    vb_hi = np.zeros((T_pad, M), np.uint32)
-    vb_lo = np.zeros((T_pad, M), np.uint32)
-    pxr_hi = np.zeros((T_pad, M), np.uint32)
-    pxr_lo = np.zeros((T_pad, M), np.uint32)
+    t0 = np.zeros(M_pad, np.uint64)
+    dod = np.zeros((T_pad, M_pad), np.int32)
+    valid = np.zeros((T_pad, M_pad), bool)
+    absval = np.zeros((T_pad, M_pad), np.uint32)
+    negbit = np.zeros((T_pad, M_pad), np.uint32)
+    int_repeat = np.zeros((T_pad, M_pad), bool)
+    vb_hi = np.zeros((T_pad, M_pad), np.uint32)
+    vb_lo = np.zeros((T_pad, M_pad), np.uint32)
+    pxr_hi = np.zeros((T_pad, M_pad), np.uint32)
+    pxr_lo = np.zeros((T_pad, M_pad), np.uint32)
+    float_lane = np.zeros(M_pad, bool)
+    float_lane[:M] = kinds == KIND_FLOAT
 
     for m, (t, v) in enumerate(lanes):
         t = np.asarray(t, np.int64)
@@ -433,17 +439,17 @@ def encode_lanes(
     words, total_bits, chunk_offs, chunk_sigs = kern(
         (t0 >> np.uint64(32)).astype(np.uint32),
         (t0 & np.uint64(0xFFFFFFFF)).astype(np.uint32),
-        dod, valid, kinds == KIND_FLOAT,
+        dod, valid, float_lane,
         absval, negbit, int_repeat,
         vb_hi, vb_lo, pxr_hi, pxr_lo,
     )
-    total_bits = np.asarray(total_bits, np.int64)
+    total_bits = np.asarray(total_bits, np.int64)[:M]
     return EncodeResult(
         words=words,
         total_bits=total_bits,
         nbytes=(total_bits + 7) // 8,
-        chunk_offs=np.asarray(chunk_offs, np.int64),
-        chunk_sigs=np.asarray(chunk_sigs, np.int32),
+        chunk_offs=np.asarray(chunk_offs, np.int64)[:, :M],
+        chunk_sigs=np.asarray(chunk_sigs, np.int32)[:, :M],
         n_chunks=((counts + k - 1) // k).astype(np.int32),
         kinds=kinds,
         counts=counts,

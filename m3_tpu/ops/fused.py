@@ -512,13 +512,6 @@ def pack_lane_inputs(batch, order: str = "c", rows: int = ROWS_DEFAULT) -> Packe
     )
 
 
-def _compiler_params(pltpu):
-    """Mosaic compiler params across pallas API generations: the class was
-    TPUCompilerParams before jax 0.6 renamed it CompilerParams."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=("arbitrary",))
-
-
 def _pallas_kernel_packed(
     k, cw, int_optimized, unroll, specialize, flag_ref, win_ref, lane_ref, out_ref
 ):
@@ -646,7 +639,9 @@ def lane_aggregates_packed(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tiles, 6, rows, 128), F32),
-        compiler_params=_compiler_params(pltpu),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(tile_flags, windows4, lanes4)
     s_sum, s_cnt, s_min, s_max, s_last, s_err = (
@@ -766,7 +761,9 @@ def lane_aggregates_pallas(
         in_specs=[win_spec] + [lane_spec] * (len(args) - 1),
         out_specs=[lane_spec] * 6,
         out_shape=out_shape,
-        compiler_params=_compiler_params(pltpu),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(*args)
     s_sum, s_cnt, s_min, s_max, s_last, s_err = (o.reshape(npad)[:n] for o in outs)

@@ -236,9 +236,13 @@ def u32_to_f32(x):
 
 def to_f32(a):
     """Approximate signed 64-bit pair -> float32 (for on-device aggregation)."""
-    hi, lo = a
-    hi_signed = hi.astype(jnp.int32).astype(jnp.float32)
-    return hi_signed * jnp.float32(4294967296.0) + u32_to_f32(lo)
+    # sign-magnitude: converting the two's-complement halves directly
+    # cancels catastrophically for small negatives (-2 is -2^32 +
+    # (2^32 - 2), and f32 rounds the second term to 2^32: result 0)
+    negative = is_neg(a)
+    mh, ml = select(negative, neg(a), a)
+    mag = u32_to_f32(mh) * jnp.float32(4294967296.0) + u32_to_f32(ml)
+    return jnp.where(negative, -mag, mag)
 
 
 def f64_bits_to_f32(a):

@@ -18,27 +18,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 top-level API
-    from jax import shard_map as _shard_map
+from jax import shard_map
 
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental namespace, check_rep knob
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
+from .. import device
 from ..ops.chunked import ChunkedBatch, decode_chunked_lanes
 from ..ops.chunked import PROFILER as CHUNKED_PROF
 from ..ops.decode import decode_batched
 from ..utils.instrument import KernelProfiler
 from .mesh import SHARD_AXIS, series_mesh
 
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable shard_map: new jax calls it check_vma, old jax
-    check_rep — semantics (skip the replication check) are the same."""
-    kw = {_SHARD_MAP_CHECK_KW: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 # device-tier observability for the batched decode kernel: first-call
 # compile attribution (m3tpu_jit_compiles_total{kernel="m3tsz_decode"})
@@ -315,7 +303,7 @@ def chunked_scan_aggregate_fused(
     if backend == "auto":
         # Mosaic kernels are TPU-only; every other backend (cpu, gpu) takes
         # the lax.scan fallback rather than attempting a pltpu lowering.
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        backend = "pallas" if device.on_tpu() else "jnp"
     fn = fused.lane_aggregates_pallas if backend == "pallas" else fused.lane_aggregates_jnp
     if _is_tracing(lane_args["windows"]):
         lane_agg = fn(**lane_args, k=k)
@@ -495,9 +483,9 @@ def _resident_gather(pool_words, side_words, page_rows, side_rows,
     j = jnp.arange(cw, dtype=jnp.int32)[None, :]
     wabs = w0[:, None] + j  # [N, CW] absolute word index within the lane
     page = jnp.take(page_rows.reshape(-1), si[:, None] * lp + wabs // w)
-    words = jnp.take(
-        jnp.asarray(pool_words, jnp.uint32).reshape(-1), page * w + wabs % w
-    )
+    # 2-D index, NOT a take over the flattened pool: on the TPU the
+    # reshape to 1-D is a re-layout copy of the whole pool per program
+    words = jnp.asarray(pool_words, jnp.uint32)[page, wabs % w]
     windows = jnp.where(valid[:, None], words, jnp.uint32(0))
     return planes, windows, rel, nbits, valid
 
@@ -679,7 +667,7 @@ def resident_chunked_local_fn(c: int, k: int, cw: int, w: int, spc: int,
 
     from ..ops.fused import ROWS_DEFAULT
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = not device.on_tpu()
 
     def local(pool_words, side_words, page_rows, side_rows, n_chunks,
               total_bits, block_hi, block_lo):

@@ -22,6 +22,7 @@ from typing import Iterable, Iterator
 import jax
 import numpy as np
 
+from .. import device
 from ..ops import fused
 from .scan import chunked_scan_aggregate_packed
 
@@ -177,7 +178,7 @@ def stream_aggregate(
 def _jitted(n: int, s: int, c: int, k: int, lane_order: str = "c"):
     # Mosaic kernels are TPU-only; other backends run the kernel body in
     # Pallas interpret mode (same code path, no Mosaic lowering)
-    interpret = jax.default_backend() != "tpu"
+    interpret = not device.on_tpu()
     return jax.jit(
         functools.partial(
             chunked_scan_aggregate_packed, n=n, s=s, c=c, k=k,

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import device
 from ...utils.instrument import DEFAULT as METRICS
 from ...utils.instrument import KernelProfiler
 from . import temporal as T
@@ -64,13 +65,6 @@ FUSABLE = {
 BLOCK_ROWS = 64  # VMEM budget: ~30 live [64, T] f32 intermediates ≈ 5.5MB @ T=720
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
-
-
 @functools.partial(
     jax.jit, static_argnames=("funcs", "window", "step_seconds", "t_cols")
 )
@@ -99,7 +93,7 @@ def fused_temporal(values, window: int, step_seconds: float, funcs: tuple[str, .
     """Evaluate ``funcs`` over the same [S, T] range matrix in one fused
     kernel on TPU; plain per-function evaluation elsewhere. Returns a tuple
     of [S, T] arrays in ``funcs`` order."""
-    if not _on_tpu() or any(f not in FUSABLE for f in funcs):
+    if not device.on_tpu() or any(f not in FUSABLE for f in funcs):
         v = jnp.asarray(values, jnp.float32)
         return tuple(FUSABLE[f](v, window, step_seconds) for f in funcs)
     v = jnp.asarray(values, jnp.float32)
@@ -127,7 +121,7 @@ def temporal_apply(name: str, values, window: int, step_seconds: float):
     """Single-function entry used by the query engine: fused on TPU (the
     intermediates of even ONE rate call are ~25 HBM passes unfused),
     unfused elsewhere."""
-    if name in FUSABLE and _on_tpu() and values.shape[0] >= BLOCK_ROWS:
+    if name in FUSABLE and device.on_tpu() and values.shape[0] >= BLOCK_ROWS:
         return fused_temporal(values, window, step_seconds, (name,))[0]
     v = jnp.asarray(values, jnp.float32)
     return FUSABLE[name](v, window, step_seconds)

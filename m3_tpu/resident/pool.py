@@ -18,7 +18,8 @@ Layout:
   (the pages themselves need not be contiguous — the device gather
   reassembles them).
 - SIDE PLANES: a second paged device buffer
-  ``uint32[num_side_pages, side_page_chunks, N_SIDE_PLANES]`` holding the
+  ``uint32[num_side_pages * side_page_chunks, N_SIDE_PLANES]`` (one row
+  per chunk slot, side page p owning rows [p*spc, (p+1)*spc)) holding the
   per-CHUNK decoder-state side table (ops/chunked.py snapshot_stream:
   byte offset, prev_time/prev_delta/prev_float_bits/prev_xor/int_val
   carries, time unit, sig/mult, is_float, and the v2 fast-chunk
@@ -237,7 +238,7 @@ class ResidentPool:
             range(self.options.num_side_pages - 1, 0, -1)
         )
         self._words = None  # device uint32[num_pages, page_words], lazy
-        self._side = None  # device uint32[side_pages, spc, N_SIDE_PLANES], lazy
+        self._side = None  # device uint32[side_pages * spc, N_SIDE_PLANES], lazy
         self._resident_bytes = 0  # sum of entries' stream bytes
         # scan/admit epoch fence: scans hold a read lease across
         # plan+decode; an admission donates the buffers (true in-place)
@@ -356,9 +357,15 @@ class ResidentPool:
         if self._side is None:
             import jax.numpy as jnp
 
+            # one ROW per chunk slot (side page p owns rows [p*spc,
+            # (p+1)*spc)): the lane gather takes rows, and on the TPU a
+            # [pages, spc, 10] buffer had to be re-laid-out whole — a
+            # temp ~9x the side budget inside every scan program — before
+            # rows could be taken from it
             o = self.options
             self._side = jnp.zeros(
-                (o.num_side_pages, o.side_page_chunks, N_SIDE_PLANES), jnp.uint32
+                (o.num_side_pages * o.side_page_chunks, N_SIDE_PLANES),
+                jnp.uint32,
             )
         return self._side
 
@@ -902,10 +909,7 @@ class ResidentPool:
                     words, jax.device_put(indices), gathered, donate
                 )
             if side_rows:
-                staged, indices = self._stage(
-                    side_rows, side_idx,
-                    (self.options.side_page_chunks, N_SIDE_PLANES),
-                )
+                staged, indices = self._stage_side(side_rows, side_idx)
                 self.ingest_side_stage_bytes += staged.nbytes
                 self._m_side_stage.inc(staged.nbytes)
                 new_side = _scatter(side, jax.device_put(indices),
@@ -985,10 +989,7 @@ class ResidentPool:
                 new_words = _scatter(words, jax.device_put(indices),
                                      jax.device_put(staged), donate)
             if side_rows:
-                staged, indices = self._stage(
-                    side_rows, side_idx,
-                    (self.options.side_page_chunks, N_SIDE_PLANES),
-                )
+                staged, indices = self._stage_side(side_rows, side_idx)
                 # side-plane staging is host->device transfer like the
                 # data pages (~1:1 with stream bytes) — count it, or the
                 # upload accounting under-reports admission cost ~2x and
@@ -1020,6 +1021,14 @@ class ResidentPool:
         else:
             self.copy_admissions += 1
             self._m_copy.inc()
+
+    def _stage_side(self, side_rows: list, side_idx: list):
+        """Side pages -> (rows uint32[n_pad * spc, N_SIDE_PLANES], their
+        row indices) for the scatter into the row-major side buffer."""
+        spc = self.options.side_page_chunks
+        staged, pages = self._stage(side_rows, side_idx, (spc, N_SIDE_PLANES))
+        rows = pages[:, None] * spc + np.arange(spc, dtype=np.int32)[None, :]
+        return staged.reshape(-1, N_SIDE_PLANES), rows.reshape(-1)
 
     @staticmethod
     def _stage(rows: list, idx: list, row_shape: tuple):
@@ -1527,7 +1536,7 @@ class ResidentChunkedPlan(NamedTuple):
     metadata assemble ON DEVICE from ``words`` + ``side``."""
 
     words: object  # device uint32[num_pages, page_words]
-    side: object  # device uint32[num_side_pages, spc, N_SIDE_PLANES]
+    side: object  # device uint32[num_side_pages * spc, N_SIDE_PLANES]
     page_rows: np.ndarray  # int32[S, LP] incl. trailing zero-page columns
     side_rows: np.ndarray  # int32[S, SL] side-page index per slot
     n_chunks: np.ndarray  # int32[S]

@@ -23,6 +23,7 @@ import functools
 
 import numpy as np
 
+from .. import device
 from ..storage.fs import CHUNK_K
 from ..utils.instrument import DEFAULT as METRICS
 
@@ -132,7 +133,7 @@ def streamed_scan_totals(segments: list, k: int = CHUNK_K):
     aggs = chunked_scan_aggregate_packed(
         windows4, lanes4, tile_flags,
         n=packed.n, s=s_pad, c=batch.num_chunks, k=k,
-        lane_order=packed.order, interpret=jax.default_backend() != "tpu",
+        lane_order=packed.order, interpret=not device.on_tpu(),
     )
     return _slice_series(aggs, s)
 

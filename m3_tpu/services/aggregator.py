@@ -95,6 +95,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    from .. import device
+
+    device.configure_compile_cache()
     forward_node = None
     producer = None
     if args.forward:

@@ -1449,6 +1449,9 @@ def main(argv=None) -> int:
     )
     args = p.parse_args(argv)
 
+    from .. import device
+
+    device.configure_compile_cache()
     cfg = load_config(CoordinatorConfig, args.config) if args.config else CoordinatorConfig()
     host = args.host if args.host is not None else cfg.host
     port = args.port if args.port is not None else cfg.port

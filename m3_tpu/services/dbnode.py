@@ -8,7 +8,10 @@ mediator. Run:
         --node-id node0 --shards 0,1,2,3 --namespace default
 
 Prints ``LISTENING <host> <port>`` on stdout once serving (process managers
-and the multi-process test fixture wait for it).
+and the multi-process test fixture wait for it). With a device tier on
+(``--resident-bytes``, ``--index-device-bytes``, ``--device-ingest``) the
+line before it is ``DEVICE <platform> <count> <kind>``, and a machine
+without a TPU is a start-up error unless ``JAX_PLATFORMS`` names ``cpu``.
 """
 
 from __future__ import annotations
@@ -215,6 +218,15 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+
+    from .. import device
+
+    device.configure_compile_cache()
+    device_marker = None
+    if args.resident_bytes > 0 or args.index_device_bytes > 0 or args.device_ingest:
+        # a device tier on a machine with no chip is an error, not a
+        # host TSDB that quietly carries on (raises -> non-zero exit)
+        device_marker = "DEVICE %s %d %s" % device.require_device()
 
     # embedded seed KV replica (server.go:266-324): starts SERVING first —
     # the quorum only forms once a majority of seeds are up, so everything
@@ -493,6 +505,8 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGTERM, shutdown)
     signal.signal(signal.SIGINT, shutdown)
 
+    if device_marker is not None:
+        print(device_marker, flush=True)
     print(f"LISTENING {server.host} {server.port}", flush=True)
     try:
         server.serve_forever()

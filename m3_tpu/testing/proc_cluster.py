@@ -35,37 +35,72 @@ from ..net.client import RemoteNode
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
 
 
-def _spawn_listening(cmd: list[str], what: str, timeout: float = 60.0,
+def stderr_tail(path: str, n_bytes: int = 4000) -> str:
+    try:
+        with open(path, "rb") as f:
+            f.seek(0, os.SEEK_END)
+            f.seek(max(f.tell() - n_bytes, 0))
+            return f.read().decode("utf-8", "replace")
+    except OSError as exc:
+        return f"<unreadable: {exc}>"
+
+
+def _spawn_listening(cmd: list[str], what: str, timeout: float = 120.0,
                      collect: dict | None = None,
                      expect_markers: set[str] | None = None,
-                     env_extra: dict | None = None):
+                     env_extra: dict | None = None,
+                     stderr_path: str | None = None):
     """Start a subprocess that prints LISTENING <host> <port>; returns
     (proc, host, port). Named marker lines (``expect_markers``, e.g.
     {"MSG_LISTENING"}) printed before/after it are collected into
     ``collect`` as (host, port), read from the same pump (reading
-    proc.stdout directly would race the pump thread that owns the pipe)."""
+    proc.stdout directly would race the pump thread that owns the pipe).
+    A ``DEVICE <platform> <count> <kind>`` marker (a dbnode with a device
+    tier on) is collected as ``collect["DEVICE"] = (platform, count,
+    kind)`` whenever ``collect`` is given.
+
+    The child's JAX platform is the CALLER's choice: it inherits this
+    process's environment (tests and the CPU gates export
+    ``JAX_PLATFORMS=cpu``) overlaid with ``env_extra`` — nothing here
+    defaults a child onto the CPU. Its stderr goes to ``stderr_path``
+    (default ``<tmp>/m3tpu-<what>-<pid>.stderr``, kept for the
+    post-mortem) and the tail is quoted when it dies at start-up."""
     expect_markers = expect_markers or set()
 
     def _maybe_collect(parts) -> None:
-        if (
-            collect is not None
-            and len(parts) == 3
+        if collect is None or not parts:
+            return
+        if parts[0] == "DEVICE" and len(parts) >= 4 and parts[2].isdigit():
+            collect["DEVICE"] = (parts[1], int(parts[2]), " ".join(parts[3:]))
+        elif (
+            len(parts) == 3
             and parts[0] in expect_markers
             and parts[2].isdigit()
         ):
             collect[parts[0]] = (parts[1], int(parts[2]))
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     if env_extra:
         env.update(env_extra)
-    proc = subprocess.Popen(
-        cmd,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.DEVNULL,
-        text=True,
-        env=env,
-        cwd=_REPO_ROOT,
-    )
+    if stderr_path is None:
+        stderr_path = os.path.join(
+            tempfile.gettempdir(), f"m3tpu-{what}-{os.getpid()}.stderr"
+        )
+    with open(stderr_path, "ab") as err_f:
+        proc = subprocess.Popen(
+            cmd,
+            stdout=subprocess.PIPE,
+            stderr=err_f,
+            text=True,
+            env=env,
+            cwd=_REPO_ROOT,
+        )
+    proc.stderr_path = stderr_path
+
+    def _died() -> RuntimeError:
+        return RuntimeError(
+            f"{what} died at startup; stderr tail ({stderr_path}):\n"
+            + stderr_tail(stderr_path)
+        )
     # a reader thread owns the (buffered) pipe; the main thread waits on a
     # queue with a deadline, so a child hanging before LISTENING (or a line
     # already sitting in the TextIOWrapper buffer, which select(2) on the
@@ -89,10 +124,10 @@ def _spawn_listening(cmd: list[str], what: str, timeout: float = 60.0,
             item = lines.get(timeout=min(remaining, 1.0))
         except _queue.Empty:
             if proc.poll() is not None:
-                raise RuntimeError(f"{what} died at startup")
+                raise _died()
             continue
         if item is None:
-            raise RuntimeError(f"{what} died at startup")
+            raise _died()
         line = item
         _maybe_collect(line.split())
         if line.startswith("LISTENING"):
@@ -117,6 +152,9 @@ class ProcNode:
     node_id: str
     proc: subprocess.Popen
     client: RemoteNode
+    # (platform, count, kind) from the child's DEVICE marker; None when
+    # the node runs no device tier
+    device: tuple | None = None
 
     @property
     def endpoint(self) -> str:
@@ -274,7 +312,10 @@ class ProcCluster:
                 )
                 kh, kp = collect["KV_LISTENING"]
                 kv_members[f"kv-{nid}"] = f"{kh}:{kp}"
-                self.nodes[nid] = ProcNode(nid, proc, RemoteNode(host, port, node_id=nid))
+                self.nodes[nid] = ProcNode(
+                    nid, proc, RemoteNode(host, port, node_id=nid),
+                    device=collect.get("DEVICE"),
+                )
             for ep in kv_members.values():
                 c = RpcClient.connect(ep)
                 c._call("raft_configure", members=kv_members)
@@ -339,12 +380,13 @@ class ProcCluster:
             "--no-mediator",
             *self.extra_args,
         ]
+        collect: dict = {}
         proc, host, port_n = _spawn_listening(
-            cmd, node_id,
+            cmd, node_id, collect=collect,
             env_extra={**self.extra_env, **self.node_env.get(node_id, {})},
         )
         client = RemoteNode(host, port_n, node_id=node_id)
-        return ProcNode(node_id, proc, client)
+        return ProcNode(node_id, proc, client, device=collect.get("DEVICE"))
 
     def spawn_spare(self, node_id: str) -> ProcNode:
         """A node process that advertises + heartbeats but owns no shards

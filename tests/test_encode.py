@@ -194,7 +194,12 @@ def test_admit_block_device_bit_identical_zero_upload():
     ).complete
 
     wh, wd = np.asarray(p_host._words), np.asarray(p_dev._words)
-    sh, sd = np.asarray(p_host._side), np.asarray(p_dev._side)
+    # the side buffer holds one row per chunk slot: view it page-major
+    spc = p_host.options.side_page_chunks
+    sh, sd = (
+        np.asarray(p._side).reshape(-1, spc, p._side.shape[-1])
+        for p in (p_host, p_dev)
+    )
     for i in range(9):
         k = BlockKey("ns", 0, bytes([i]), BS, 1)
         eh, ed = p_host.get(k), p_dev.get(k)
@@ -250,7 +255,12 @@ def test_admit_block_device_mixed_host_fallback_riders():
     )
     assert r.complete and r.admitted == 6
     wh, wd = np.asarray(p_host._words), np.asarray(p_dev._words)
-    sh, sd = np.asarray(p_host._side), np.asarray(p_dev._side)
+    # the side buffer holds one row per chunk slot: view it page-major
+    spc = p_host.options.side_page_chunks
+    sh, sd = (
+        np.asarray(p._side).reshape(-1, spc, p._side.shape[-1])
+        for p in (p_host, p_dev)
+    )
     for i in range(6):
         k = BlockKey("ns", 0, bytes([i]), BS, 1)
         eh, ed = p_host.get(k), p_dev.get(k)

@@ -127,7 +127,7 @@ def test_murmur3_shard_routing_matches_reference_vectors():
 def test_psum_rides_shard_axis():
     """A bare shard_map psum over the mesh equals the global sum — the
     primitive the cross-series totals rely on."""
-    from m3_tpu.parallel.scan import shard_map  # version-portable shim
+    from jax import shard_map
 
     mesh = series_mesh(N_DEV)
     x = jnp.arange(64, dtype=jnp.float32)

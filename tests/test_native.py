@@ -201,3 +201,77 @@ def test_shard_batch_matches_python_hash():
             pytest.skip("native lib unavailable")
         for sid, got in zip(ids, out.tolist()):
             assert got == shard_for(sid, num_shards), (sid, num_shards)
+
+
+def _scratch_native_tree(tmp_path):
+    """A tree holding only what git commits of the native codec: the
+    loader module and native/m3tsz.cc (no .so), so a test can race or
+    break the build without touching the library other workers use."""
+    import os
+    import shutil
+
+    import m3_tpu.native as real
+
+    pkg = tmp_path / "m3_tpu" / "native"
+    pkg.mkdir(parents=True)
+    shutil.copy(real.__file__, pkg / "__init__.py")
+    (tmp_path / "native").mkdir()
+    shutil.copy(real._SRC_PATH, tmp_path / "native" / "m3tsz.cc")
+    return str(pkg / "__init__.py"), str(tmp_path / "native")
+
+
+_LOAD_SNIPPET = """
+import importlib.util, sys
+spec = importlib.util.spec_from_file_location("scratch_native", sys.argv[1])
+mod = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(mod)
+lib = mod.load()
+print("LOADED" if lib is not None and lib.m3tsz_prescan is not None else "NONE")
+"""
+
+
+def test_concurrent_first_load_both_get_a_library(tmp_path):
+    """Two processes racing load() on a tree without the .so (xdist
+    workers on a fresh checkout; chip_smoke.py's dbnode + coordinator):
+    the build publishes by os.replace, so neither sees a half-written
+    file and both end up with a library."""
+    import os
+    import subprocess
+    import sys
+
+    mod_path, native_dir = _scratch_native_tree(tmp_path)
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", _LOAD_SNIPPET, mod_path],
+            stdout=subprocess.PIPE, text=True,
+        )
+        for _ in range(3)
+    ]
+    outs = [p.communicate(timeout=180)[0].strip() for p in procs]
+    assert outs == ["LOADED"] * 3, outs
+    left = sorted(os.listdir(native_dir))
+    assert left == ["libm3tsz.so", "libm3tsz.so.buildinfo", "m3tsz.cc"], left
+
+
+def test_unloadable_existing_library_is_rebuilt(tmp_path):
+    """An existing file that will not dlopen (what a non-atomic build left
+    behind) is rebuilt and loaded, not answered with None for the life of
+    the process."""
+    import os
+    import subprocess
+    import sys
+
+    mod_path, native_dir = _scratch_native_tree(tmp_path)
+    lib_path = os.path.join(native_dir, "libm3tsz.so")
+    with open(lib_path, "wb") as f:
+        f.write(b"\x7fELF-half-written")
+    import m3_tpu.native as real
+
+    with open(lib_path + ".buildinfo", "w") as f:
+        f.write(real._cpu_signature())
+    out = subprocess.run(
+        [sys.executable, "-c", _LOAD_SNIPPET, mod_path],
+        capture_output=True, text=True, timeout=180,
+    )
+    assert out.stdout.strip() == "LOADED", (out.stdout, out.stderr)
+    assert os.path.getsize(lib_path) > 10_000

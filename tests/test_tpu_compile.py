@@ -1,0 +1,204 @@
+"""Ask the chip's compiler before the chip.
+
+Every program of the served path is lowered from SHAPES and compiled by
+the installed TPU compiler for one described (not attached) v5e device,
+with ``interpret=False`` — what Mosaic or XLA:TPU would refuse on the
+machine with the chip (fast-memory limit, unaligned slice, an unsupported
+op in a 64-bit-as-u32-pair path, a program that does not fit the device's
+memory) is refused here, at no chip time. Nothing runs: a compile that
+passes says nothing about results or times.
+
+The topology is described inside a module-scoped fixture, never at import
+(on-chip-measurement guide, section 2): only the xdist worker that is
+handed this file loads the TPU library, and every compile happens in this
+process. Keep these tests in this ONE file.
+
+The one-dispatch plan program (query/plan.py) lowers from shapes without
+a live pool: ``_build_program(ast, dims)`` closes over static dimensions
+only and takes every runtime value as an argument, so the test hands it a
+hand-built AST shape tree and ShapeDtypeStructs.
+
+Code that decides "is this the chip" from ``jax.default_backend()`` sees
+the CPU here, so the resident body is steered by monkeypatching
+``m3_tpu.device.on_tpu`` — in the test, not through an option.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from m3_tpu.query.functions.temporal_fused import FUSABLE
+
+U32, I32 = jnp.uint32, jnp.int32
+
+# the served decode shape: storage/fs.CHUNK_K records per chunk, a 720-point
+# int block's window width, ops/fused.ROWS_DEFAULT rows per grid program
+CHUNK_K = 32
+WINDOW_WORDS = 29
+CHUNKS = 23  # ceil(720 / 32)
+RESIDENT_BYTES = 1 << 30
+# a TSBS-shaped 65,536-doc index segment: 11 fields, one posting per field
+# per doc, ~6.5k hostnames + the small fields' terms, keys up to 20 bytes
+N_DOCS = 65_536
+N_TERMS = 6_800
+KEY_WORDS = 5
+N_FIELDS = 11
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory pinned to one described v5e device. The
+    persistent compile cache is off around these compiles: an entry written
+    for a described device cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _pool_shapes(sds):
+    from m3_tpu.resident.pool import N_SIDE_PLANES, ResidentOptions
+
+    o = ResidentOptions(max_bytes=RESIDENT_BYTES)
+    return o, (
+        sds((o.num_pages, o.page_words), U32),
+        sds((o.num_side_pages * o.side_page_chunks, N_SIDE_PLANES), U32),
+    )
+
+
+def _plan_rows(sds, o, rows: int):
+    # page rows: one data page + the trailing zero-page columns a window
+    # may read into; side rows: ceil(CHUNKS / side_page_chunks)
+    lp = 1 + -(-WINDOW_WORDS // o.page_words) + 1
+    sl = -(-CHUNKS // o.side_page_chunks)
+    return lp, sl, (
+        sds((rows, lp), I32), sds((rows, sl), I32), sds((rows,), I32),
+        sds((rows,), I32), sds((rows,), U32), sds((rows,), U32),
+    )
+
+
+def test_lane_aggregates_packed_served_shape(sds):
+    from m3_tpu.ops import fused
+
+    tiles = 64
+    compiled = fused.lane_aggregates_packed.lower(
+        sds((tiles, WINDOW_WORDS, fused.ROWS_DEFAULT, 128), U32),
+        sds((tiles, fused.NLANE, fused.ROWS_DEFAULT, 128), U32),
+        sds((tiles,), I32),
+        n=tiles * fused.ROWS_DEFAULT * 128, k=CHUNK_K, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", sorted(FUSABLE))
+def test_fused_temporal_kernel(sds, name):
+    from m3_tpu.query.functions.temporal_fused import _fused_call
+
+    compiled = _fused_call.lower(
+        sds((1024, 720), jnp.float32), funcs=(name,), window=7,
+        step_seconds=10.0, t_cols=720,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_resident_assemble_and_decode(sds, monkeypatch):
+    """parallel/scan.resident_chunked_local_fn — the whole warm-scan
+    program (pool + side-plane gathers fused with the packed kernel) at the
+    buffers a 1 GiB --resident-bytes allocates. The temp bound is the
+    regression guard for the side-plane layout: a [pages, spc, 10] side
+    buffer cost a 9.8 GB re-layout temp inside this program."""
+    from m3_tpu import device
+    from m3_tpu.parallel.scan import resident_chunked_local_fn
+
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    o, pool = _pool_shapes(sds)
+    s = 1024
+    _, _, rows = _plan_rows(sds, o, s)
+    fn = jax.jit(resident_chunked_local_fn(
+        CHUNKS, CHUNK_K, WINDOW_WORDS, o.page_words, o.side_page_chunks))
+    compiled = fn.lower(*pool, *rows).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < RESIDENT_BYTES // 2
+
+
+def test_batched_encode_kernel(sds):
+    """ops/encode.py at the default ingest plane (--ingest-lanes 1024
+    --ingest-slots 1024), words rounded to the pool's page size."""
+    from m3_tpu.ops import encode
+
+    t = m = 1024
+    kern = encode._build_kernel(t, encode.words_bound(t, 512), encode.CHUNK_K_DEFAULT)
+    lane, plane = sds((m,), U32), sds((t, m), U32)
+    flag = sds((t, m), jnp.bool_)
+    kern.lower(
+        lane, lane, sds((t, m), I32), flag, sds((m,), jnp.bool_),
+        plane, plane, flag, plane, plane, plane, plane,
+    ).compile()
+
+
+def test_device_index_kernels(sds):
+    from m3_tpu.index.device import kernels
+
+    n_words = N_DOCS // 32
+    slab = N_DOCS  # one field's postings, already a power of two
+    post_idx = sds((N_TERMS, 2), I32)
+    post_data = sds((N_FIELDS * N_DOCS + slab,), I32)
+    scalar, batch = sds((), I32), sds((8,), I32)
+    jax.jit(kernels.match_terms_traced).lower(
+        sds((N_TERMS, KEY_WORDS), U32), sds((N_TERMS,), I32), batch, batch,
+        sds((8, KEY_WORDS), U32), batch,
+    ).compile()
+    jax.jit(kernels.bitmap_from_terms_traced, static_argnums=(4, 5)).lower(
+        post_idx, post_data, batch, scalar, n_words, slab,
+    ).compile()
+    jax.jit(kernels.bitmap_from_term_range_traced, static_argnums=(5, 6)).lower(
+        post_idx, post_data, scalar, scalar, scalar, n_words, slab,
+    ).compile()
+
+
+def test_one_dispatch_plan_program(sds):
+    """query/plan._build_program for ``metric{tag="v"}`` (two exact leaves
+    ANDed) over a 16,384-doc segment, one block, a 128-step grid."""
+    from m3_tpu.query import plan
+
+    o, pool = _pool_shapes(sds)
+    n_docs = 16_384
+    n_words = n_docs // 32
+    slab = n_docs
+    ast = ("and", (("terms", 0, 1, 0, slab), ("terms", 1, 1, slab, slab)), ())
+    lp, sl, tables = _plan_rows(sds, o, n_docs + 1)
+    t_grid = 128
+    dims = (n_words, n_docs, n_docs, 1, CHUNKS, CHUNK_K, WINDOW_WORDS, lp, sl,
+            o.page_words, o.side_page_chunks, t_grid)
+    pair = (sds((), U32), sds((), U32))
+    leaves = sds((2,), I32)
+    compiled = plan._build_program(ast, dims).lower(
+        sds((N_TERMS, KEY_WORDS), U32), sds((N_TERMS,), I32),
+        sds((N_TERMS, 2), I32), sds((N_FIELDS * n_docs + slab,), I32),
+        sds((n_words,), U32),
+        sds((2, KEY_WORDS), U32), leaves, leaves, leaves,
+        sds((1,), I32), sds((1,), I32),
+        *pool, *tables,
+        sds((t_grid,), U32), sds((t_grid,), U32), pair, pair, pair,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12 << 30

@@ -107,3 +107,17 @@ def test_f64_bits_to_f32():
             assert g == 0
         else:
             assert abs(g - np.float32(v)) <= abs(np.float32(v)) * 1e-6 or g == np.float32(v)
+
+
+@pytest.mark.parametrize(
+    "v", [0, 1, -1, -2, -7, -126, -381, 381, -(1 << 24), (1 << 40) + 5,
+          -((1 << 40) + 5), -(1 << 62)],
+)
+def test_to_f32_signed(v):
+    """Signed pair -> f32 is the correctly rounded value for BOTH signs:
+    small negatives used to cancel to 0 (-2) or lose their low byte
+    (-381 -> -256), which fed wrong int-mode values to every f32
+    aggregate."""
+    got = np.asarray(u64.to_f32(pair([v])))
+    assert got.dtype == np.float32
+    assert got[0] == np.float32(v), (v, got)
