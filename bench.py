@@ -43,7 +43,10 @@ import time
 NORTH_STAR = 10e9  # datapoints/sec/chip
 
 
-def main() -> None:
+def main() -> int:
+    from m3_tpu import device
+
+    device.configure_compile_cache()
     # BENCH_SELFMON=1: run the self-monitoring pipeline DURING the bench —
     # the collector stores this process's registry into a local reserved
     # namespace every BENCH_SELFMON_INTERVAL (default 10s) while the
@@ -53,28 +56,21 @@ def main() -> None:
     # env-var A/B away
     selfmon = maybe_start_selfmon()
     profiler = maybe_start_profiler()
-    # the storage warm-cache phase is independent of the device kernel
-    # phase: a kernel-phase failure (e.g. a jax version without the APIs
-    # the Pallas path needs) must not cost the warm-cache metric line
-    try:
-        kernel_phase()
-    except Exception as exc:
-        print(f"WARN kernel bench phase failed: {exc}", file=sys.stderr)
-    try:
-        bench_warm_cache()
-    except Exception as exc:
-        # the metrics snapshot below is purely in-process and must still
-        # print — a lost line 2 shouldn't also cost line 3
-        print(f"WARN warm-cache bench phase failed: {exc}", file=sys.stderr)
-    try:
-        bench_resident()
-    except Exception as exc:
-        print(f"WARN resident bench phase failed: {exc}", file=sys.stderr)
+    # the phases are independent, so one that fails does not cost the
+    # others their lines — but it does cost the run its exit code
+    failed = []
+    for phase in (kernel_phase, bench_warm_cache, bench_resident):
+        try:
+            phase()
+        except Exception as exc:
+            failed.append(phase.__name__)
+            print(f"FAIL bench phase {phase.__name__}: {exc!r}", file=sys.stderr)
     metrics_snapshot_line()
     if selfmon is not None:
         selfmon_overhead_line(selfmon)
     if profiler is not None:
         profile_overhead_line(profiler)
+    return 1 if failed else 0
 
 
 def maybe_start_selfmon():
@@ -508,4 +504,4 @@ def metrics_snapshot_line() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -56,6 +56,7 @@ INDEX_DEVICE_BYTES = 256 << 20
 COMMITLOG_SYNC = "interval"
 REHEARSAL_MAX_SCALE = 64  # a non-TPU platform may only rehearse this small
 DEFAULT_SCALE = 1000
+REPLICATED_SCALE = 16  # --chips 4: every sample rides the MAJORITY write path
 
 # TSBS cpu-only (timescale/tsbs cmd/tsbs_generate_data, use-case cpu-only):
 # 10 cpu fields per host, 10 host tags
@@ -269,6 +270,30 @@ def kernel_parity_child() -> None:
         np.asarray(got2.series_count), np.asarray(want.series_count))
     print(f"KERNEL_PARITY packed+fused lane aggregates k={CHUNK_K} ok", flush=True)
 
+    # is block_until_ready a barrier here? (parallel/stream.py relies on
+    # it.) Enqueue ~0.3 s of dependent device work; if the wait returns
+    # only when the work is done, the scalar fetch after it is immediate.
+    import jax.numpy as jnp
+
+    @jax.jit
+    def busy(x):
+        return jax.lax.fori_loop(0, 200, lambda _, a: jnp.tanh(a @ a) * 0.5, x)
+
+    x = jnp.ones((2048, 2048), jnp.float32)
+    jax.block_until_ready(busy(x))  # compile + warm
+    t0 = time.perf_counter()
+    y = busy(x)
+    t1 = time.perf_counter()
+    jax.block_until_ready(y)
+    t2 = time.perf_counter()
+    float(y[0, 0])
+    t3 = time.perf_counter()
+    print(f"BARRIER dispatch {1e3 * (t1 - t0):.1f}ms block_until_ready "
+          f"{1e3 * (t2 - t1):.1f}ms fetch-after {1e3 * (t3 - t2):.1f}ms",
+          flush=True)
+    assert (t3 - t2) < 0.5 * max(t2 - t1, 1e-3) + 0.05, (
+        "block_until_ready returned before the work finished")
+
     # the fused temporal kernel vs the unfused jnp graph (TOLERANCE.md,
     # round-5 additions: 1e-4 abs+rel, 5e-3 abs for stddev/stdvar)
     rng = np.random.default_rng(3)
@@ -477,9 +502,49 @@ def query_checks(node, http: str | None, hosts, vals) -> dict:
     return times
 
 
+def seal_and_check_admission(node, n_series: int, who: str = "") -> float:
+    t0 = time.perf_counter()
+    flushed = node.flush(NS, T0 + BLOCK_SECS * NANOS)
+    seal_s = time.perf_counter() - t0
+    say(f"{who}seal: {seal_s:.1f}s, {len(flushed)} filesets")
+    rs = node.resident_stats()
+    ix = node.index_stats()
+    say(f"{who}resident: entries {rs.get('entries')} admissions "
+        f"{rs.get('admissions')} device_admissions "
+        f"{rs.get('device_admissions')} upload_bytes "
+        f"{rs.get('upload_bytes')} bytes {rs.get('bytes')} rejections "
+        f"{rs.get('rejections')} evictions {rs.get('evictions')}")
+    say(f"{who}index: admissions {ix.get('admissions')} namespaces "
+        f"{ix.get('namespaces', {}).get(NS)}")
+    check(rs.get("entries") == n_series and rs.get("rejections") == 0
+          and rs.get("evictions") == 0,
+          f"{who}every sealed block admitted to the resident pool ({n_series})")
+    check(rs.get("device_admissions") == n_series,
+          f"{who}every block born resident (device-encoded, no stream upload)")
+    ixns = ix.get("namespaces", {}).get(NS, {})
+    check(ix.get("admissions", 0) >= 1
+          and ixns.get("device_resident_segments", 0) >= 1
+          and ixns.get("device_resident_segments")
+          == ixns.get("sealed_segments"),
+          f"{who}index segment admitted to the device tier")
+    return seal_s
+
+
+def spawn_coordinator(kv_endpoint: str):
+    """A cluster-mode coordinator on the host CPU: it holds no chip."""
+    from m3_tpu.testing.proc_cluster import _spawn_listening
+
+    proc, host, port = _spawn_listening(
+        [sys.executable, "-m", "m3_tpu.services.coordinator", "--cluster",
+         "--kv-endpoint", kv_endpoint, "--namespace", NS, "--port", "0"],
+        "coordinator", env_extra={"JAX_PLATFORMS": "cpu"},
+    )
+    return proc, f"http://{host}:{port}"
+
+
 def served_phase(scale: int, seed: int) -> tuple | None:
     from m3_tpu.net.client import RemoteNode
-    from m3_tpu.testing.proc_cluster import ProcCluster, _spawn_listening
+    from m3_tpu.testing.proc_cluster import ProcCluster
 
     hosts = host_tags(scale, seed)
     n_series = scale * len(METRICS)
@@ -503,12 +568,7 @@ def served_phase(scale: int, seed: int) -> tuple | None:
         pn = cluster.nodes["node0"]
         procs.append(("dbnode", pn.proc))
         device = pn.device
-        coordinator, ch, cport = _spawn_listening(
-            [sys.executable, "-m", "m3_tpu.services.coordinator", "--cluster",
-             "--kv-endpoint", cluster.kv_endpoint, "--namespace", NS,
-             "--port", "0"],
-            "coordinator", env_extra={"JAX_PLATFORMS": "cpu"},
-        )
+        coordinator, http = spawn_coordinator(cluster.kv_endpoint)
         procs.append(("coordinator", coordinator))
         say(f"dbnode pid {pn.proc.pid} (this script: pid {os.getpid()}) "
             f"DEVICE {device}; coordinator on the host CPU; "
@@ -524,36 +584,10 @@ def served_phase(scale: int, seed: int) -> tuple | None:
         say(f"load: {load_s:.1f}s ({n_series * POINTS / load_s:.0f} points/s "
             "over the wire, commit log on)")
 
-        c0 = compile_stats(node)
-        t0 = time.perf_counter()
-        flushed = node.flush(NS, T0 + BLOCK_SECS * NANOS)
-        seal_s = time.perf_counter() - t0
-        c1 = compile_stats(node)
-        say(f"seal: {seal_s:.1f}s, {len(flushed)} filesets "
-            f"({c1[0] - c0[0]} compiles, {c1[1] - c0[1]:.1f}s compiling)")
-        rs = node.resident_stats()
-        ix = node.index_stats()
-        say(f"resident: entries {rs.get('entries')} admissions "
-            f"{rs.get('admissions')} device_admissions "
-            f"{rs.get('device_admissions')} upload_bytes "
-            f"{rs.get('upload_bytes')} bytes {rs.get('bytes')} rejections "
-            f"{rs.get('rejections')} evictions {rs.get('evictions')}")
-        say(f"index: admissions {ix.get('admissions')} namespaces "
-            f"{ix.get('namespaces', {}).get(NS)}")
-        check(rs.get("entries") == n_series and rs.get("rejections") == 0
-              and rs.get("evictions") == 0,
-              f"every sealed block admitted to the resident pool ({n_series})")
-        check(rs.get("device_admissions") == n_series,
-              "every block born resident (device-encoded, no stream upload)")
-        ixns = ix.get("namespaces", {}).get(NS, {})
-        check(ix.get("admissions", 0) >= 1
-              and ixns.get("device_resident_segments", 0) >= 1
-              and ixns.get("device_resident_segments")
-              == ixns.get("sealed_segments"),
-              "index segment admitted to the device tier")
+        seal_s = seal_and_check_admission(node, n_series)
 
         t0 = time.perf_counter()
-        times = query_checks(node, f"http://{ch}:{cport}", hosts, vals)
+        times = query_checks(node, http, hosts, vals)
         say(f"queries: {time.perf_counter() - t0:.1f}s")
 
         # an acknowledged write after seal is read back
@@ -590,18 +624,163 @@ def served_phase(scale: int, seed: int) -> tuple | None:
             cluster.close()
 
 
+def chip_env(i: int) -> dict:
+    """Bind one process to chip ``i`` of a multi-chip host (one process
+    per chip). Harmless off the TPU."""
+    port = str(8476 + i)
+    return {
+        "TPU_VISIBLE_CHIPS": str(i),
+        "TPU_VISIBLE_DEVICES": str(i),  # the same, by its older name
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": "localhost:" + port,
+        "TPU_PROCESS_PORT": port,
+        "CLOUD_TPU_TASK_ID": "0",
+    }
+
+
+def remote_write(http: str, hosts, vals) -> None:
+    """Prometheus remote write through the coordinator (snappy protobuf):
+    the session behind it writes every sample at MAJORITY."""
+    from m3_tpu.gen import prompb_pb2 as prompb
+    from m3_tpu.utils.snappy import compress
+
+    times_ms = [(T0 + j * INTERVAL_SECS * NANOS) // 1_000_000
+                for j in range(POINTS)]
+    s = 0
+    for host in hosts:
+        req = prompb.WriteRequest()
+        for metric in METRICS:
+            ts = req.timeseries.add()
+            for k, v in series_tags(host, metric):
+                ts.labels.add(name=k.decode(), value=v.decode())
+            for t, v in zip(times_ms, vals[s].tolist()):
+                ts.samples.add(timestamp=t, value=float(v))
+            s += 1
+        r = urllib.request.Request(
+            http + "/api/v1/prom/remote/write",
+            data=compress(req.SerializeToString()), method="POST")
+        with urllib.request.urlopen(r, timeout=600) as resp:
+            if resp.status != 200:
+                raise RuntimeError(f"remote write: HTTP {resp.status}")
+
+
 def replicated_phase(scale: int, seed: int) -> tuple | None:
-    say("FAIL --chips 4: not implemented")
+    """The deployment M3 documents, on one four-chip host: three dbnode
+    processes, each bound to its own chip with every device tier on,
+    placement in the embedded KV, RF=3, writes at MAJORITY through a
+    cluster-mode coordinator; the parent and the coordinator hold no chip.
+    Runs ONLY this phase."""
+    from m3_tpu.net.client import RemoteNode
+    from m3_tpu.rules.rules import encode_tags_id
+    from m3_tpu.testing.proc_cluster import ProcCluster
+
+    hosts = host_tags(scale, seed)
+    n_series = scale * len(METRICS)
+    vals = series_values(n_series, seed)
+    ids = [f"node{i}" for i in range(3)]
+    say(f"replicated: RF=3, MAJORITY writes via the coordinator, scale "
+        f"{scale}: {n_series} series x {POINTS} points = {n_series * POINTS} "
+        f"points (seed {seed}); commit log {COMMITLOG_SYNC}")
+    base = tempfile.mkdtemp(prefix="m3tpu-chip-smoke-rf3-")
+    cluster = coordinator = None
+    procs: list = []
+    try:
+        cluster = ProcCluster(
+            num_nodes=3, num_shards=8, replica_factor=3,
+            block_size_secs=BLOCK_SECS, embedded_kv=True, base_dir=base,
+            extra_args=dbnode_args(n_series, 8),
+            node_env={nid: chip_env(i) for i, nid in enumerate(ids)},
+        )
+        for i, nid in enumerate(ids):
+            pn = cluster.nodes[nid]
+            procs.append((nid, pn.proc))
+            say(f"{nid} pid {pn.proc.pid} TPU_VISIBLE_CHIPS={i} "
+                f"DEVICE {pn.device}")
+        # a chip belongs to one process: three live dbnodes that each
+        # report one device are on three different chips
+        check(all(cluster.nodes[n].device is not None
+                  and cluster.nodes[n].device[1] == 1 for n in ids),
+              "each replica holds exactly one device of its own")
+        coordinator, http = spawn_coordinator(cluster.kv_endpoint)
+        procs.append(("coordinator", coordinator))
+
+        t0 = time.perf_counter()
+        remote_write(http, hosts, vals)
+        load_s = time.perf_counter() - t0
+        say(f"load: {load_s:.1f}s ({n_series * POINTS / load_s:.0f} points/s "
+            "acked at MAJORITY through the coordinator)")
+
+        nodes = {nid: RemoteNode.connect(cluster.nodes[nid].endpoint,
+                                         timeout=1500.0) for nid in ids}
+        for nid in ids:
+            seal_and_check_admission(nodes[nid], n_series, who=nid + " ")
+        for i, nid in enumerate(ids):
+            say(f"{nid}: queries")
+            query_checks(nodes[nid], http if i == 0 else None, hosts, vals)
+
+        # a write acknowledged at MAJORITY is read back from ALL replicas
+        t_new = (T0 + (BLOCK_SECS + INTERVAL_SECS) * NANOS)
+        tags = series_tags(hosts[0], METRICS[0])
+        body = json.dumps({
+            "tags": {k.decode(): v.decode() for k, v in tags},
+            "timestamp": t_new / NANOS, "value": 42.0,
+        }).encode()
+        r = urllib.request.Request(http + "/api/v1/json/write", data=body,
+                                   method="POST")
+        with urllib.request.urlopen(r, timeout=60) as resp:
+            acked = json.loads(resp.read()).get("ok") is True
+        check(acked, "write acknowledged through the coordinator")
+        sid = encode_tags_id(tags)
+        deadline = time.monotonic() + 15
+        seen: dict = {}
+        while time.monotonic() < deadline and len(seen) < len(ids):
+            for nid in ids:
+                back = nodes[nid].read(NS, sid, t_new, t_new + NANOS)
+                if [(d.timestamp, d.value) for d in back] == [(t_new, 42.0)]:
+                    seen[nid] = True
+            time.sleep(0.1)
+        check(len(seen) == len(ids),
+              f"acknowledged write read back from all replicas ({sorted(seen)})")
+        for what, proc in procs:
+            check(proc.poll() is None, f"{what} still alive at the end")
+    except BaseException as exc:
+        FAILURES.append(f"{type(exc).__name__}: {exc}")
+        say(f"FAIL {type(exc).__name__}: {exc}")
+        say(stderr_tails(procs))
+        return None
+    finally:
+        if coordinator is not None:
+            coordinator.kill()
+            coordinator.wait(timeout=10)
+        if cluster is not None:
+            cluster.close()
+    # the chips are free again: one unbound process reports the host's
+    # devices as jax sees them
+    res = subprocess.run(
+        [sys.executable, "-c", "from m3_tpu import device; "
+         "print('DEVICE %s %d %s' % device.require_device())"],
+        cwd=HERE, capture_output=True, text=True, timeout=300,
+    )
+    for line in res.stdout.splitlines():
+        parts = line.split()
+        if parts[:1] == ["DEVICE"] and len(parts) >= 4:
+            say("host devices: " + line)
+            return parts[1], int(parts[2]), " ".join(parts[3:])
+    say("FAIL device probe: " + res.stderr[-2000:])
     return None
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--scale", type=int, default=DEFAULT_SCALE,
-                    help="TSBS hosts (x10 metrics = series)")
+    ap.add_argument("--scale", type=int, default=None,
+                    help="TSBS hosts (x10 metrics = series); default "
+                    f"{DEFAULT_SCALE}, or {REPLICATED_SCALE} with --chips 4")
     ap.add_argument("--seed", type=int, default=22)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = ap.parse_args()
+    if args.scale is None:
+        args.scale = REPLICATED_SCALE if args.chips == 4 else DEFAULT_SCALE
 
     t_start = time.perf_counter()
     cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
@@ -611,6 +790,8 @@ def main() -> int:
 
     if args.chips == 4:
         device = replicated_phase(args.scale, args.seed)
+        if device is not None and device[0] == "tpu":
+            check(device[1] == 4, f"four chips on the host (found {device[1]})")
     else:
         device = kernel_parity_phase()
         if device is not None and device[0] != "tpu" \

@@ -34,8 +34,9 @@ def require_device() -> tuple[str, int, str]:
 
     devs = jax.devices()
     platform, kind = devs[0].platform, devs[0].device_kind
-    named = os.environ.get("JAX_PLATFORMS", "").lower().split(",")
-    if platform != "tpu" and "cpu" not in [p.strip() for p in named]:
+    # the first platform JAX_PLATFORMS lists is the one jax defaults to
+    chosen = os.environ.get("JAX_PLATFORMS", "").lower().split(",")[0].strip()
+    if platform != "tpu" and chosen != "cpu":
         raise RuntimeError(
             f"a device tier was requested but jax found platform "
             f"{platform!r} ({len(devs)} x {kind}); run on a TPU, or set "

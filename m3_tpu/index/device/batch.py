@@ -4,8 +4,7 @@ The per-segment device executor (segment.py) already batches every
 exact-match leaf of a query AST into one ``match_terms`` launch — but a
 namespace holding several device-resident segments (multiple index
 blocks in range, or mutable/sealed generations) paid one launch PER
-SEGMENT, and each launch is a host round trip (PROFILE.md's dispatch
-floor). Here ALL of a query's exact leaves resolve over ALL
+SEGMENT, and each launch is a host round trip. Here ALL of a query's exact leaves resolve over ALL
 device-resident segments in one launch:
 
 - the segments' fixed-width term-key matrices concatenate into one

@@ -237,7 +237,7 @@ class M3Storage:
         # block is resident and no live buffer overlays the range, series
         # selection is a device gather of page rows + ONE batched decode —
         # replacing the per-series host select/decode loop below (the
-        # VERDICT round-5 host-bound select/pack gap). The index resolves
+        # host-bound select/pack gap). The index resolves
         # ONCE: the resident plan and any fallback share `docs`. Cache
         # before-stats are captured up front so the pooled fallback's
         # decode work is accounted like the plain path's.

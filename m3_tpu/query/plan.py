@@ -1,7 +1,8 @@
 """Device query plans: a query is ONE XLA program.
 
-PROFILE.md's recurring villain is the host round trip — 8–15 ms of
-dispatch RTT dominating every sub-ms kernel — and the staged executor
+The recurring cost is the host round trip per dispatch (PROFILE.md; its
+per-dispatch milliseconds are from an earlier chip run, record removed,
+not re-measured) — and the staged executor
 pays it 4–6 times per query because `query/m3_storage.py` stitches the
 stages with host-side types: the device index resolves doc ids to the
 host, the host walks per-doc block keys, the resident pool plans a

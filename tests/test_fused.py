@@ -6,14 +6,11 @@ oracle (ops/chunked.py + parallel/scan.chunked_scan_aggregate) in three tiers:
   1. jnp fallback vs oracle (always, CPU mesh)
   2. Pallas interpret-mode vs oracle (always, CPU mesh) — exercises the exact
      kernel body Mosaic compiles, catching i1-vector hazards before hardware
-  3. real-TPU compile+run vs oracle — opt-in via M3_TPU_SMOKE=1 since the CI
-     conftest forces a CPU mesh (run: M3_TPU_SMOKE=1 pytest tests/test_fused.py)
+  3. Mosaic compile for a described v5e (tests/test_tpu_compile.py) and
+     compile+run vs oracle on the chip (chip_smoke.py, kernel-parity phase)
 """
 
 import functools
-import os
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -358,63 +355,3 @@ def test_fused_auto_backend_on_cpu_is_jnp():
     # On the CI CPU mesh this would raise in lowering if 'pallas' were chosen.
     out = _fused(batch, args, "auto")
     _assert_matches(out, _oracle(batch, args))
-
-
-@pytest.mark.skipif(
-    os.environ.get("M3_TPU_SMOKE") != "1",
-    reason="real-TPU smoke test; set M3_TPU_SMOKE=1 (requires a TPU)",
-)
-def test_fused_pallas_real_tpu_smoke():
-    """Compile + run the Mosaic kernel on real hardware, outside the forced
-    CPU mesh, by shelling out to a clean interpreter."""
-    code = r"""
-import functools, json
-import jax, numpy as np
-from m3_tpu.ops.chunked import build_chunked, tile_chunked
-from m3_tpu.parallel.scan import (
-    chunked_device_args, chunked_scan_aggregate, chunked_scan_aggregate_fused)
-from m3_tpu.utils.synthetic import synthetic_streams
-
-assert jax.default_backend() == "tpu", jax.default_backend()
-streams = synthetic_streams(32, 180, seed=11)
-batch = tile_chunked(build_chunked(streams, k=16), 1024)
-args = chunked_device_args(batch)
-p = functools.partial(
-    chunked_scan_aggregate, s=batch.num_series, c=batch.num_chunks, k=batch.k)
-want = jax.jit(p)(args)
-pf = functools.partial(
-    chunked_scan_aggregate_fused, s=batch.num_series, c=batch.num_chunks,
-    k=batch.k, backend="pallas")
-got = jax.jit(pf)(args)
-assert int(got.total_count) == int(want.total_count)
-np.testing.assert_allclose(
-    float(got.total_sum), float(want.total_sum), rtol=1e-6)
-
-from m3_tpu.ops import fused
-from m3_tpu.parallel.scan import chunked_scan_aggregate_packed
-packed = fused.pack_lane_inputs(batch)
-assert packed.tile_flags.sum() > 0, "no fast tiles classified"
-pp = functools.partial(
-    chunked_scan_aggregate_packed, n=packed.n, s=batch.num_series,
-    c=batch.num_chunks, k=batch.k)
-got2 = jax.jit(pp)(packed.windows4, packed.lanes4, packed.tile_flags)
-assert int(got2.total_count) == int(want.total_count)
-np.testing.assert_allclose(
-    float(got2.total_sum), float(want.total_sum), rtol=1e-6)
-np.testing.assert_allclose(
-    np.asarray(got2.series_sum), np.asarray(want.series_sum), rtol=1e-5)
-np.testing.assert_array_equal(
-    np.asarray(got2.series_count), np.asarray(want.series_count))
-print("TPU_SMOKE_OK")
-"""
-    from m3_tpu.testing.cpu_mesh import original_env
-
-    env = original_env()
-    res = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True,
-        text=True,
-        timeout=600,
-        env=env,
-    )
-    assert "TPU_SMOKE_OK" in res.stdout, res.stdout + res.stderr

@@ -2,11 +2,9 @@
 per-function jnp path for every FUSABLE function (NaN pattern included).
 
 On CPU this exercises the fallback dispatch + the engine wiring; the pallas
-path itself is validated on real hardware by the M3_TPU_SMOKE device test
-below (1e-4 for 13 functions; stddev/stdvar at ~5e-3 — see TOLERANCE.md)
-and exercised by bench_suite config3."""
-
-import os
+path itself compiles for a described v5e in tests/test_tpu_compile.py and is
+validated on the chip by chip_smoke.py's kernel-parity phase (1e-4 for 13
+functions; stddev/stdvar at ~5e-3 — see TOLERANCE.md)."""
 
 import numpy as np
 import pytest
@@ -54,47 +52,3 @@ def test_temporal_apply_single(data):
     got = np.asarray(temporal_apply("max_over_time", data, 5, 10.0))
     ref = np.asarray(T.max_over_time(data, 5))
     assert np.array_equal(np.isnan(got), np.isnan(ref))
-
-
-@pytest.mark.skipif(
-    os.environ.get("M3_TPU_SMOKE") != "1",
-    reason="real-TPU smoke only (M3_TPU_SMOKE=1; requires a TPU)",
-)
-def test_fused_pallas_parity_on_device():
-    """On-device (Mosaic-lowered) fused kernel vs the unfused jnp path —
-    the CPU suite exercises only the fallback dispatch. Shells out to a
-    clean interpreter (the conftest forces a CPU mesh in-process)."""
-    import subprocess
-    import sys
-
-    code = r"""
-import numpy as np, jax
-from m3_tpu.query.functions.temporal_fused import FUSABLE, fused_temporal
-assert jax.devices()[0].platform == "tpu", jax.devices()
-rng = np.random.default_rng(3)
-vals = rng.normal(100, 10, (256, 720)).astype(np.float32)
-vals[rng.random((256, 720)) < 0.02] = np.nan
-for name in sorted(FUSABLE):
-    got = np.asarray(fused_temporal(vals, 7, 10.0, (name,))[0])
-    ref = np.asarray(FUSABLE[name](vals, 7, 10.0))
-    both_nan = np.isnan(got) & np.isnan(ref)
-    # stddev/stdvar: the E[x^2]-mean^2 form cancels catastrophically in
-    # f32 (values ~100, window stdev ~10), so reassociation under Mosaic
-    # fusion moves the result by up to ~5e-3 absolute — the measured
-    # on-device bound, recorded in TOLERANCE.md (round-5 additions)
-    atol = 5e-3 if name.startswith("std") else 1e-4
-    close = np.abs(got - ref) <= atol + 1e-4 * np.abs(ref)
-    assert np.all(both_nan | close), name
-print("FUSED_PARITY_OK")
-"""
-    from m3_tpu.testing.cpu_mesh import original_env
-
-    env = original_env()
-    r = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=900, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
-    assert r.returncode == 0 and "FUSED_PARITY_OK" in r.stdout, (
-        (r.stdout + r.stderr)[-2000:]
-    )
