@@ -136,15 +136,17 @@ def series_tags(host: dict, metric: str) -> tuple:
     return tuple((k.encode(), v.encode()) for k, v in sorted(tags.items()))
 
 
+# the step grid lies on sample times: step j reads sample ON_GRID[j]
+GRID_STRIDE = Q_STEP // (INTERVAL_SECS * NANOS)
+ON_GRID = (Q_START - T0) // (INTERVAL_SECS * NANOS) + GRID_STRIDE * np.arange(Q_STEPS)
+
+
 def grid_windows(vals: np.ndarray, window_steps: int) -> np.ndarray:
     """[S, Q_STEPS, window] samples the engine's window sees at each output
-    step: the step grid lies on sample times, so a window of w grid points
-    ending at step j is w samples Q_STEP apart."""
-    stride = Q_STEP // (INTERVAL_SECS * NANOS)
-    first = (Q_START - T0) // (INTERVAL_SECS * NANOS)
-    ends = first + stride * np.arange(Q_STEPS)
-    offs = stride * np.arange(-(window_steps - 1), 1)
-    return vals[:, ends[:, None] + offs[None, :]]
+    step: a window of w grid points ending at step j is w samples Q_STEP
+    apart."""
+    offs = GRID_STRIDE * np.arange(-(window_steps - 1), 1)
+    return vals[:, ON_GRID[:, None] + offs[None, :]]
 
 
 def ref_rate(vals: np.ndarray, range_secs: int) -> np.ndarray:
@@ -197,6 +199,15 @@ def same_values(a: dict, b: dict) -> bool:
         np.array_equal(np.asarray(x), np.asarray(y), equal_nan=True)
         for x, y in zip(a["values"], b["values"])
     )
+
+
+def device_marker(stdout: str) -> tuple | None:
+    """(platform, count, kind) from a child's ``DEVICE`` line, if any."""
+    for line in stdout.splitlines():
+        parts = line.split()
+        if parts[:1] == ["DEVICE"] and len(parts) >= 4:
+            return parts[1], int(parts[2]), " ".join(parts[3:])
+    return None
 
 
 def stderr_tails(procs) -> str:
@@ -316,11 +327,8 @@ def kernel_parity_phase() -> tuple:
          "import chip_smoke; chip_smoke.kernel_parity_child()"],
         cwd=HERE, capture_output=True, text=True, timeout=900,
     )
-    device = None
+    device = device_marker(res.stdout)
     for line in res.stdout.splitlines():
-        parts = line.split()
-        if parts[:1] == ["DEVICE"] and len(parts) >= 4:
-            device = (parts[1], int(parts[2]), " ".join(parts[3:]))
         say("  [kernel-parity] " + line)
     ok = res.returncode == 0 and res.stdout.count("KERNEL_PARITY") == 2
     if not ok:
@@ -385,8 +393,7 @@ def query_checks(node, http: str | None, hosts, vals) -> dict:
         warm = fn()
         t2 = time.perf_counter()
         c2 = compile_stats(node)
-        times[label] = (t1 - t0, t2 - t1, c1[0] - c0[0], c1[1] - c0[1],
-                        c2[0] - c1[0])
+        times[label] = (t1 - t0, t2 - t1)
         say(f"  {label}: cold {t1 - t0:.3f}s ({c1[0] - c0[0]} compiles, "
             f"{c1[1] - c0[1]:.1f}s compiling), warm {t2 - t1:.3f}s "
             f"({c2[0] - c1[0]} compiles)")
@@ -452,14 +459,10 @@ def query_checks(node, http: str | None, hosts, vals) -> dict:
                  f"within rtol 2e-4 of numpy (worst {worst:.2e})"))
         return warm
 
-    stride = Q_STEP // (INTERVAL_SECS * NANOS)
-    first = (Q_START - T0) // (INTERVAL_SECS * NANOS)
-    on_grid = first + stride * np.arange(Q_STEPS)
-
     # 2) needle selector — the warm eligible query of the dispatch contract
     q_needle = 'cpu_usage_user{hostname="%s"}' % needle_host
     warm = promql("query_range needle", q_needle,
-                  {needle_host: vals[ni, on_grid].astype(np.float64)}, True)
+                  {needle_host: vals[ni, ON_GRID].astype(np.float64)}, True)
     st = warm["stats"]
     check(st.get("deviceDispatches") == 1 and st.get("planHits", 0) >= 1
           and st.get("planFallbacks") == 0,
@@ -494,7 +497,7 @@ def query_checks(node, http: str | None, hosts, vals) -> dict:
             got_t = np.asarray([float(t) for t, _ in result[0]["values"]])
             ok = (
                 result[0]["metric"].get("hostname") == needle_host
-                and np.array_equal(got_v, vals[ni, on_grid].astype(np.float64))
+                and np.array_equal(got_v, vals[ni, ON_GRID].astype(np.float64))
                 and np.array_equal(
                     got_t, (Q_START + Q_STEP * np.arange(Q_STEPS)) / NANOS)
             )
@@ -611,7 +614,7 @@ def served_phase(scale: int, seed: int) -> tuple | None:
         for what, proc in procs:
             check(proc.poll() is None, f"{what} still alive at the end")
         return device
-    except BaseException as exc:
+    except Exception as exc:
         FAILURES.append(f"{type(exc).__name__}: {exc}")
         say(f"FAIL {type(exc).__name__}: {exc}")
         say(stderr_tails(procs))
@@ -744,7 +747,7 @@ def replicated_phase(scale: int, seed: int) -> tuple | None:
               f"acknowledged write read back from all replicas ({sorted(seen)})")
         for what, proc in procs:
             check(proc.poll() is None, f"{what} still alive at the end")
-    except BaseException as exc:
+    except Exception as exc:
         FAILURES.append(f"{type(exc).__name__}: {exc}")
         say(f"FAIL {type(exc).__name__}: {exc}")
         say(stderr_tails(procs))
@@ -762,13 +765,10 @@ def replicated_phase(scale: int, seed: int) -> tuple | None:
          "print('DEVICE %s %d %s' % device.require_device())"],
         cwd=HERE, capture_output=True, text=True, timeout=300,
     )
-    for line in res.stdout.splitlines():
-        parts = line.split()
-        if parts[:1] == ["DEVICE"] and len(parts) >= 4:
-            say("host devices: " + line)
-            return parts[1], int(parts[2]), " ".join(parts[3:])
-    say("FAIL device probe: " + res.stderr[-2000:])
-    return None
+    device = device_marker(res.stdout)
+    say(f"host devices: {device}" if device is not None
+        else "FAIL device probe: " + res.stderr[-2000:])
+    return device
 
 
 def main() -> int:

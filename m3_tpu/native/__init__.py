@@ -124,9 +124,7 @@ def load():
     global _lib
     if _lib is not None:
         return _lib
-    lib = None
-    if not _needs_build():
-        lib = _open()
+    lib = None if _needs_build() else _open()
     if lib is None:
         # missing, stale, or an existing file that would not load (left
         # half-written by a build that was not atomic): build, then retry
