@@ -613,6 +613,8 @@ def served_phase(scale: int, seed: int) -> tuple | None:
                        for k, v in times.items()))
         for what, proc in procs:
             check(proc.poll() is None, f"{what} still alive at the end")
+        if FAILURES:
+            say(stderr_tails(procs))
         return device
     except Exception as exc:
         FAILURES.append(f"{type(exc).__name__}: {exc}")
@@ -747,6 +749,8 @@ def replicated_phase(scale: int, seed: int) -> tuple | None:
               f"acknowledged write read back from all replicas ({sorted(seen)})")
         for what, proc in procs:
             check(proc.poll() is None, f"{what} still alive at the end")
+        if FAILURES:
+            say(stderr_tails(procs))
     except Exception as exc:
         FAILURES.append(f"{type(exc).__name__}: {exc}")
         say(f"FAIL {type(exc).__name__}: {exc}")
