@@ -34,6 +34,7 @@ import argparse
 import json
 import math
 import os
+import shutil
 import subprocess
 import sys
 import tempfile
@@ -627,6 +628,7 @@ def served_phase(scale: int, seed: int) -> tuple | None:
             coordinator.wait(timeout=10)
         if cluster is not None:
             cluster.close()
+        shutil.rmtree(base, ignore_errors=True)  # commit logs + filesets
 
 
 def chip_env(i: int) -> dict:
@@ -762,6 +764,7 @@ def replicated_phase(scale: int, seed: int) -> tuple | None:
             coordinator.wait(timeout=10)
         if cluster is not None:
             cluster.close()
+        shutil.rmtree(base, ignore_errors=True)  # commit logs + filesets
     # the chips are free again: one unbound process reports the host's
     # devices as jax sees them
     res = subprocess.run(
