@@ -1,0 +1,148 @@
+#!/usr/bin/env python3
+"""Checks of ``BENCHMARK.json`` and the files it names that need no chip.
+
+    python3 benchmark/selfcheck.py
+
+- every name has only letters, digits, ``_``, ``.``, ``-`` (at most 64);
+  every unit also ``/`` and ``%`` (at most 16);
+- every ``moves`` names an end-to-end metric that every cell reporting the
+  per-layer metric reports too;
+- every file a name points at exists (configuration, traffic mix, layer
+  file, reader);
+- two seeds give byte-identical series ids, the same series count in every
+  shard and the same index terms, and different sample values, request
+  draws and read-back samples;
+- a one-host query class asks for every host of the fleet once, warm-up
+  included, before it asks for any host again, at every seed.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+sys.path.insert(0, ROOT)
+
+import fleet  # noqa: E402
+import traffic as traffic_mod  # noqa: E402
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SEEDS = (1, 2_500_000_011)
+
+
+def problems() -> list[str]:
+    bad: list[str] = []
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = {w["name"]: w for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    names = ([c["name"] for c in bench["configs"]] + list(cells) + list(e2e)
+             + [m["name"] for m in bench["per_layer"]]
+             + [w["config"] for w in cells.values()]
+             + [w["traffic"] for w in cells.values()]
+             + [k for c in bench["configs"] for k in c["reduced"]])
+    bad += [f"name {n!r} has a character outside letters, digits, _ . -" for n in names
+            if not NAME.match(n)]
+    bad += [f"unit {m['unit']!r} of {m['name']}" for m in bench["end_to_end"] + bench["per_layer"]
+            if not UNIT.match(m["unit"])]
+
+    def reported_in(metric: dict) -> set[str]:
+        return set(metric.get("workloads", cells))
+
+    for m in bench["per_layer"]:
+        target = e2e.get(m["moves"])
+        if target is None:
+            bad.append(f"{m['name']} moves {m['moves']!r}, which is no end-to-end metric")
+        elif not reported_in(m) <= reported_in(target):
+            bad.append(f"{m['name']} is reported in {sorted(reported_in(m) - reported_in(target))}, "
+                       f"which do not report {m['moves']}")
+        layer_file = os.path.join(HERE, "layers", m["name"] + ".json")
+        if not os.path.exists(layer_file):
+            bad.append(f"missing {layer_file}")
+            continue
+        layer = fleet.load_json("layers", m["name"] + ".json")
+        if layer["layer"] != m["layer"] or layer["name"] != m["name"]:
+            bad.append(f"layers/{m['name']}.json disagrees with BENCHMARK.json")
+        if not os.path.exists(os.path.join(HERE, "readers", layer["reader"] + ".py")):
+            bad.append(f"missing readers/{layer['reader']}.py")
+    for c in bench["configs"]:
+        if not os.path.exists(os.path.join(ROOT, c["file"])):
+            bad.append(f"missing {c['file']}")
+        elif fleet.load_config(c["name"])["reduced"] != c["reduced"]:
+            bad.append(f"{c['file']}: reduced differs from BENCHMARK.json")
+    for w in cells.values():
+        if not os.path.exists(os.path.join(HERE, "traffic", w["traffic"] + ".json")):
+            bad.append(f"missing traffic/{w['traffic']}.json")
+        if w["config"] not in {c["name"] for c in bench["configs"]}:
+            bad.append(f"cell {w['name']} names no configuration")
+
+    # the fleet does not follow the seed; the samples and the draws do
+    from m3_tpu.utils.hash import shard_for
+    from m3_tpu.utils.serialize import encode_tags
+
+    for w in cells.values():
+        cfg = fleet.load_config(w["config"])
+        tr = fleet.load_json("traffic", w["traffic"] + ".json")
+        seen = []
+        for seed in SEEDS:
+            hosts = fleet.hosts(cfg)
+            table = fleet.series_table(cfg)
+            sids = [bytes(encode_tags(fleet.series_tags(hosts[h], m))) for h, m, _ in table]
+            counts = np.bincount([shard_for(s, cfg["dbnode"]["num_shards"]) for s in sids],
+                                 minlength=cfg["dbnode"]["num_shards"]).tolist()
+            terms = {(k, v) for h, m, _ in table for k, v in fleet.series_tags(hosts[h], m)}
+            n = fleet.points_per_block(cfg)
+            vals = fleet.values(cfg, seed, n)
+            if tr["kind"] == "query":
+                plan = traffic_mod.query_plan(cfg, tr, fleet.t0_nanos(cfg), n, seed)
+                draws = [(r["query"], r["start"]) for reqs in plan["window"] for r in reqs[:50]]
+                rb = [r["query"] for r in traffic_mod.readback_requests(
+                    cfg, table, fleet.t0_nanos(cfg), n, seed, tr["readback_per_class"])]
+            else:
+                draws = None
+                rb = fleet.rng_for(seed, fleet.STREAM_READBACK).choice(len(sids), 32).tolist()
+            seen.append((sids, counts, len(terms), vals, draws, rb))
+        a, b = seen
+        if a[0] != b[0]:
+            bad.append(f"{w['name']}: series ids differ between seeds")
+        if a[1] != b[1] or a[2] != b[2]:
+            bad.append(f"{w['name']}: shard counts or index terms differ between seeds")
+        if np.array_equal(a[3], b[3]):
+            bad.append(f"{w['name']}: two seeds gave the same sample values")
+        if a[4] is not None and a[4] == b[4]:
+            bad.append(f"{w['name']}: two seeds gave the same request draws")
+        if a[5] == b[5]:
+            bad.append(f"{w['name']}: two seeds read back the same sample")
+        print(f"{w['name']}: {len(a[0])} series, shard counts {a[1]}, "
+              f"{a[2]} index terms, the same at seeds {SEEDS}; values and draws differ")
+
+    # a one-host class (no cell has one yet: PERF.md section 7, the needle)
+    one_host = {"workers": 4, "warmup_per_worker": 5, "requests_per_worker": 100,
+                "align_secs": 10, "classes": [{
+                    "fn": "max_over_time", "metric": "cpu_usage_user", "range_secs": 300,
+                    "step_secs": 60, "span_secs": 3600, "hosts": 1}]}
+    fleet_of = {"hosts": 400, "interval_secs": 10}
+    for seed in SEEDS:
+        plan = traffic_mod.query_plan(fleet_of, one_host, 0, 720, seed)
+        dealt = [r["host"] for k in ("warmup", "window") for reqs in plan[k]
+                 for r in reqs[:95]]
+        if sorted(dealt) != list(range(400)):
+            bad.append(f"seed {seed}: a one-host class asks for a host again before "
+                       "it has gone once round the fleet")
+    return bad
+
+
+if __name__ == "__main__":
+    found = problems()
+    for line in found:
+        print("FAIL " + line)
+    print("selfcheck: " + ("ok" if not found else f"{len(found)} problem(s)"))
+    sys.exit(1 if found else 0)

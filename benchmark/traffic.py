@@ -1,0 +1,192 @@
+"""One general traffic generator: a traffic mix is a data file
+(``traffic/<name>.json``), never code.
+
+``kind: "query"`` — closed-loop range queries. ``classes`` lists query
+classes ``{fn, metric, range_secs, step_secs, span_secs, hosts}`` (``hosts``
+is 1 for one host of the whole fleet, or "all"); every worker gets
+``warmup_per_worker`` warm-up requests and ``requests_per_worker`` window
+requests, the classes in equal shares, each with a start drawn from the
+seed on an ``align_secs`` grid. The hosts of the one-host classes are DEALT
+from one permutation of the whole fleet drawn from the seed, without
+replacement, the warm-up first and the window after it, round-robin over
+the workers: TSBS draws each query's host from all of them, and so does
+this, but every seed sends the same number of first sights of a host and
+of repeats (none until the window has gone once round the fleet).
+
+``kind: "write"`` — closed-loop remote write: every series once per
+interval, tick after tick in time order, ``blocks`` blocks of data time.
+Worker w owns the series of the dbnode shards s with s % workers == w (a
+remote-write client owns a slice of the series by hash, and one ingest
+buffer is fed by one client, in order).
+
+The write warm-up is worked out here from what the configuration states
+(``ingest_sync_batch``) and the fleet's per-shard series counts: a shard's
+ingest buffer syncs to the device whenever ``sync_batch`` rows are staged,
+with a tile padded to (pow2 lanes, pow2 slot tail); the first syncs of the
+stretch see every steady-state tile, and the tiles that exist only where a
+sync spans a block boundary are reached by replaying that stretch, shard by
+shard, into a scratch namespace before the window.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from fleet import NANOS, STREAM_READBACK, STREAM_TRAFFIC, rng_for
+
+# ---------------------------------------------------------------------------
+# queries
+# ---------------------------------------------------------------------------
+
+
+def _query_request(cfg: dict, t0: int, cls: dict, host, first_idx: int) -> dict:
+    interval = cfg["interval_secs"]
+    stride = cls["step_secs"] // interval
+    n_steps = cls["span_secs"] // cls["step_secs"] + 1
+    sel = cls["metric"]
+    if host is not None:
+        sel += '{hostname="host_%d"}' % host
+    fn = cls["fn"]
+    if fn == "selector":
+        query, window_steps = sel, 0
+    else:
+        query = f"{fn}({sel}[{cls['range_secs']}s])"
+        window_steps = cls["range_secs"] // cls["step_secs"]
+    start = t0 + first_idx * interval * NANOS
+    return {
+        "query": query, "start": start,
+        "end": start + (n_steps - 1) * cls["step_secs"] * NANOS,
+        "step": cls["step_secs"] * NANOS,
+        # what the reference needs: sample-index arithmetic only
+        "fn": fn, "metric": cls["metric"], "host": host,
+        "first_idx": first_idx, "stride": stride, "n_steps": n_steps,
+        "window_steps": window_steps,
+    }
+
+
+def _start_slots(cfg: dict, n_points: int, cls: dict, align_secs: int) -> np.ndarray:
+    """Sample indices a request of this class may start at: the whole
+    range, look-back included, stays inside the loaded block."""
+    interval = cfg["interval_secs"]
+    lo = cls["range_secs"] if cls["fn"] != "selector" else 0
+    hi = n_points * interval - cls["span_secs"] - interval
+    grid = np.arange(0, hi + 1, align_secs)
+    return grid[grid >= lo] // interval
+
+
+def query_plan(cfg: dict, traffic: dict, t0: int, n_points: int, seed: int) -> dict:
+    """Per-worker request lists: ``warmup`` then ``window``.
+
+    The plan of a matcher is built at its first sight, which costs more
+    than a later sight, so hosts drawn with replacement would mix the two
+    in a ratio that follows the draw. Dealing them from one permutation of
+    the fleet keeps the source's distribution (any host, each as likely)
+    and gives every seed the same mix: request k over all workers, warm-up
+    included, asks for host ``deal[k % hosts]``."""
+    rng = rng_for(seed, STREAM_TRAFFIC)
+    classes = traffic["classes"]
+    workers = traffic["workers"]
+    for cls in classes:
+        if cls["hosts"] not in (1, "all"):
+            raise ValueError(f"query class hosts {cls['hosts']!r}: 1 or \"all\"")
+    deal = rng.permutation(cfg["hosts"])
+
+    def requests(n: int, worker: int, dealt: int) -> list[dict]:
+        """``n`` requests of one worker; its k-th takes deal position
+        ``dealt + k * workers + worker``."""
+        which = np.arange(n) % len(classes)
+        rng.shuffle(which)
+        out = []
+        for k, c in enumerate(which.tolist()):
+            cls = classes[c]
+            host = None
+            if cls["hosts"] == 1:
+                host = int(deal[(dealt + k * workers + worker) % len(deal)])
+            slots = _start_slots(cfg, n_points, cls, traffic["align_secs"])
+            out.append(_query_request(cfg, t0, cls, host,
+                                      int(slots[rng.integers(len(slots))])))
+        return out
+
+    n_warm = traffic["warmup_per_worker"]
+    return {
+        "warmup": [requests(n_warm, w, 0) for w in range(workers)],
+        "window": [requests(traffic["requests_per_worker"], w, n_warm * workers)
+                   for w in range(workers)],
+    }
+
+
+def readback_requests(cfg: dict, table: list, t0: int, n_points: int,
+                      seed: int, per_class: int) -> list[dict]:
+    """A plain selector over the whole block, every sample a step, for
+    ``per_class`` series of every value class the segment holds, drawn
+    from the seed."""
+    rng = rng_for(seed, STREAM_READBACK)
+    by_class: dict[str, list[int]] = {}
+    for i, (_, _, cls) in enumerate(table):
+        by_class.setdefault(cls, []).append(i)
+    reqs = []
+    for cls in sorted(by_class):
+        rows = by_class[cls]
+        for i in rng.choice(len(rows), size=min(per_class, len(rows)), replace=False):
+            host, metric, _ = table[rows[int(i)]]
+            sel = {"fn": "selector", "metric": metric, "range_secs": 0,
+                   "step_secs": cfg["interval_secs"],
+                   "span_secs": (n_points - 1) * cfg["interval_secs"], "hosts": 1}
+            req = _query_request(cfg, t0, sel, host, 0)
+            req["class"] = cls
+            reqs.append(req)
+    return reqs
+
+
+# ---------------------------------------------------------------------------
+# writes
+# ---------------------------------------------------------------------------
+
+
+def pow2ceil(n: int) -> int:
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def sync_ticks(count: int, sync_batch: int, n_ticks: int) -> list[int]:
+    """Ticks after which a shard of ``count`` series syncs its ingest
+    buffer: whenever ``sync_batch`` rows have been staged since the last."""
+    out, staged = [], 0
+    for j in range(n_ticks):
+        staged += count
+        if staged >= sync_batch:
+            out.append(j)
+            staged = 0
+    return out
+
+
+def write_plan(shard_counts: list[int], sync_batch: int, n_points: int,
+               blocks: int) -> dict:
+    """The warm-up of a write cell: ``warmup_ticks`` of the traffic itself
+    (until every shard has synced once, so every steady tile has been
+    seen), and for each shard the stretch around each block boundary
+    (``replays``: shard, first tick, last tick) to replay in the scratch
+    namespace. ``tiles`` lists, per shard, the padded (lanes, slots) tiles
+    the window will dispatch."""
+    n_ticks = n_points * blocks
+    warmup, replays, tiles = 1, [], []  # tick 0 is the registration
+    for shard, count in enumerate(shard_counts):
+        if count == 0:
+            tiles.append([])
+            continue
+        syncs = sync_ticks(count, sync_batch, n_ticks)
+        if not syncs:
+            tiles.append([])
+            continue
+        warmup = max(warmup, syncs[0] + 1)
+        nd = pow2ceil(count)
+        seen = {(nd, pow2ceil(syncs[0] + 1))}
+        prev = -1
+        for s in syncs:
+            for b in range(n_points, n_ticks, n_points):
+                if prev + 1 < b <= s:  # this sync spans boundary b
+                    replays.append((shard, prev + 1, s))
+                    seen.add((nd, pow2ceil(b - prev - 1)))
+                    seen.add((nd, pow2ceil(s - b + 1)))
+            prev = s
+        tiles.append(sorted(seen))
+    return {"warmup_ticks": warmup, "replays": replays, "tiles": tiles}
