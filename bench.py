@@ -47,6 +47,7 @@ def main() -> int:
     from m3_tpu import device
 
     device.configure_compile_cache()
+    device.install_compile_counters()
     # BENCH_SELFMON=1: run the self-monitoring pipeline DURING the bench —
     # the collector stores this process's registry into a local reserved
     # namespace every BENCH_SELFMON_INTERVAL (default 10s) while the
@@ -233,14 +234,11 @@ def kernel_phase() -> None:
                 k=batch.k,
             )
         )
-    from m3_tpu.utils.instrument import JitTracker
-
-    # compile + warm; the tracker lands the compile time in
-    # m3tpu_jit_compile_seconds_total{kernel="bench_chunked_scan"} so the
-    # metrics snapshot line can separate warmup from steady-state
-    with JitTracker("bench_chunked_scan").track((platform, n_series, n_points, k)):
-        out = fn(args)
-        jax.block_until_ready(out)
+    # compile + warm; device.install_compile_counters() lands the compile
+    # time in m3tpu_jit_compile_seconds_total so the metrics snapshot line
+    # can separate warmup from steady-state
+    out = fn(args)
+    jax.block_until_ready(out)
     total_points = int(out.total_count)
 
     iters = 10

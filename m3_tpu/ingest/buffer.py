@@ -36,6 +36,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..utils.instrument import DEFAULT as METRICS
+from ..utils.trace import TRACER
 
 SPILL_REASONS = ("window", "lanes", "slots")
 
@@ -200,7 +201,10 @@ class ColumnWriteBuffer:
             self._m_appends.inc(got)
             want_sync = self._staged_since_sync >= o.sync_batch
         if want_sync:
-            self.sync()
+            # one stage a sync (the device_puts and the scatter dispatch),
+            # a child of the caller's write.ingest_append
+            with TRACER.stage("ingest.sync"):
+                self.sync()
         return accepted
 
     def _frame_locked(self, bs: int, n_rows: int):

@@ -458,6 +458,15 @@ class RemoteNode(RpcClient):
         every peer."""
         return self._call("profile", seconds=seconds)
 
+    def device_profile(self, action: str = "stat", dir: str | None = None) -> dict:
+        """Start (into ``dir``, on the node), stop or stat a jax.profiler
+        capture of the node's process; every answer carries the fullest
+        device's peak and current bytes in use."""
+        args = {"action": action}
+        if dir is not None:
+            args["dir"] = dir
+        return self._call("device_profile", **args)
+
     def owned_shards(self, cache_secs: float = 1.0) -> set[int]:
         cached = self._shards_cache
         now = time.monotonic()

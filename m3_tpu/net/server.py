@@ -16,13 +16,27 @@ import socket
 import socketserver
 import threading
 import time
+from typing import NamedTuple
 
 from ..utils.instrument import DEFAULT as METRICS
-from ..utils.trace import NOOP_SPAN, TRACER
+from ..utils.trace import TRACER
 from ..utils.xtime import Unit
 from . import wire
 from .faults import plan_from_env
 from .resilience import UnavailableError
+
+
+class OpHandles(NamedTuple):
+    """One op's metric handles. ``label`` is the op, or ``_overflow`` past
+    the cardinality cap: what stages of the op are labelled with."""
+
+    requests: object
+    errors: object
+    inflight: object
+    duration: object
+    label: str
+    request_bytes: object
+    response_bytes: object
 
 
 class RpcMiddleware:
@@ -33,10 +47,13 @@ class RpcMiddleware:
     - per-op request/error counters, latency histograms, and an in-flight
       gauge, all labeled {component, op} so one /metrics scrape separates
       dbnode data-plane ops from control-plane KV traffic;
-    - trace adoption: an incoming request carrying a wire trace context
-      gets a server-side span that JOINS the client's trace (the other half
-      of net/client's injection) — a query fanning out coordinator → dbnode
-      replicas renders as one stitched tree in /debug/traces;
+    - the request's root stage, ``rpc.server.<op>`` (utils/trace.py): its
+      wall and CPU seconds in the stage table, every stage under it
+      labelled with the op, and trace adoption — an incoming request
+      carrying a wire trace context gets a server-side span that JOINS the
+      client's trace (the other half of net/client's injection), so a
+      query fanning out coordinator → dbnode replicas renders as one
+      stitched tree in /debug/traces;
     - deadline enforcement: a request whose propagated ``_deadline``
       already expired is refused with a typed retryable UnavailableError
       BEFORE dispatch — the caller stopped waiting, so doing the work only
@@ -85,7 +102,7 @@ class RpcMiddleware:
     # without bound. Real services have far fewer ops than this.
     _MAX_OPS = 64
 
-    def _handles(self, op: str):
+    def handles_for(self, op: str) -> OpHandles:
         handles = self._per_op.get(op)
         if handles is not None:
             return handles
@@ -99,12 +116,23 @@ class RpcMiddleware:
                 if handles is not None:
                     return handles
             labels = {"component": self.component, "op": op}
-            handles = self._per_op[op] = (
+            handles = self._per_op[op] = OpHandles(
                 METRICS.counter("rpc_requests_total", labels=labels),
                 METRICS.counter("rpc_errors_total", labels=labels),
                 METRICS.gauge("rpc_inflight", labels=labels),
                 METRICS.histogram(
                     "rpc_request_duration_seconds", labels=labels
+                ),
+                op,
+                METRICS.counter(
+                    "rpc_request_bytes_total",
+                    "request frame bytes received, length prefix included",
+                    labels=labels,
+                ),
+                METRICS.counter(
+                    "rpc_response_bytes_total",
+                    "reply frame bytes sent, length prefix included",
+                    labels=labels,
                 ),
             )
             return handles
@@ -128,7 +156,7 @@ class RpcMiddleware:
             if req.get("fmt") == "json":
                 return METRICS.collect()
             return METRICS.expose()
-        requests, errors, inflight, hist = self._handles(op)
+        requests, errors, inflight, hist, op_label = self.handles_for(op)[:5]
         requests.inc()
         # admission: shed past the in-flight cap before spending anything
         # else on the request ('metrics' stays admitted so the scrape that
@@ -156,16 +184,10 @@ class RpcMiddleware:
                     f"shedding {op!r}"
                 )
         trace_hex = None
-        if ctx is not None and op not in wire.UNTRACED_OPS:
-            span = TRACER.span_from_context(
-                f"rpc.server.{op}", ctx, component=self.component
-            )
-            if ctx.get("sampled", True):
-                # exemplar for the latency histogram: a slow bucket links
-                # to the stitched trace this request belongs to
-                trace_hex = f"{int(ctx['trace_id']):016x}"
-        else:
-            span = NOOP_SPAN
+        span = TRACER.request(
+            op_label, ctx, spans=op not in wire.UNTRACED_OPS,
+            component=self.component,
+        )
         inflight.add(1)
         t0 = time.perf_counter()
         try:
@@ -195,6 +217,10 @@ class RpcMiddleware:
             errors.inc()
             raise
         finally:
+            if span.span is not None:
+                # exemplar for the latency histogram: a slow bucket links
+                # to the stitched trace this request belongs to
+                trace_hex = f"{span.span.trace_id:016x}"
             hist.observe(time.perf_counter() - t0, trace_id=trace_hex)
             inflight.add(-1)
             if tracked:
@@ -259,6 +285,11 @@ class NodeService:
     # wire-carried tenant context (query/tenants.charge_writes — a no-op
     # for unattributed intra-fleet traffic)
 
+    # stages: write.route is the frame's lists turned into what the
+    # database takes; write_batch's further stages are the database's
+    # own (storage/database.py), the tagged ops' is one, write.tagged,
+    # around a database call that is a per-entry loop
+
     def op_write(self, req):
         from ..query.tenants import charge_writes
         from ..selfmon.guard import wire_writer
@@ -274,17 +305,20 @@ class NodeService:
         from ..query.tenants import charge_writes
         from ..selfmon.guard import wire_writer
 
+        with TRACER.stage("write.route"):
+            entries = [tuple(e) for e in req["entries"]]
         with wire_writer(req.get("selfmon")):
-            self.db.write_batch(req["ns"], [tuple(e) for e in req["entries"]])
-        charge_writes(len(req["entries"]))
+            self.db.write_batch(req["ns"], entries)
+        charge_writes(len(entries))
         return True
 
     def op_write_tagged(self, req):
         from ..query.tenants import charge_writes
         from ..selfmon.guard import wire_writer
 
-        tags = tuple((n, v) for n, v in req["tags"])
-        with wire_writer(req.get("selfmon")):
+        with TRACER.stage("write.route"):
+            tags = tuple((n, v) for n, v in req["tags"])
+        with wire_writer(req.get("selfmon")), TRACER.stage("write.tagged"):
             result = self.db.write_tagged(
                 req["ns"], tags, req["t"], req["v"], Unit(req.get("unit", 1))
             )
@@ -297,11 +331,12 @@ class NodeService:
         from ..query.tenants import charge_writes
         from ..selfmon.guard import wire_writer
 
-        entries = [
-            (tuple((n, v) for n, v in tags), t, val, unit)
-            for tags, t, val, unit in req["entries"]
-        ]
-        with wire_writer(req.get("selfmon")):
+        with TRACER.stage("write.route"):
+            entries = [
+                (tuple((n, v) for n, v in tags), t, val, unit)
+                for tags, t, val, unit in req["entries"]
+            ]
+        with wire_writer(req.get("selfmon")), TRACER.stage("write.tagged"):
             errs = self.db.write_tagged_batch(req["ns"], entries)
         charge_writes(sum(1 for e in errs if not e) if errs else len(entries))
         return errs
@@ -447,6 +482,28 @@ class NodeService:
 
         return process_profile(seconds=req.get("seconds"))
 
+    def op_device_profile(self, req):
+        """A jax.profiler capture of THIS running process, and its device
+        memory: ``action`` ``start`` (with ``dir``, a directory on this
+        node), ``stop`` (writes the ``.xplane.pb`` there; ``python -m
+        m3_tpu.profiling.gaps <dir>`` reduces it) or ``stat``. Every
+        answer carries the fullest device's ``peak_bytes_in_use`` and
+        ``bytes_in_use``. While a capture runs every request is sampled,
+        so ``traces`` holds the window's span trees as well."""
+        from .. import profiling
+
+        action = req.get("action", "stat")
+        if action == "start":
+            out = profiling.start_capture(str(req["dir"]))
+        elif action == "stop":
+            out = profiling.stop_capture()
+        elif action == "stat":
+            running = profiling.capture_dir()
+            out = {"capturing": running is not None, "dir": running}
+        else:
+            raise ValueError(f"device_profile: unknown action {action!r}")
+        return {**out, **profiling.device_stat()}
+
     def op_flush(self, req):
         """Operator/CI flush: seal buffered blocks before the cutoff
         (the mediator does this on its own cadence; tools/check_resident
@@ -500,8 +557,6 @@ class NodeService:
         ``explain``: also record and return the per-(series, block)
         routing decisions (query/stats.py add_routing) so CI can assert
         WHICH decoder served the scan, not just the path."""
-        import time as _time
-
         from ..query import stats
         from ..query.m3_storage import M3Storage
         from ..query.promql import Matcher
@@ -516,12 +571,13 @@ class NodeService:
         if st is not None:
             st.record_routing = True
             st.namespace = str(req["ns"])
-        t0 = _time.perf_counter()
+        scan = TRACER.stage("query.eval")
         try:
-            out = storage.scan_totals(matchers, req["start"], req["end"])
+            with scan:
+                out = storage.scan_totals(matchers, req["start"], req["end"])
         finally:
             if st is not None:
-                stats.finish(st, _time.perf_counter() - t0)
+                stats.finish(st, scan.seconds)
         if st is not None:
             out["routing"] = list(st.routing)
         return out
@@ -536,7 +592,7 @@ class NodeService:
         counts, and (with ``explain``) per-series routing reasons — so
         CI can assert a warm eligible query is exactly ONE dispatch and
         bit-identical to the staged path."""
-        import time as _time
+        import numpy as np
 
         from ..query import plan as query_plan
         from ..query import stats
@@ -547,34 +603,38 @@ class NodeService:
             st.namespace = str(req["ns"])
             if req.get("explain"):
                 st.record_routing = True
-        t0 = _time.perf_counter()
+        # durationSecs is this stage's wall time: the evaluation alone, as
+        # it always was; reply.build is the request's, beside it
+        evaluate = TRACER.stage("query.eval")
         err = None
         try:
-            if req.get("force_staged"):
-                with query_plan.force_staged():
+            with evaluate:
+                if req.get("force_staged"):
+                    with query_plan.force_staged():
+                        r = eng.query_range(
+                            req["query"], req["start"], req["end"], req["step"]
+                        )
+                else:
                     r = eng.query_range(
                         req["query"], req["start"], req["end"], req["step"]
                     )
-            else:
-                r = eng.query_range(
-                    req["query"], req["start"], req["end"], req["step"]
-                )
+            with TRACER.stage("reply.build"):
+                values = np.asarray(r.values, np.float64)
+                reply = {
+                    "values": [list(map(float, row)) for row in values],
+                    "metas": [
+                        [[bytes(k), bytes(v)] for k, v in m.tags]
+                        for m in r.metas
+                    ],
+                }
         except Exception as exc:
             err = f"{type(exc).__name__}: {exc}"
             raise
         finally:
             if st is not None:
-                stats.finish(st, _time.perf_counter() - t0, error=err)
-        import numpy as np
-
-        values = np.asarray(r.values, np.float64)
-        return {
-            "values": [list(map(float, row)) for row in values],
-            "metas": [
-                [[bytes(k), bytes(v)] for k, v in m.tags] for m in r.metas
-            ],
-            "stats": st.to_dict() if st is not None else {},
-        }
+                stats.finish(st, evaluate.seconds, error=err)
+        reply["stats"] = st.to_dict() if st is not None else {}
+        return reply
 
     def _query_engine(self, ns: str):
         """Cached per-namespace Engine over the LOCAL database (bounded
@@ -631,18 +691,40 @@ class RpcServer:
         conns: set = set()
         conns_lock = threading.Lock()
         self._conns, self._conns_lock = conns, conns_lock
+        recv_wait = METRICS.counter(
+            "rpc_recv_wait_seconds_total",
+            "seconds connection handlers sat between a reply sent and the "
+            "next frame's first bytes",
+            labels={"component": component},
+        )
 
         class Handler(socketserver.BaseRequestHandler):
             def handle(self):
                 self.request.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
                 with conns_lock:
                     conns.add(self.request)
+                served = "-"  # the op this connection last served
                 try:
                     while True:
                         try:
-                            req = wire.recv_frame(self.request)
+                            # idle between a reply sent and the next
+                            # frame's first bytes: outside any request, so
+                            # a counter and a profiler annotation (a
+                            # server starved by its clients, not one busy
+                            # in Python), never a span; labelled with the
+                            # op just served, which tells a write client's
+                            # think time from an idle pooled connection
+                            with TRACER.stage("rpc.recv_wait", op=served) as idle:
+                                n = wire.recv_header(self.request)
+                            recv_wait.inc(idle.seconds)
+                            with TRACER.stage("wire.decode") as dec:
+                                req = wire.loads(wire.recv_exact(self.request, n))
+                                ops = svc.handles_for(
+                                    str(req.get("op")) if isinstance(req, dict) else "?")
+                                dec.op = served = ops.label
                         except (ConnectionError, OSError):
                             return
+                        ops.request_bytes.inc(n + 4)
                         if fault_plan is not None:
                             action, delay = fault_plan.decide(str(req.get("op")))
                             if delay > 0.0:
@@ -672,9 +754,12 @@ class RpcServer:
                                 "etype": type(exc).__name__,
                             }
                         try:
-                            wire.send_frame(self.request, resp)
+                            with TRACER.stage("wire.encode", op=ops.label):
+                                frame = wire.pack_frame(wire.dumps(resp))
+                                self.request.sendall(frame)
                         except (ConnectionError, OSError):
                             return
+                        ops.response_bytes.inc(len(frame))
                 finally:
                     with conns_lock:
                         conns.discard(self.request)

@@ -166,7 +166,7 @@ def send_frame(sock, v) -> None:
     sock.sendall(pack_frame(dumps(v)))
 
 
-def _recv_exact(sock, n: int) -> bytes:
+def recv_exact(sock, n: int) -> bytes:
     chunks = []
     while n:
         chunk = sock.recv(min(n, 1 << 20))
@@ -177,11 +177,17 @@ def _recv_exact(sock, n: int) -> bytes:
     return b"".join(chunks)
 
 
-def recv_frame(sock):
-    (n,) = _U32.unpack(_recv_exact(sock, 4))
+def recv_header(sock) -> int:
+    """Block until the next frame's length prefix has come; its payload
+    length (the server times this wait apart from the decoding)."""
+    (n,) = _U32.unpack(recv_exact(sock, 4))
     if n > MAX_FRAME:
         raise ValueError(f"frame too large: {n}")
-    return loads(_recv_exact(sock, n))
+    return n
+
+
+def recv_frame(sock):
+    return loads(recv_exact(sock, recv_header(sock)))
 
 
 # --- trace context propagation (Dapper-style; x/context StartSampledTraceSpan
@@ -196,7 +202,7 @@ TRACE_KEY = "_trace"
 # half-stitched (server spans with no client parent, or vice versa).
 UNTRACED_OPS = frozenset(
     {"health", "metrics", "traces", "cache_stats", "resident_stats",
-     "index_stats", "owned_shards"}
+     "index_stats", "owned_shards", "device_profile"}
 )
 
 # ops the RPC client may TRANSPARENTLY retry on a transport failure or a
@@ -225,9 +231,11 @@ IDEMPOTENT_OPS = frozenset(
         # stack table — sampling continues regardless, duplicate-safe)
         "metrics", "traces", "cache_stats", "resident_stats", "index_stats",
         "lg_poll", "profile",
-        # operator ops that re-apply to the same state
+        # operator ops that re-apply to the same state ('device_profile':
+        # a second start into the same directory, or a second stop,
+        # changes nothing)
         "flush", "assign_shards", "resident_clear", "scrub", "repair",
-        "snapshot",
+        "snapshot", "device_profile",
         # raft protocol (duplicate-safe by design)
         "raft_vote", "raft_append", "raft_snapshot", "raft_status",
         # KV reads (mutations ride RemoteKVStore's own failover contract);

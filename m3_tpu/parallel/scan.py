@@ -28,10 +28,10 @@ from ..utils.instrument import KernelProfiler
 from .mesh import SHARD_AXIS, series_mesh
 
 
-# device-tier observability for the batched decode kernel: first-call
-# compile attribution (m3tpu_jit_compiles_total{kernel="m3tsz_decode"})
-# plus sampled block_until_ready-bounded dispatch wall time under
-# M3_TPU_PROFILE_SAMPLE_RATE (m3tpu_kernel_dispatch_seconds)
+# device-tier observability for the batched decode kernel: sampled
+# block_until_ready-bounded dispatch wall time under
+# M3_TPU_PROFILE_SAMPLE_RATE (m3tpu_kernel_dispatch_seconds), the first
+# dispatch of a signature (its compile) kept out of it
 _JIT_DECODE = KernelProfiler("m3tsz_decode")
 
 

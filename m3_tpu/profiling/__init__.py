@@ -7,7 +7,10 @@
 - **device tier** — ``utils.instrument.KernelProfiler`` dispatch
   timing + compiled HLO cost analysis (flops / bytes accessed per
   kernel), plus the live device-memory split
-  (``m3tpu_device_memory_bytes{kind}``, device.py);
+  (``m3tpu_device_memory_bytes{kind}``, device.py), and a jax.profiler
+  capture of a running process (the ``device_profile`` op, device.py)
+  that ``python -m m3_tpu.profiling.gaps`` reduces to "what the host was
+  doing while the device idled" (gaps.py);
 - **fleet tier** — ``/debug/pprof/fleet`` merges every peer's folded
   stacks by frame with per-instance tags (merge.py).
 
@@ -21,21 +24,31 @@ selfmon collector, so a ruler rule can alert on the profiler itself.
 
 from __future__ import annotations
 
-from .device import collect_device_memory
+from .device import (
+    capture_dir,
+    collect_device_memory,
+    device_stat,
+    start_capture,
+    stop_capture,
+)
 from .merge import collect_fleet_profile, merge_profiles
 from .sampler import StackSampler, default_hz, folded_text
 
 __all__ = [
     "StackSampler",
+    "capture_dir",
     "collect_device_memory",
     "collect_fleet_profile",
     "default_hz",
+    "device_stat",
     "folded_text",
     "install",
     "installed",
     "merge_profiles",
     "process_profile",
+    "start_capture",
     "start_sampler",
+    "stop_capture",
 ]
 
 # the process's installed sampler (the instrument.DEFAULT pattern): op

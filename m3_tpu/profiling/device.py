@@ -26,7 +26,11 @@ design), but not free, which is why refresh rides the sampler's slow
 
 from __future__ import annotations
 
+import sys
+import threading
+
 from ..utils.instrument import DEFAULT as METRICS
+from ..utils.trace import TRACER
 
 KINDS = ("resident_pool", "decoded_cache", "index", "other")
 
@@ -93,4 +97,79 @@ def collect_device_memory(db=None) -> dict:
     }
     for kind in KINDS:
         _gauge(kind).set(float(out[kind]))
+    device_stat()
     return out
+
+
+def device_stat() -> dict:
+    """``peak_bytes_in_use`` and ``bytes_in_use`` of the fullest local
+    device (None where the backend reports none, as the CPU's does not),
+    published as gauges too. Like the accounting above it never starts
+    the jax import."""
+    peak = in_use = None
+    jax = sys.modules.get("jax")
+    try:
+        for d in jax.local_devices() if jax is not None else ():
+            ms = d.memory_stats() or {}
+            if "peak_bytes_in_use" in ms:
+                peak = max(peak or 0, int(ms["peak_bytes_in_use"]))
+                in_use = max(in_use or 0, int(ms.get("bytes_in_use", 0)))
+    except Exception:
+        # partially initialized / backend torn down: report nothing, like
+        # the accounting above (this runs on the sampler's schedule)
+        peak = in_use = None
+    if peak is not None:
+        METRICS.gauge(
+            "device_peak_bytes_in_use",
+            "peak bytes in use on the fullest local device since the process started",
+        ).set(float(peak))
+        METRICS.gauge(
+            "device_bytes_in_use", "bytes in use on the fullest local device"
+        ).set(float(in_use))
+    return {"peak_bytes_in_use": peak, "bytes_in_use": in_use}
+
+
+# one jax.profiler session per process; the lock orders start against stop
+_capture_lock = threading.Lock()
+_capture_dir: str | None = None
+
+
+def start_capture(directory: str) -> dict:
+    """Start a jax.profiler capture of this process into ``directory``
+    (the ``device_profile`` op): the Python tracer off, the host tracer at
+    level 1, so the trace holds the device's operations and the program's
+    stage annotations on one clock and little else. While it runs every
+    request is sampled (``TRACER.capturing``)."""
+    global _capture_dir
+    jax = sys.modules.get("jax")
+    if jax is None:
+        raise RuntimeError("this process has not imported jax: nothing to profile")
+    with _capture_lock:
+        if _capture_dir == directory:
+            # a retried start: the op is duplicate-safe (wire.IDEMPOTENT_OPS)
+            return {"capturing": True, "dir": directory}
+        if _capture_dir is not None:
+            raise RuntimeError(f"a capture into {_capture_dir} is already running")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(directory, profiler_options=opts)
+        _capture_dir = directory
+        TRACER.capturing = True
+    return {"capturing": True, "dir": directory}
+
+
+def stop_capture() -> dict:
+    """Stop the running capture and write its ``.xplane.pb``; a stop with
+    none running changes nothing."""
+    global _capture_dir
+    with _capture_lock:
+        directory, _capture_dir = _capture_dir, None
+        TRACER.capturing = False
+        if directory is not None:
+            sys.modules["jax"].profiler.stop_trace()
+    return {"capturing": False, "dir": directory}
+
+
+def capture_dir() -> str | None:
+    return _capture_dir

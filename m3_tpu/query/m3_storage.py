@@ -452,22 +452,19 @@ class M3Storage:
         from ..resident.scan import resident_fetch_arrays
         from . import stats as query_stats
 
-        from ..utils.trace import NOOP_SPAN, TRACER
+        from ..utils.trace import TRACER
 
         plan = self._resident_plan(docs, start_nanos, end_nanos)
         if plan is None:
             return None
         flat_keys = [key for _, doc_keys in plan for key in doc_keys]
         decoded = ([], np.zeros(0, bool))
-        # this path replaces db.fetch_tagged_arrays, so it emits the same
-        # storage.fetch_tagged span — trace shape in /debug/traces must
+        # this path replaces db.fetch_tagged_arrays, so it is the same
+        # storage.fetch_tagged stage — trace shape in /debug/traces must
         # not vary with residency state
-        span = (
-            TRACER.span("storage.fetch_tagged", namespace=self.namespace)
-            if TRACER.active()
-            else NOOP_SPAN
-        )
-        with span:
+        with TRACER.stage(
+            "storage.fetch_tagged", namespace=self.namespace
+        ) as span:
             if flat_keys:
                 decoded = resident_fetch_arrays(self.db.resident_pool, flat_keys)
                 if decoded is None:

@@ -58,6 +58,7 @@ import numpy as np
 from ..index.device.kernels import pad_pow2
 from ..utils.instrument import DEFAULT as METRICS
 from ..utils.instrument import KernelProfiler
+from ..utils.trace import TRACER
 
 _M_HITS = METRICS.counter(
     "query_plan_hits_total",
@@ -533,50 +534,55 @@ class Planner:
         concurrent queries over the same resident blocks cost ONE device
         dispatch (the in-flight execution is the batching window; a
         joiner records plan_coalesced and zero deviceDispatches)."""
-        if not plan_enabled():
-            raise Ineligible("plan-disabled")
-        if staged_forced():
-            raise Ineligible("force-staged")
-        db = self.db
-        namespaces = getattr(db, "namespaces", None)
-        if namespaces is None or self.namespace not in namespaces:
-            raise Ineligible("remote-storage")
-        pool = getattr(db, "resident_pool", None)
-        if pool is None or not pool.enabled:
-            raise Ineligible("resident-pool-disabled")
-        ns = namespaces[self.namespace]
-        if ns.index is None:
-            raise Ineligible("no-index")
-        seg, arrays = self._single_device_segment(ns.index, fetch_lo, fetch_hi)
-        blocks = self._block_set(ns, pool, fetch_lo, fetch_hi)
-        if not blocks:
-            raise Ineligible("no-sealed-blocks")
-        for shard in ns.shards:
-            if shard.has_buffered_overlap(fetch_lo, fetch_hi):
-                raise Ineligible("buffer-overlay")
-
+        from . import stats
         from .m3_storage import matchers_to_index_query
 
-        q = matchers_to_index_query(matchers)
-        t_grid = pad_pow2(len(grid), _SENTINEL_GRID)
-        key = (
-            self.namespace,
-            tuple((m.name, m.op, m.value) for m in matchers),
-            tuple(blocks),
-            t_grid,
-        )
-        from . import stats
+        # stages (utils/trace.py; PERF.md section 3): plan.lookup here and
+        # at the cache in _run_leader, then plan.coalesce_wait (a
+        # follower) or plan.build (a miss), plan.enqueue,
+        # plan.device_wait, plan.finalize
+        with TRACER.stage("plan.lookup"):
+            if not plan_enabled():
+                raise Ineligible("plan-disabled")
+            if staged_forced():
+                raise Ineligible("force-staged")
+            db = self.db
+            namespaces = getattr(db, "namespaces", None)
+            if namespaces is None or self.namespace not in namespaces:
+                raise Ineligible("remote-storage")
+            pool = getattr(db, "resident_pool", None)
+            if pool is None or not pool.enabled:
+                raise Ineligible("resident-pool-disabled")
+            ns = namespaces[self.namespace]
+            if ns.index is None:
+                raise Ineligible("no-index")
+            seg, arrays = self._single_device_segment(ns.index, fetch_lo, fetch_hi)
+            blocks = self._block_set(ns, pool, fetch_lo, fetch_hi)
+            if not blocks:
+                raise Ineligible("no-sealed-blocks")
+            for shard in ns.shards:
+                if shard.has_buffered_overlap(fetch_lo, fetch_hi):
+                    raise Ineligible("buffer-overlay")
 
-        fkey = key + (fetch_lo, fetch_hi, grid.tobytes(), lookback_nanos)
-        with self._lock:
-            fl = self._flights.get(fkey)
-            leader = fl is None
-            if leader:
-                fl = self._flights[fkey] = _Flight()
+            q = matchers_to_index_query(matchers)
+            t_grid = pad_pow2(len(grid), _SENTINEL_GRID)
+            key = (
+                self.namespace,
+                tuple((m.name, m.op, m.value) for m in matchers),
+                tuple(blocks),
+                t_grid,
+            )
+            fkey = key + (fetch_lo, fetch_hi, grid.tobytes(), lookback_nanos)
+            with self._lock:
+                fl = self._flights.get(fkey)
+                leader = fl is None
+                if leader:
+                    fl = self._flights[fkey] = _Flight()
         if not leader:
             # join the in-flight identical scan: this query dispatches
             # nothing (device_dispatches ticks on the leader's thread)
-            fl.event.wait()
+            with TRACER.stage("plan.coalesce_wait"):
+                fl.event.wait()
             if fl.error is not None:
                 if isinstance(fl.error, Ineligible):
                     # a fresh instance per thread: the reason is shared,
@@ -610,18 +616,21 @@ class Planner:
                     lookback_nanos: int):
         from . import stats
 
-        with self._lock:
-            entry = self._cache.get(key)
-            if entry is not None:
-                self._cache.move_to_end(key)
-        if entry is not None and self._valid(entry, seg, arrays, ns, pool):
+        with TRACER.stage("plan.lookup"):
+            with self._lock:
+                entry = self._cache.get(key)
+                if entry is not None:
+                    self._cache.move_to_end(key)
+            hit = entry is not None and self._valid(entry, seg, arrays, ns, pool)
+        if hit:
             self.hits += 1
             _M_HITS.inc()
             stats.add(plan_hits=1)
             return self._execute(
                 entry, ns, fetch_lo, fetch_hi, grid, lookback_nanos
             )
-        entry = self._build(q, seg, arrays, ns, pool, blocks, t_grid)
+        with TRACER.stage("plan.build"):
+            entry = self._build(q, seg, arrays, ns, pool, blocks, t_grid)
         with self._lock:
             self._cache[key] = entry
             self._cache.move_to_end(key)
@@ -837,79 +846,84 @@ class Planner:
                  grid: np.ndarray, lookback_nanos: int):
         from ..index.device import kernels
 
-        pool = self.db.resident_pool
-        t_grid = entry.dims[-1]
-        g = np.zeros(t_grid, np.int64)
-        g[: len(grid)] = grid
-        if len(grid):
-            g[len(grid):] = grid[-1]  # padded steps discarded below
-        gu = g.astype(np.uint64)
-        g_hi = (gu >> np.uint64(32)).astype(np.uint32)
-        g_lo = (gu & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+        # plan.enqueue: the lease, the arguments and the dispatch
+        # returning; plan.device_wait: the blocked read-back
+        with TRACER.stage("plan.enqueue"):
+            pool = self.db.resident_pool
+            t_grid = entry.dims[-1]
+            g = np.zeros(t_grid, np.int64)
+            g[: len(grid)] = grid
+            if len(grid):
+                g[len(grid):] = grid[-1]  # padded steps discarded below
+            gu = g.astype(np.uint64)
+            g_hi = (gu >> np.uint64(32)).astype(np.uint32)
+            g_lo = (gu & np.uint64(0xFFFFFFFF)).astype(np.uint32)
 
-        def pair(v: int):
-            v = int(v) & ((1 << 64) - 1)
-            return (
-                np.uint32(v >> 32),
-                np.uint32(v & 0xFFFFFFFF),
+            def pair(v: int):
+                v = int(v) & ((1 << 64) - 1)
+                return (
+                    np.uint32(v >> 32),
+                    np.uint32(v & 0xFFFFFFFF),
+                )
+
+            with pool.read_lease():
+                # buffer snapshots under the lease (same discipline as the
+                # staged resident scan); the plan tables reference page
+                # indices, so the validity stamp re-checks INSIDE the lease:
+                # an eviction + re-admission racing between run()'s check and
+                # this snapshot could otherwise hand reused pages to stale
+                # table rows. Under the lease the snapshot is immutable
+                # (admissions take the functional-copy path), so a stamp that
+                # holds here holds for the whole dispatch.
+                with pool._lock:
+                    if pool._words is None or pool._side is None:
+                        raise Ineligible("resident-pool-empty")
+                    words, side = pool._words, pool._side
+                if entry.stamp != self._stamp(
+                    entry.seg, entry.arrays, ns, pool
+                ):
+                    raise Ineligible("raced-invalidation")
+                with PROF.dispatch((entry.ast, entry.dims)) as d:
+                    outs = d.done(entry.fn(
+                        entry.arrays.term_keys, entry.arrays.term_lens,
+                        entry.arrays.post_idx, entry.arrays.post_data,
+                        entry.arrays.all_words,
+                        *entry.inputs,
+                        words, side,
+                        *entry.tables,
+                        g_hi, g_lo, pair(fetch_lo), pair(fetch_hi),
+                        pair(lookback_nanos),
+                    ))
+        with TRACER.stage("plan.device_wait"):
+            (bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml, ok) = (
+                # m3lint: disable=M3L010 -- sanctioned end-of-query host finalize: the ONE device->host readback after the fused program dispatch
+                np.asarray(x) for x in outs
             )
+        with TRACER.stage("plan.finalize"):
+            n = int(n_matched)
+            if n > entry.cap:
+                # more matches than the compiled capacity (a doc-count jump
+                # since build): fall back for THIS query; the stamp check
+                # rebuilds at the larger size next time
+                raise Ineligible("plan-capacity")
+            if entry.matched is not None and len(entry.matched[0]) == n:
+                matched = entry.matched
+            else:
+                from ..block.core import SeriesMeta
 
-        with pool.read_lease():
-            # buffer snapshots under the lease (same discipline as the
-            # staged resident scan); the plan tables reference page
-            # indices, so the validity stamp re-checks INSIDE the lease:
-            # an eviction + re-admission racing between run()'s check and
-            # this snapshot could otherwise hand reused pages to stale
-            # table rows. Under the lease the snapshot is immutable
-            # (admissions take the functional-copy path), so a stamp that
-            # holds here holds for the whole dispatch.
-            with pool._lock:
-                if pool._words is None or pool._side is None:
-                    raise Ineligible("resident-pool-empty")
-                words, side = pool._words, pool._side
-            if entry.stamp != self._stamp(
-                entry.seg, entry.arrays, ns, pool
-            ):
-                raise Ineligible("raced-invalidation")
-            with PROF.dispatch((entry.ast, entry.dims)) as d:
-                outs = d.done(entry.fn(
-                    entry.arrays.term_keys, entry.arrays.term_lens,
-                    entry.arrays.post_idx, entry.arrays.post_data,
-                    entry.arrays.all_words,
-                    *entry.inputs,
-                    words, side,
-                    *entry.tables,
-                    g_hi, g_lo, pair(fetch_lo), pair(fetch_hi),
-                    pair(lookback_nanos),
-                ))
-        (bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml, ok) = (
-            # m3lint: disable=M3L010 -- sanctioned end-of-query host finalize: the ONE device->host readback after the fused program dispatch
-            np.asarray(x) for x in outs
-        )
-        n = int(n_matched)
-        if n > entry.cap:
-            # more matches than the compiled capacity (a doc-count jump
-            # since build): fall back for THIS query; the stamp check
-            # rebuilds at the larger size next time
-            raise Ineligible("plan-capacity")
-        if entry.matched is not None and len(entry.matched[0]) == n:
-            matched = entry.matched
-        else:
-            from ..block.core import SeriesMeta
-
-            doc_ids = kernels.bitmap_to_docids(bitmap)[:n]
-            docs = entry.seg.docs
-            matched_docs = [docs[int(i)] for i in doc_ids]
-            matched = (
-                matched_docs,
-                [SeriesMeta(tags=d.fields) for d in matched_docs],
+                doc_ids = kernels.bitmap_to_docids(bitmap)[:n]
+                docs = entry.seg.docs
+                matched_docs = [docs[int(i)] for i in doc_ids]
+                matched = (
+                    matched_docs,
+                    [SeriesMeta(tags=d.fields) for d in matched_docs],
+                )
+                entry.matched = matched
+            t = len(grid)
+            values = _finalize_grid(
+                g_vh[:n, :t], g_vl[:n, :t], g_pf[:n, :t], g_ml[:n, :t],
+                ok[:n, :t],
             )
-            entry.matched = matched
-        t = len(grid)
-        values = _finalize_grid(
-            g_vh[:n, :t], g_vl[:n, :t], g_pf[:n, :t], g_ml[:n, :t],
-            ok[:n, :t],
-        )
-        datapoints = int(counts[:n].sum())
-        err_rows = np.nonzero(err[:n])[0]
+            datapoints = int(counts[:n].sum())
+            err_rows = np.nonzero(err[:n])[0]
         return matched, values, datapoints, err_rows

@@ -8,8 +8,11 @@ a query, capturing:
 
 - per-stage wall seconds: ``parse``, ``index_resolve``, ``fetch``,
   ``decode``, ``exec`` (fetch CONTAINS index_resolve + decode when storage
-  is local — stages are attributed, not disjoint; ``exec`` is total minus
-  fetch minus parse);
+  is local, and the plan path's ``plan.*`` stages — stages are
+  attributed, not disjoint; ``exec`` is total minus fetch minus parse).
+  This module keeps no clock: ``stage()`` is the tracer's stage helper
+  (utils/trace.py), which adds its seconds to the record bound to the
+  thread, so any stage opened under a query lands here by name;
 - series / datapoints / bytes scanned, decoded-block cache hit/miss counts.
 
 Completed records land in a bounded ring served by the coordinator's
@@ -31,6 +34,7 @@ from collections import deque
 from dataclasses import dataclass, field
 
 from ..utils.instrument import DEFAULT as METRICS
+from ..utils.trace import TRACER
 
 # buckets matched to query latencies (sub-ms cached instant queries up to
 # multi-second cold range scans)
@@ -207,12 +211,10 @@ def slo_objectives_for(tenant: str) -> list | None:
         return None
 
 
-_local = threading.local()
-
-
 def current() -> QueryStats | None:
-    """The query record active on this thread (None outside a query)."""
-    return getattr(_local, "stats", None)
+    """The query record active on this thread (None outside a query):
+    the request record bound to the tracer, where stages find it."""
+    return TRACER.record()
 
 
 def start(query: str) -> QueryStats | None:
@@ -222,21 +224,20 @@ def start(query: str) -> QueryStats | None:
     if current() is not None:
         return None
     st = QueryStats(query=query, start_unix_nanos=time.time_ns())
-    from ..utils.trace import TRACER
     from . import tenants
 
     ctx = TRACER.current_context()
     if ctx is not None:
         st.trace_id = f"{ctx['trace_id']:016x}"
     st.tenant = tenants.current() or tenants.DEFAULT_TENANT
-    _local.stats = st
+    TRACER.bind_record(st)
     ACTIVE.register(st)
     return st
 
 
 def finish(st: QueryStats, duration_secs: float, error: str | None = None) -> None:
     """Seal + publish a record: ring, histograms, counters."""
-    _local.stats = None
+    TRACER.bind_record(None)
     ACTIVE.unregister(st)
     st.current_stage = None
     st.duration_secs = duration_secs
@@ -377,34 +378,11 @@ from ..utils.instrument import set_dispatch_counter as _set_dispatch_counter
 _set_dispatch_counter(_count_dispatch)
 
 
-class _Stage:
-    """``with stage("fetch"):`` — accumulates elapsed wall time onto the
-    active record and marks it as the query's CURRENT stage (what
-    /debug/active_queries shows for an in-flight query); no-op (still
-    times nothing extra) outside a query."""
-
-    __slots__ = ("name", "_t0", "_prev")
-
-    def __init__(self, name: str) -> None:
-        self.name = name
-
-    def __enter__(self) -> "_Stage":
-        self._t0 = time.perf_counter()
-        st = current()
-        self._prev = st.current_stage if st is not None else None
-        if st is not None:
-            st.current_stage = self.name
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        st = current()
-        if st is not None:
-            st.add_stage(self.name, time.perf_counter() - self._t0)
-            st.current_stage = self._prev
-
-
-def stage(name: str) -> _Stage:
-    return _Stage(name)
+def stage(name: str):
+    """``with stage("fetch"):`` — one stage of the query on this thread:
+    the tracer's helper times it, adds the seconds to the active record
+    and marks it the query's CURRENT stage (/debug/active_queries)."""
+    return TRACER.stage(name)
 
 
 class ActiveQueryRegistry:

@@ -98,6 +98,7 @@ def main(argv=None) -> int:
     from .. import device
 
     device.configure_compile_cache()
+    device.install_compile_counters()
     forward_node = None
     producer = None
     if args.forward:

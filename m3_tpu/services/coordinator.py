@@ -1452,6 +1452,7 @@ def main(argv=None) -> int:
     from .. import device
 
     device.configure_compile_cache()
+    device.install_compile_counters()
     cfg = load_config(CoordinatorConfig, args.config) if args.config else CoordinatorConfig()
     host = args.host if args.host is not None else cfg.host
     port = args.port if args.port is not None else cfg.port

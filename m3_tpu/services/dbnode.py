@@ -222,6 +222,7 @@ def main(argv=None) -> int:
     from .. import device
 
     device.configure_compile_cache()
+    device.install_compile_counters()
     device_marker = None
     if args.resident_bytes > 0 or args.index_device_bytes > 0 or args.device_ingest:
         # a device tier on a machine with no chip is an error, not a

@@ -28,6 +28,7 @@ import zlib
 from dataclasses import dataclass
 
 from ..utils.instrument import DEFAULT as METRICS
+from ..utils.trace import TRACER
 from ..utils.xtime import Unit
 from .faults import DISK, DiskFullError, crash_point
 
@@ -48,6 +49,27 @@ _DISK_FULL_EVENTS = METRICS.counter(
     "storage_disk_full_events_total",
     "commit log disk-full degrade events",
 )
+# what the log writes, counted where it is written: once per queue
+# command (bytes summed over the command's entries) and once per fsync,
+# never per entry
+_M_BYTES = METRICS.counter(
+    "commitlog_bytes_total",
+    "bytes appended to commit log segments, segment headers included",
+)
+_M_ENTRIES = METRICS.counter(
+    "commitlog_entries_total", "entries appended to commit log segments"
+)
+_M_FSYNCS = METRICS.counter("commitlog_fsyncs_total", "commit log fsyncs")
+_M_FSYNC_SECONDS = METRICS.counter(
+    "commitlog_fsync_seconds_total", "seconds inside commit log fsyncs"
+)
+_M_QUEUE_DEPTH = METRICS.gauge(
+    "commitlog_queue_depth",
+    "commands waiting in the write-behind queue when the writer last took one",
+)
+# stages here run outside any request (the writer thread in write-behind
+# mode), so they carry their own op label
+_STAGE_OP = "commitlog"
 _degraded_dirs: set = set()
 _degraded_lock = threading.Lock()
 
@@ -154,7 +176,9 @@ class CommitLog:
         self._fpath = path
         if f.tell() == 0:
             DISK.write(f, path, struct.pack("<I", _MAGIC))
+            _M_BYTES.inc(4)
             DISK.fsync(f, path)
+            _M_FSYNCS.inc()
         return f
 
     # --- caller-facing surface ---
@@ -204,7 +228,7 @@ class CommitLog:
                 if self._closed:
                     raise ValueError("commit log is closed")
                 try:
-                    self._append(entry)
+                    self._append_all((entry,))
                     if self._pending >= self.flush_every:
                         self._fsync()
                 except OSError as exc:
@@ -224,8 +248,7 @@ class CommitLog:
                 if self._closed:
                     raise ValueError("commit log is closed")
                 try:
-                    for e in entries:
-                        self._append(e)
+                    self._append_all(entries)
                     self._fsync()
                 except OSError as exc:
                     self._map_sync_oserror(exc)
@@ -381,15 +404,15 @@ class CommitLog:
         re-serving a command whose first attempt partially appended is
         safe because replay dedupes (sid, t) last-wins at bootstrap."""
         kind = cmd[0]
+        _M_QUEUE_DEPTH.set(self._q.qsize())
         if kind == "fsync":
             self._fsync()
         elif kind == "entry":
-            self._append(cmd[1])
+            self._append_all((cmd[1],))
             if self._pending >= self.flush_every:
                 self._fsync()
         elif kind == "batch":
-            for e in cmd[1]:
-                self._append(e)
+            self._append_all(cmd[1])
             if self._pending >= self.flush_every:
                 self._fsync()
         elif kind == "flush":
@@ -468,7 +491,22 @@ class CommitLog:
 
     # --- file ops (writer thread in write-behind mode; else under _wlock) ---
 
-    def _append(self, entry: CommitLogEntry) -> None:
+    def _append_all(self, entries) -> None:
+        """Append one queue command's entries: one stage and one call to
+        each counter for the whole command (``_append`` runs per entry).
+        What landed before a failure is still counted."""
+        nbytes = n = 0
+        try:
+            with TRACER.stage("commitlog.append", op=_STAGE_OP):
+                for e in entries:
+                    nbytes += self._append(e)
+                    n += 1
+        finally:
+            _M_BYTES.inc(nbytes)
+            _M_ENTRIES.inc(n)
+
+    def _append(self, entry: CommitLogEntry) -> int:
+        """Write one record; its bytes."""
         payload = (
             struct.pack(
                 "<qdBH",
@@ -484,9 +522,13 @@ class CommitLog:
         DISK.write(self._f, self._fpath, rec)
         self._pending += 1
         self._active_entries += 1
+        return len(rec)
 
     def _fsync(self) -> None:
-        DISK.fsync(self._f, self._fpath)
+        with TRACER.stage("commitlog.fsync", op=_STAGE_OP) as sg:
+            DISK.fsync(self._f, self._fpath)
+        _M_FSYNCS.inc()
+        _M_FSYNC_SECONDS.inc(sg.seconds)
         self._pending = 0
 
     def _rotate_now(self) -> int:

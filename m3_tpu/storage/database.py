@@ -24,7 +24,7 @@ from ..query import stats as query_stats
 from ..utils.hash import shard_for
 from ..utils.instrument import DEFAULT as METRICS
 from ..utils.serialize import decode_tags, is_tag_id
-from ..utils.trace import NOOP_SPAN, TRACER
+from ..utils.trace import TRACER
 from ..utils.xtime import Unit
 
 # decoded bytes off the compressed-stream hot path (BENCH attribution:
@@ -561,8 +561,10 @@ class Shard:
                     if (f.block_start, f.volume) not in device_blocks
                 ]
             )
-        self._admit_payload(payload)
-        self._admit_device_payload(device_payload)
+        if payload or device_payload:
+            with TRACER.stage("seal.admit"):
+                self._admit_payload(payload)
+                self._admit_device_payload(device_payload)
         return flushed
 
     def _seal_encode_locked(self, bs: int, buckets: list):
@@ -655,26 +657,29 @@ class Shard:
                     blocks.setdefault(bs, []).append((sid, bucket))
         flushed = []
         device_payload = []
+        # seal stages are per shard and block, never per series
         for bs, buckets in sorted(blocks.items()):
-            if self.ingest is not None:
-                series, side_rows, dev_payload = self._seal_encode_locked(
-                    bs, buckets
-                )
-            else:
-                series = {
-                    sid: stream
-                    for sid, bucket in buckets
-                    for stream in [bucket.merged_stream()]
-                    if stream
-                }
-                side_rows, dev_payload = {}, None
+            with TRACER.stage("seal.encode"):
+                if self.ingest is not None:
+                    series, side_rows, dev_payload = self._seal_encode_locked(
+                        bs, buckets
+                    )
+                else:
+                    series = {
+                        sid: stream
+                        for sid, bucket in buckets
+                        for stream in [bucket.merged_stream()]
+                        if stream
+                    }
+                    side_rows, dev_payload = {}, None
             if not series:
                 continue
             fid = FilesetID(self.namespace, self.id, bs, volume=0)
-            write_fileset(
-                self.base, fid, series, self.opts.block_size_nanos, CHUNK_K,
-                side_rows=side_rows or None,
-            )
+            with TRACER.stage("seal.fileset_write"):
+                write_fileset(
+                    self.base, fid, series, self.opts.block_size_nanos, CHUNK_K,
+                    side_rows=side_rows or None,
+                )
             self._flushed_blocks.add(bs)
             flushed.append(fid)
             if dev_payload is not None:
@@ -1086,7 +1091,10 @@ class Database:
         per datapoint and capped node ingest at ~80k writes/s/core. If an
         entry is rejected midway (a flush can seal a block between
         entries), everything ALREADY applied is still WAL-logged before
-        the error propagates, so no applied write is ever unlogged."""
+        the error propagates, so no applied write is ever unlogged.
+
+        Stages (utils/trace.py) are per batch and per shard, never inside
+        the per-entry loop."""
         from .series import BufferBucket, SeriesBuffer
 
         from ..selfmon.guard import check_write
@@ -1104,23 +1112,27 @@ class Database:
         # python per-id fallback without the lib
         from .. import native
 
-        shard_ids = native.shard_batch([e[0] for e in entries], namespace.num_shards)
-        by_shard: dict[int, tuple] = {}
-        if shard_ids is None:
-            ns_shard_for = namespace.shard_for
-            for e in entries:
-                sh = ns_shard_for(e[0])
-                rec = by_shard.get(sh.id)
-                if rec is None:
-                    rec = by_shard[sh.id] = (sh, [])
-                rec[1].append(e)
-        else:
-            shards = namespace.shards
-            for e, si in zip(entries, shard_ids.tolist()):
-                rec = by_shard.get(si)
-                if rec is None:
-                    rec = by_shard[si] = (shards[si], [])
-                rec[1].append(e)
+        stage = TRACER.stage
+        with stage("write.route"):
+            shard_ids = native.shard_batch(
+                [e[0] for e in entries], namespace.num_shards
+            )
+            by_shard: dict[int, tuple] = {}
+            if shard_ids is None:
+                ns_shard_for = namespace.shard_for
+                for e in entries:
+                    sh = ns_shard_for(e[0])
+                    rec = by_shard.get(sh.id)
+                    if rec is None:
+                        rec = by_shard[sh.id] = (sh, [])
+                    rec[1].append(e)
+            else:
+                shards = namespace.shards
+                for e, si in zip(entries, shard_ids.tolist()):
+                    rec = by_shard.get(si)
+                    if rec is None:
+                        rec = by_shard[si] = (shards[si], [])
+                    rec[1].append(e)
         applied: list[CommitLogEntry] = []
         cache = self.block_cache
         pool = self.resident_pool
@@ -1130,7 +1142,9 @@ class Database:
                 bsz = sh.opts.block_size_nanos
                 cold_ok = sh.opts.cold_writes_enabled
                 flushed = sh._flushed_blocks
-                with sh.lock:
+                with stage("write.shard_lock_wait"):
+                    sh.lock.acquire()
+                try:
                     # decided UNDER the shard lock: cache entries for this
                     # shard's keys are only created by readers holding this
                     # lock (pool entries by flushes, which also hold it), so
@@ -1141,54 +1155,61 @@ class Database:
                         pool is not None and len(pool) > 0
                     )
                     series = sh.series
-                    for sid, t, v in items:
-                        bs = (t // bsz) * bsz
-                        if bs in flushed and not cold_ok:
-                            raise ColdWriteError(
-                                f"write at {t} targets flushed block {bs} and "
-                                f"namespace {sh.namespace} has cold writes disabled"
-                            )
-                        if collect:
-                            touched.add((sh.id, sid, bs))
-                        buf = series.get(sid)
-                        if buf is None:
-                            if limit_on:
-                                with self._limit_lock:
-                                    self._check_new_series(sh, sid)
-                                    self._consume_new_series()
-                            buf = series[sid] = SeriesBuffer(sid, bsz)
-                        bucket = buf.buckets.get(bs)
-                        if bucket is None:
-                            bucket = buf.buckets[bs] = BufferBucket(block_start=bs)
-                            buffered = sh._buffered_blocks
-                            buffered[bs] = buffered.get(bs, 0) + 1
-                        bucket.times.append(t)
-                        bucket.values.append(v)
-                        bucket.units.append(unit_s)
-                        if t > bucket.last_write_nanos:
-                            bucket.last_write_nanos = t
-                        bucket.num_writes += 1
-                        bucket._stream_cache = None
-                        bucket._arrays_cache = None
-                        applied.append(CommitLogEntry(sid, t, v))
+                    with stage("write.buffer"):
+                        for sid, t, v in items:
+                            bs = (t // bsz) * bsz
+                            if bs in flushed and not cold_ok:
+                                raise ColdWriteError(
+                                    f"write at {t} targets flushed block {bs} and "
+                                    f"namespace {sh.namespace} has cold writes disabled"
+                                )
+                            if collect:
+                                touched.add((sh.id, sid, bs))
+                            buf = series.get(sid)
+                            if buf is None:
+                                if limit_on:
+                                    with self._limit_lock:
+                                        self._check_new_series(sh, sid)
+                                        self._consume_new_series()
+                                buf = series[sid] = SeriesBuffer(sid, bsz)
+                            bucket = buf.buckets.get(bs)
+                            if bucket is None:
+                                bucket = buf.buckets[bs] = BufferBucket(block_start=bs)
+                                buffered = sh._buffered_blocks
+                                buffered[bs] = buffered.get(bs, 0) + 1
+                            bucket.times.append(t)
+                            bucket.values.append(v)
+                            bucket.units.append(unit_s)
+                            if t > bucket.last_write_nanos:
+                                bucket.last_write_nanos = t
+                            bucket.num_writes += 1
+                            bucket._stream_cache = None
+                            bucket._arrays_cache = None
+                            applied.append(CommitLogEntry(sid, t, v))
                     if sh.ingest is not None and items:
                         # mirror the batch into the device column planes
                         # (one vectorized append per shard, not per point);
                         # spilled rows just lose the device-seal shortcut —
                         # the bucket append above stays the source of truth
-                        sh.ingest.append_batch(
-                            [e[0] for e in items],
-                            [e[1] for e in items],
-                            [e[2] for e in items],
-                            [unit_s] * len(items),
-                        )
+                        with stage("write.ingest_append"):
+                            sh.ingest.append_batch(
+                                [e[0] for e in items],
+                                [e[1] for e in items],
+                                [e[2] for e in items],
+                                [unit_s] * len(items),
+                            )
+                finally:
+                    sh.lock.release()
             self._writes_counter(ns).inc(len(applied))
         finally:
             if touched:
-                for shard_id, sid, bs in touched:
-                    self.cache_invalidator.on_write(ns, shard_id, sid, bs)
+                with stage("write.cache_invalidate"):
+                    for shard_id, sid, bs in touched:
+                        self.cache_invalidator.on_write(ns, shard_id, sid, bs)
             if cl is not None and applied:
-                cl.write_batch(applied)
+                # blocks while the write-behind queue is full
+                with stage("write.commitlog_enqueue"):
+                    cl.write_batch(applied)
 
     def apply_runtime_options(self, ro) -> None:
         """storage/runtime.py listener target: live-tunable node knobs."""
@@ -1308,15 +1329,10 @@ class Database:
         self, ns: str, query, start: int, end: int, limit: int | None = None
     ) -> list[tuple[bytes, tuple, list[Datapoint]]]:
         """Index query + per-series read (the FetchTagged server path,
-        tchannelthrift/node/service.go:626). Inside a traced request (e.g.
-        a server-side RPC span) the index-resolve + decode work gets a
-        storage span so stitched traces show where node time went."""
-        span = (
-            TRACER.span("storage.fetch_tagged", namespace=ns)
-            if TRACER.active()
-            else NOOP_SPAN
-        )
-        with span:
+        tchannelthrift/node/service.go:626). The index-resolve + decode
+        work is one stage, so a sampled request's stitched trace shows
+        where node time went."""
+        with TRACER.stage("storage.fetch_tagged", namespace=ns) as span:
             result = self.query_ids(ns, query, start, end, limit=limit)
             out = []
             with query_stats.stage("decode"):
@@ -1335,12 +1351,7 @@ class Database:
         per matched series, served through the decoded-block cache.
         ``docs``: pre-resolved index docs — callers that already ran
         query_ids (the residency router) skip the second resolution."""
-        span = (
-            TRACER.span("storage.fetch_tagged", namespace=ns)
-            if TRACER.active()
-            else NOOP_SPAN
-        )
-        with span:
+        with TRACER.stage("storage.fetch_tagged", namespace=ns) as span:
             if docs is None:
                 docs = self.query_ids(ns, query, start, end, limit=limit).docs
             out = []
@@ -1503,27 +1514,33 @@ class Database:
                 # at write time (never logged), so the same coverage rule holds
                 # (the reference removes commit logs only once covered by
                 # snapshot/fileset data — storage/cleanup.go).
-                cl = self._commitlogs.get(ns)
-                bsz = namespace.opts.block_size_nanos
-                if cl is not None:
-                    cl.rotate()
-                    cl.cleanup(
-                        lambda e: (e.time_nanos // bsz) * bsz + bsz
-                        <= flush_before_nanos
-                    )
-                # Snapshots whose every record now lives in a flushed block are
-                # covered by filesets; drop them so bootstrap doesn't re-buffer
-                # flushed points (storage/cleanup.go snapshot cleanup).
-                for shard in namespace.shards:
-                    snap = read_latest_snapshot(self.base, ns, shard.id)
-                    if snap and all(
-                        bs + bsz <= flush_before_nanos and bs in shard._flushed_blocks
-                        for _, bs, _, _ in snap
-                    ):
-                        remove_snapshots(self.base, ns, shard.id)
+                # one stage for what follows the shards' seals: the cleanup
+                # READS every sealed WAL segment back to prove it covered
+                with TRACER.stage("seal.log_cleanup"):
+                    cl = self._commitlogs.get(ns)
+                    bsz = namespace.opts.block_size_nanos
+                    if cl is not None:
+                        cl.rotate()
+                        cl.cleanup(
+                            lambda e: (e.time_nanos // bsz) * bsz + bsz
+                            <= flush_before_nanos
+                        )
+                    # Snapshots whose every record now lives in a flushed block are
+                    # covered by filesets; drop them so bootstrap doesn't re-buffer
+                    # flushed points (storage/cleanup.go snapshot cleanup).
+                    for shard in namespace.shards:
+                        snap = read_latest_snapshot(self.base, ns, shard.id)
+                        if snap and all(
+                            bs + bsz <= flush_before_nanos and bs in shard._flushed_blocks
+                            for _, bs, _, _ in snap
+                        ):
+                            remove_snapshots(self.base, ns, shard.id)
                 # WarmFlush of index blocks (storage/index.go:868): seal + persist
                 if namespace.index is not None:
-                    namespace.index.persist_before(self.base, ns, flush_before_nanos)
+                    with TRACER.stage("seal.index_build"):
+                        namespace.index.persist_before(
+                            self.base, ns, flush_before_nanos
+                        )
                 return out
 
     def snapshot(self, ns: str) -> int:

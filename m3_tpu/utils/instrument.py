@@ -77,6 +77,19 @@ class Gauge:
         return self._v
 
 
+class CounterView:
+    """A counter child whose value lives elsewhere and is read at
+    exposition (the tracer's stage table keeps wall, CPU and calls of a
+    stage in one row under one lock, not in three counters)."""
+
+    def __init__(self, read) -> None:
+        self._read = read
+
+    @property
+    def value(self) -> float:
+        return self._read()
+
+
 DEFAULT_BUCKETS = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10
 )
@@ -166,6 +179,9 @@ class Registry:
 
     def counter(self, name: str, help: str = "", labels: dict | None = None) -> Counter:
         return self._child(name, "counter", help, labels, Counter)
+
+    def counter_view(self, name: str, help: str, labels: dict | None, read) -> CounterView:
+        return self._child(name, "counter", help, labels, lambda: CounterView(read))
 
     def gauge(self, name: str, help: str = "", labels: dict | None = None) -> Gauge:
         return self._child(name, "gauge", help, labels, Gauge)
@@ -310,64 +326,6 @@ class Registry:
 DEFAULT = Registry(prefix="m3tpu_")
 
 
-class JitTracker:
-    """JAX hot-path compile observability: first call with an unseen static
-    signature is a jit cache miss, so its wall time ≈ compile time (jax
-    dispatch blocks on compilation; execution itself is async and cheap to
-    dispatch). Feeds m3tpu_jit_compiles_total / m3tpu_jit_compile_seconds_total
-    {kernel=...} so BENCH rounds can attribute warmup cost to the right
-    kernel without importing jax here.
-
-    Usage::
-
-        _JIT = JitTracker("temporal_fused")
-        with _JIT.track((funcs, values.shape, window)):
-            out = _fused_call(...)
-    """
-
-    def __init__(self, kernel: str, registry: Registry | None = None) -> None:
-        reg = registry or DEFAULT
-        self.kernel = kernel
-        self._compiles = reg.counter(
-            "jit_compiles_total", "jit cache misses", {"kernel": kernel}
-        )
-        self._seconds = reg.counter(
-            "jit_compile_seconds_total",
-            "wall seconds spent in first-call jit compilation",
-            {"kernel": kernel},
-        )
-        self._seen: set = set()
-        self._lock = threading.Lock()
-
-    def track(self, key):
-        return _JitCall(self, key)
-
-    def _observe(self, key, elapsed: float) -> bool:
-        """Record a first-call compile; returns whether THIS call was the
-        first sighting of ``key`` (i.e. its wall time is compile time)."""
-        with self._lock:
-            if key in self._seen:
-                return False
-            self._seen.add(key)
-        self._compiles.inc()
-        self._seconds.inc(elapsed)
-        return True
-
-
-class _JitCall:
-    def __init__(self, tracker: JitTracker, key) -> None:
-        self.tracker = tracker
-        self.key = key
-
-    def __enter__(self) -> "_JitCall":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        if exc_type is None:
-            self.tracker._observe(self.key, time.perf_counter() - self._t0)
-
-
 # device-seconds attribution hook: query/tenants.py installs a callable
 # ``(kernel, seconds)`` invoked for every SAMPLED, non-compile profiled
 # dispatch, charging device time to the tenant context active on the
@@ -427,9 +385,9 @@ def _env_cost_flag() -> bool | None:
     return None
 
 
-class KernelProfiler(JitTracker):
-    """Device-tier dispatch observability: JitTracker's compile attribution
-    plus SAMPLED wall-time profiles of every kernel dispatch.
+class KernelProfiler:
+    """Device-tier dispatch observability: SAMPLED wall-time profiles of
+    every kernel dispatch, and HLO cost capture once per signature.
 
     JAX dispatch is async — wall time around the call measures Python
     dispatch, not device work — so a profiled sample bounds the dispatch
@@ -437,9 +395,12 @@ class KernelProfiler(JitTracker):
     span in ``m3tpu_kernel_dispatch_seconds{kernel=...}``. Sampling is
     DETERMINISTIC (dispatch ``n`` is sampled iff ``floor(n·rate)`` advances
     over ``floor((n−1)·rate)``), so profiles are reproducible run to run
-    and exactly ``rate`` of dispatches pay the sync. First-call compiles
-    are excluded from the dispatch histogram — their wall time is XLA
-    compilation and lands in the existing jit_compile counters instead.
+    and exactly ``rate`` of dispatches pay the sync. The first dispatch of
+    a ``key`` is taken to be its compile: it stays out of the dispatch
+    histogram and is where the cost is captured. The process's compile
+    COUNTERS (``m3tpu_jit_*``) are not fed from here: they count jax's own
+    backend-compile events (``device.install_compile_counters``), which
+    see every program, profiled or not.
 
     Usage::
 
@@ -451,8 +412,10 @@ class KernelProfiler(JitTracker):
     def __init__(self, kernel: str, registry: Registry | None = None,
                  sample_rate: float | None = None,
                  capture_costs: bool | None = None) -> None:
-        super().__init__(kernel, registry=registry)
         reg = registry or DEFAULT
+        self.kernel = kernel
+        self._seen: set = set()  # dispatch keys already compiled
+        self._lock = threading.Lock()
         self.sample_rate = (
             _env_sample_rate() if sample_rate is None
             else min(max(float(sample_rate), 0.0), 1.0)
@@ -503,9 +466,17 @@ class KernelProfiler(JitTracker):
             "and never breaks a dispatch",
             labels,
         )
-        self._n = 0  # dispatch sequence (guarded by JitTracker._lock)
+        self._n = 0  # dispatch sequence (guarded by _lock)
         self._costs: dict = {}  # compilation key -> {"flops", "bytes_accessed"}
         self._cost_seen: set = set()
+
+    def _first_sight(self, key) -> bool:
+        """Whether this is the first dispatch of ``key`` (its compile)."""
+        with self._lock:
+            if key in self._seen:
+                return False
+            self._seen.add(key)
+            return True
 
     def _next_sampled(self) -> bool:
         rate = self.sample_rate
@@ -598,7 +569,7 @@ class _Dispatch:
             counter(prof.kernel)
         compiled = False
         if self.key is not None:
-            compiled = prof._observe(self.key, time.perf_counter() - self._t0)
+            compiled = prof._first_sight(self.key)
         if compiled and self.cost is not None:
             # first sighting of this signature = the compile just
             # happened: capture its HLO cost analysis once (no-op when

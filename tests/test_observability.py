@@ -352,3 +352,295 @@ def test_rpc_middleware_op_label_cardinality_capped():
             pass
     assert len(mw._per_op) <= mw._MAX_OPS + 1
     assert "_overflow" in mw._per_op
+
+
+# --- stages: one mechanism inside the dbnode's served paths ---
+
+HOUR = 3600 * NANOS
+STEP = 10 * NANOS
+B0 = T0 // HOUR * HOUR  # a block's start: the data below stays in one block
+
+# what each served path opens, as PERF.md section 3 and README.md name them
+WRITE_STAGES = {
+    "rpc.server.write_batch", "write.route", "write.shard_lock_wait",
+    "write.buffer", "write.ingest_append", "ingest.sync",
+    "write.cache_invalidate", "write.commitlog_enqueue",
+}
+QUERY_STAGES = {
+    "rpc.server.query_range", "query.eval", "parse", "fetch", "plan.lookup",
+    "plan.enqueue", "plan.device_wait", "plan.finalize", "reply.build",
+}
+
+
+SERVED_PATHS = pytest.mark.parametrize("kind,op,want", [
+    ("write", "write_batch", WRITE_STAGES),
+    ("query", "query_range", QUERY_STAGES),
+])
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """One device-tier database behind the RPC middleware, a sealed block
+    the plan serves, and one request of each served path."""
+    from m3_tpu.index.device.store import IndexDeviceOptions
+    from m3_tpu.ingest import IngestOptions
+    from m3_tpu.net.server import NodeService, RpcMiddleware
+    from m3_tpu.resident.pool import ResidentOptions
+    from m3_tpu.rules.rules import encode_tags_id
+    from m3_tpu.storage.database import Database, NamespaceOptions
+
+    db = Database(
+        str(tmp_path_factory.mktemp("served")),
+        num_shards=2,
+        resident_options=ResidentOptions(max_bytes=16 << 20),
+        index_device_options=IndexDeviceOptions(max_bytes=64 << 20),
+        # every batch of the 96 series passes sync_batch in both shards,
+        # so each write request runs ingest.sync
+        ingest_options=IngestOptions(lanes=64, slots=256, sync_batch=32),
+    )
+    db.create_namespace("ns", NamespaceOptions(block_size_nanos=HOUR))
+    sids = []
+    for i in range(96):
+        tags = ((b"__name__", b"pm"), (b"s", b"%03d" % i))
+        sids.append(encode_tags_id(tags))
+        db.write_tagged("ns", tags, B0, float(i))
+    # a block large enough that a request's fixed costs (parse, the
+    # record's publication) stay under a twentieth of it on the CPU
+    for j in range(1, 240):
+        db.write_batch("ns", [(sid, B0 + j * STEP, float(j % 7)) for sid in sids])
+    db.flush("ns", B0 + HOUR)
+    mw = RpcMiddleware(NodeService(db), component="dbnode")
+    requests = {
+        # into the open block, the sealed one resident: every write stage runs
+        "write": lambda k: {
+            "op": "write_batch", "ns": "ns",
+            "entries": [[sid, B0 + HOUR + k * STEP, 1.0] for sid in sids],
+        },
+        "query": lambda k: {
+            "op": "query_range", "ns": "ns", "query": "pm",
+            "start": B0 + 60 * NANOS, "end": B0 + 2300 * NANOS, "step": 4 * NANOS,
+        },
+    }
+    # first sight compiles the plan program and the ingest scatter
+    for kind in requests:
+        mw.handle(requests[kind](0))
+    yield mw, requests
+    db.close()
+
+
+def _stage_calls(op):
+    from m3_tpu.utils.trace import TRACER
+
+    return {stage: calls for (o, stage), (_w, _c, calls)
+            in TRACER.stage_table().items() if o == op}
+
+
+@SERVED_PATHS
+def test_sampled_request_yields_one_stage_tree(served, kind, op, want):
+    from m3_tpu.net import wire
+    from m3_tpu.utils.trace import TRACER
+
+    mw, requests = served
+    trace_id, parent = 0x5EED0000 + sum(kind.encode()), 77
+    req = wire.inject_trace(
+        requests[kind](1), {"trace_id": trace_id, "span_id": parent, "sampled": True})
+    mw.handle(req)
+    spans = [s for s in TRACER.dump() if s["traceId"] == f"{trace_id:016x}"]
+    assert {s["name"] for s in spans} == want
+    by_id = {s["spanId"]: s for s in spans}
+    roots = [s for s in spans if s["parentId"] not in by_id]
+    assert [s["name"] for s in roots] == [f"rpc.server.{op}"]
+    assert roots[0]["parentId"] == f"{parent:016x}"
+    slack = 1_000_000  # span starts are wall clock, durations perf_counter
+    for s in spans:
+        p = by_id.get(s["parentId"])
+        if p is not None:
+            assert s["startNanos"] >= p["startNanos"] - slack
+            assert (s["startNanos"] + s["durationNanos"]
+                    <= p["startNanos"] + p["durationNanos"] + slack)
+    # every name follows the rule profiling/gaps.py tells stages by
+    from m3_tpu.utils.trace import is_stage_name
+
+    assert all(is_stage_name(n) for n in want)
+
+
+@SERVED_PATHS
+def test_unsampled_request_counts_stages_and_builds_no_span(served, kind, op, want):
+    from m3_tpu.utils.instrument import DEFAULT as METRICS
+    from m3_tpu.utils.trace import TRACER
+
+    mw, requests = served
+    before, sampled, started = _stage_calls(op), TRACER.sampled, TRACER.started
+    mw.handle(requests[kind](2))
+    after = _stage_calls(op)
+    assert (TRACER.sampled, TRACER.started) == (sampled, started)
+    assert {s for s in after if after[s] > before.get(s, 0)} == want
+    expo = METRICS.expose()
+    for family in ("stage_seconds_total", "stage_cpu_seconds_total", "stage_calls_total"):
+        assert f'm3tpu_{family}{{op="{op}",stage="rpc.server.{op}"}}' in expo
+
+
+def test_plan_served_reply_stages_sum_to_the_handlers_wall_time(served):
+    import time
+
+    mw, requests = served
+    best = None
+    for k in range(3, 8):
+        req = requests["query"](k)
+        reply = None  # the last reply is freed outside the timed region
+        t0 = time.perf_counter()
+        reply = mw.handle(req)
+        wall = time.perf_counter() - t0
+        st = reply["stats"]
+        assert st["planHits"] == 1 and st["planFallbacks"] == 0
+        stages = st["stages"]
+        assert stages["query.eval"] == st["durationSecs"]
+        parts = sum(stages[n] for n in (
+            "plan.lookup", "plan.enqueue", "plan.device_wait", "plan.finalize",
+            "reply.build"))
+        share = parts / wall
+        best = share if best is None else max(best, share)
+    # the five name the handler's time: parse, the engine's own steps and
+    # the middleware are what is left. The best of five requests, so one
+    # pre-empted request on a loaded test machine does not fail it
+    assert 0.95 <= best <= 1.0, best
+
+
+def test_stages_are_profiler_annotations_nested_in_the_request(served, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    mw, requests = served
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        mw.handle(requests["write"](8))
+        mw.handle(requests["query"](8))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [p for p in ProfileData.from_file(path).planes if p.name.startswith("/host:")]
+    for op, want in (("write_batch", WRITE_STAGES), ("query_range", QUERY_STAGES)):
+        root_name = f"rpc.server.{op}"
+        found = False
+        for plane in host:
+            for line in plane.lines:
+                events = [(e.name, int(e.start_ns), int(e.start_ns) + int(e.duration_ns))
+                          for e in line.events]
+                roots = [e for e in events if e[0] == root_name]
+                if not roots:
+                    continue
+                found = True
+                _, r0, r1 = roots[0]
+                inside = {n for n, s, e in events if r0 <= s and e <= r1}
+                assert want <= inside, (op, want - inside)
+        assert found, f"no {root_name} annotation on any host thread"
+
+
+def test_commitlog_counters_match_the_files_and_the_fsyncs(tmp_path, monkeypatch):
+    import os
+
+    from m3_tpu.storage import commitlog as cl_mod
+    from m3_tpu.storage.commitlog import CommitLog, CommitLogEntry
+    from m3_tpu.utils.instrument import DEFAULT as METRICS
+
+    def value(name):
+        return sum(c["value"] for c in METRICS.collect()[f"m3tpu_{name}"]["children"])
+
+    def files():
+        return sum(os.path.getsize(os.path.join(tmp_path, n)) for n in os.listdir(tmp_path))
+
+    fsyncs = []
+    real = cl_mod.DISK.fsync
+    monkeypatch.setattr(
+        cl_mod.DISK, "fsync", lambda f, path: (fsyncs.append(path), real(f, path))[1])
+    names = ("commitlog_bytes_total", "commitlog_entries_total", "commitlog_fsyncs_total")
+    b0, e0, f0 = (value(n) for n in names)
+    log = CommitLog(str(tmp_path), flush_every=64, flush_interval=60.0)
+    try:
+        for k in range(5):
+            log.write_batch([CommitLogEntry(b"series-%d" % i, T0 + k, float(i))
+                             for i in range(100)])
+        log.write(CommitLogEntry(b"one", T0, 1.0, annotation=b"note"))
+        log.rotate()
+        log.write_batch([CommitLogEntry(b"after", T0 + 9, 2.0)])
+        log.flush()
+        b1, e1, f1 = (value(n) for n in names)
+        assert b1 - b0 == files()
+        assert e1 - e0 == 502
+        assert f1 - f0 == len(fsyncs) >= 3
+        assert value("commitlog_fsync_seconds_total") > 0
+    finally:
+        log.close()
+
+
+def test_compile_counters_count_what_jax_counts():
+    import jax
+    import jax.numpy as jnp
+    from jax import monitoring
+
+    from m3_tpu import device
+    from m3_tpu.utils.instrument import DEFAULT as METRICS
+
+    device.install_compile_counters()
+    device.install_compile_counters()  # a second call registers nothing more
+    seen = []
+    monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **kw: seen.append(kw.get("fun_name"))
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+
+    def total():
+        fam = METRICS.collect().get("m3tpu_jit_compiles_total", {"children": []})
+        return sum(c["value"] for c in fam["children"])
+
+    def fresh_program_for_the_compile_counter(x):
+        return (x * 3 + 1).sum()
+
+    before = total()
+    fn = jax.jit(fresh_program_for_the_compile_counter)
+    x = jnp.arange(37, dtype=jnp.float32)
+    fn(x).block_until_ready()
+    first = total() - before
+    assert first == len(seen) >= 1
+    assert "fresh_program_for_the_compile_counter" in " ".join(map(str, seen))
+    fn(x).block_until_ready()  # the repeat compiles nothing
+    assert total() - before == first == len(seen)
+    kernels = {c["labels"]["kernel"] for c in
+               METRICS.collect()["m3tpu_jit_compiles_total"]["children"]}
+    assert any("fresh_program_for_the_compile_counter" in k for k in kernels)
+
+
+def test_device_profile_op_over_the_wire(tmp_path):
+    import glob
+
+    from m3_tpu.net.client import RemoteNode
+    from m3_tpu.testing.proc_cluster import ProcCluster
+
+    cluster = ProcCluster(num_nodes=1, num_shards=2, replica_factor=1,
+                          base_dir=str(tmp_path / "data"))
+    node = RemoteNode.connect(cluster.nodes["node0"].endpoint, timeout=120.0)
+    try:
+        assert node.device_profile("stat")["capturing"] is False
+        out = str(tmp_path / "capture")
+        started = node.device_profile("start", dir=out)
+        assert started["capturing"] is True and "peak_bytes_in_use" in started
+        # duplicate-safe: the same start again changes nothing, another
+        # directory is refused while this capture runs
+        assert node.device_profile("start", dir=out)["dir"] == out
+        with pytest.raises(Exception, match="already running"):
+            node.device_profile("start", dir=out + "-other")
+        node.write_batch("default", [(b"sid-%d" % i, T0 + NANOS, 1.0) for i in range(8)])
+        # while a capture runs every request is sampled
+        assert any(s["name"] == "rpc.server.write_batch" for s in node.traces())
+        assert node.device_profile("stat")["dir"] == out
+        assert node.device_profile("stop")["capturing"] is False
+        assert node.device_profile("stop")["dir"] is None  # nothing running
+        assert glob.glob(out + "/**/*.xplane.pb", recursive=True)
+        assert "m3tpu_stage_calls_total" in node.metrics()
+    finally:
+        node.close()
+        cluster.close()

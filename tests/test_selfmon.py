@@ -224,14 +224,16 @@ def test_kernel_profiler_excludes_compiles_from_dispatch_histogram():
     with prof.dispatch(key=("sig", 1)) as d:
         d.done(np.zeros(1))
     snap = reg.collect()
-    assert snap["m3tpu_jit_compiles_total"]["children"][0]["value"] == 1.0
     # the first call's wall time is compile time -> not a dispatch sample
     assert snap["m3tpu_kernel_dispatch_seconds"]["children"][0]["count"] == 0
     with prof.dispatch(key=("sig", 1)) as d:
         d.done(np.zeros(1))
     snap = reg.collect()
-    assert snap["m3tpu_jit_compiles_total"]["children"][0]["value"] == 1.0
     assert snap["m3tpu_kernel_dispatch_seconds"]["children"][0]["count"] == 1
+    assert snap["m3tpu_kernel_dispatches_total"]["children"][0]["value"] == 2.0
+    # the first-sight rule no longer feeds the compile counters: those
+    # count jax's own events (device.install_compile_counters)
+    assert "m3tpu_jit_compiles_total" not in snap
 
 
 def test_scan_dispatch_profiled(monkeypatch):

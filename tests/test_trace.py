@@ -96,3 +96,147 @@ def test_debug_dump_zip(server):
     assert b"thread" in z.read("stacks.txt")
     ns = json.loads(z.read("namespaces.json"))
     assert "default" in ns
+
+
+# --- stages (utils/trace.py) and the profile reduction (profiling/gaps.py) ---
+
+
+class _Record:
+    """What a stage needs of a request record (QueryStats has it)."""
+
+    def __init__(self):
+        self.stages, self.current_stage, self.seen = {}, None, []
+
+    def add_stage(self, name, secs):
+        self.stages[name] = self.stages.get(name, 0.0) + secs
+
+
+def test_stage_feeds_table_record_and_span_only_when_sampled():
+    import time
+
+    from m3_tpu.utils.instrument import Registry
+
+    reg = Registry(prefix="m3tpu_")
+    tr = Tracer(registry=reg)
+    rec = _Record()
+    tr.bind_record(rec)
+    with tr.request("write_batch"):  # no context, no capture: unsampled
+        with tr.stage("write.route") as sg:
+            assert rec.current_stage == "write.route"
+        assert rec.current_stage == "rpc.server.write_batch"
+    tr.bind_record(None)
+    assert tr.dump() == [] and tr.sampled == 0
+    assert sg.seconds == rec.stages["write.route"] > 0
+    table = tr.stage_table()
+    assert table[("write_batch", "write.route")][2] == 1
+    assert table[("write_batch", "rpc.server.write_batch")][0] >= sg.seconds
+    # the thread-CPU clock is read only where the request is sampled
+    assert table[("write_batch", "rpc.server.write_batch")][1] == 0
+    with tr.stage("commitlog.fsync", op="commitlog"):  # outside any request
+        pass
+    assert ("commitlog", "commitlog.fsync") in tr.stage_table()
+    assert 'm3tpu_stage_calls_total{op="write_batch",stage="write.route"} 1.0' in reg.expose()
+    # sampled three ways: a sampled wire context, an open span, a capture
+    with tr.request("flush", {"trace_id": 9, "span_id": 4, "sampled": True}):
+        with tr.stage("seal.encode"):
+            pass
+    with tr.span("outer"):
+        with tr.stage("seal.admit"):
+            pass
+    tr.capturing = True
+    with tr.request("query_range"):
+        t_end = time.perf_counter() + 0.02
+        while time.perf_counter() < t_end:
+            pass
+    with tr.request("health", spans=False):
+        pass
+    tr.capturing = False
+    wall, cpu, _calls = tr.stage_table()[("query_range", "rpc.server.query_range")]
+    assert 0.01 <= cpu <= wall + 0.005
+    spans = {s["name"]: s for s in tr.dump()}
+    assert set(spans) == {"rpc.server.flush", "seal.encode", "outer", "seal.admit",
+                          "rpc.server.query_range"}
+    assert spans["seal.encode"]["parentId"] == spans["rpc.server.flush"]["spanId"]
+    assert spans["seal.encode"]["traceId"] == f"{9:016x}"
+    assert spans["seal.admit"]["parentId"] == spans["outer"]["spanId"]
+
+
+def test_stage_table_rows_are_capped():
+    from m3_tpu.utils import trace
+    from m3_tpu.utils.instrument import Registry
+
+    tr = Tracer(registry=Registry())
+    for i in range(trace._MAX_STAGE_ROWS + 50):
+        with tr.stage("wire.decode", op=f"bogus_{i}"):
+            pass
+    table = tr.stage_table()
+    assert len(table) <= trace._MAX_STAGE_ROWS + 1
+    assert table[("_overflow", "wire.decode")][2] == 50
+
+
+def test_gaps_innermost_and_overlap():
+    from m3_tpu.profiling import gaps
+
+    segs = gaps.innermost([(0, 100, "rpc.server.x"), (10, 40, "write.route"),
+                           (20, 30, "ingest.sync"), (60, 90, "write.buffer")])
+    assert segs == [
+        (0, 10, "rpc.server.x"), (10, 20, "write.route"), (20, 30, "ingest.sync"),
+        (30, 40, "write.route"), (40, 60, "rpc.server.x"), (60, 90, "write.buffer"),
+        (90, 100, "rpc.server.x"),
+    ]
+    assert gaps.overlap(segs, [(5, 25), (85, 200)]) == {
+        "rpc.server.x": 5 + 10, "write.route": 10, "ingest.sync": 5, "write.buffer": 5}
+    assert gaps.merge([(5, 9), (0, 6), (20, 30)]) == [(0, 9), (20, 30)]
+
+
+def test_gaps_attributes_idle_to_the_open_stage_or_no_stage(tmp_path):
+    """A capture the test makes itself on the CPU backend: a jitted call,
+    a sleep inside a stage (while a second thread sleeps in a stage of its
+    own), the call again, a sleep with no stage open, the call a third
+    time."""
+    import threading
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from m3_tpu.profiling import gaps
+    from m3_tpu.utils.trace import TRACER
+
+    fn = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((256, 256), jnp.float32)
+    fn(x).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    def writer():
+        with TRACER.stage("commitlog.fsync", op="commitlog"):
+            time.sleep(0.04)
+
+    other = threading.Thread(target=writer)
+    try:
+        with TRACER.request("write_batch"):
+            fn(x).block_until_ready()
+            with TRACER.stage("write.buffer"):
+                other.start()
+                time.sleep(0.08)
+                other.join(timeout=10)
+            fn(x).block_until_ready()
+        time.sleep(0.05)
+        fn(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    out = gaps.reduce_trace(str(tmp_path))
+    by_stage = out["gap_seconds_by_stage"]
+    assert 0.075 <= by_stage["write.buffer"] <= 0.12
+    # thread seconds: both threads' lines are named "python" in the capture
+    assert 0.035 <= by_stage["commitlog.fsync"] <= 0.08 and out["threads"] == 2
+    assert 0.045 <= by_stage["no_stage"] <= 0.09
+    assert by_stage.get("rpc.server.write_batch", 0.0) < 0.02
+    assert 0.12 <= out["idle_s"] <= 0.25 and out["busy_s"] > 0
+    st = out["stages"]
+    assert st["write.buffer"]["calls"] == 1
+    assert st["write.buffer"]["self_s"] == pytest.approx(st["write.buffer"]["total_s"])
+    root = st["rpc.server.write_batch"]
+    assert root["self_s"] == pytest.approx(root["total_s"] - st["write.buffer"]["total_s"])
