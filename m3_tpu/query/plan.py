@@ -18,8 +18,11 @@ them inside ONE jit program per query *shape*:
       → resident chunked decode  (parallel/scan assembly +
         ops/chunked.decode_chunked_lanes, straight from the pool's
         pages + packed side planes)
-      → step-grid consolidation  (vectorized binary search over the
-        decoded timestamps, u64-pair compares)
+      → step-grid consolidation  (per step the newest valid point at
+        or before it: a masked max over the point axis and one-hot
+        select-sums, u64-pair compares, no gather — TPU gathers lower
+        to per-element loops, see functions/temporal.py "window index
+        machinery")
 
 The program returns the CONSOLIDATED grid as raw (hi, lo) value pairs
 plus validity masks; the host then runs the exact same float64
@@ -96,6 +99,9 @@ _M_COALESCED = METRICS.counter(
 PROF = KernelProfiler("query_plan")
 
 _SENTINEL_GRID = 8  # minimum padded grid length
+# grid steps per compare-and-reduce pass of stage 5: a power of two no
+# larger than _SENTINEL_GRID, so it divides every padded grid
+_GRID_TILE = 8
 
 
 def plan_enabled() -> bool:
@@ -244,6 +250,59 @@ def _prefix_bounds(arrays, prefix: bytes, lo: int, hi: int):
 # ---------------------------------------------------------------------------
 
 
+def _consolidate_last(ts, planes, valid, g, flo, fhi, lb):
+    """Stage 5, traced: decoded rows onto the step grid by the 'last' rule
+    of engine.consolidate_row — per (row, step j) the newest valid point
+    with ts <= t_j, kept while t_j - ts < lookback.
+
+    ``ts`` (u64 pair) and every plane are [cap, t_pts]; ``g`` is the
+    [t_grid] step pair; ``flo``/``fhi``/``lb`` scalar pairs. Returns
+    (counts [cap], the planes on [cap, t_grid], ok [cap, t_grid]).
+
+    NO gather: TPU gathers (take_along_axis on [S, T]) lower to
+    per-element loops (functions/temporal.py, "window index machinery").
+    A row's valid timestamps ascend, so the wanted point is the valid
+    one of LARGEST INDEX with ts <= t_j: a masked max over the point
+    axis, and each plane rides along as a one-hot select-sum. Integer
+    compares, selects and adds only. XLA fuses broadcast, compare and
+    reduce, so the [cap, t_grid, t_pts] cube never exists in memory;
+    lax.map walks the grid _GRID_TILE steps at a time (measured faster
+    on the v5e than one reduce over the whole grid: PERF.md, PR 28)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops import u64
+
+    i32 = jnp.int32
+    # range mask mirrors the staged fetch window [fetch_lo, fetch_hi)
+    valid = valid & ~u64.lt_u(ts, flo) & u64.lt_u(ts, fhi)
+    counts = jnp.sum(valid.astype(i32), axis=1)
+    rows = lambda x: x[:, None, :]
+    tsr, live = (rows(ts[0]), rows(ts[1])), rows(valid)
+
+    def tile(gt):
+        gt = (gt[0][None, :], gt[1][None, :])
+        gc = (gt[0][..., None], gt[1][..., None])
+        le = live & ~u64.lt_u(gc, tsr)  # ts_i <= t_j
+        iota = jax.lax.broadcasted_iota(i32, le.shape, 2)
+        pick = jnp.max(jnp.where(le, iota, -1), axis=2)
+        hot = iota == pick[..., None]
+        sel = lambda x: jnp.sum(
+            jnp.where(hot, rows(x), 0), axis=2, dtype=x.dtype
+        )
+        age = u64.sub(gt, (sel(ts[0]), sel(ts[1])))
+        ok = (pick >= 0) & u64.lt_u(age, lb)
+        return tuple(sel(x) for x in planes), ok
+
+    out, ok = jax.lax.map(
+        tile, tuple(x.reshape(-1, _GRID_TILE) for x in g)
+    )
+    # [tiles, cap, _GRID_TILE] -> [cap, t_grid]
+    flat = lambda x: jnp.moveaxis(x, 0, 1).reshape(x.shape[1], -1)
+    return counts, tuple(flat(x) for x in out), flat(ok)
+
+
+
 @functools.lru_cache(maxsize=64)
 def _build_program(ast, dims):
     """ONE jitted program for a (query shape, plan shapes) class. ``ast``
@@ -259,7 +318,6 @@ def _build_program(ast, dims):
         bitmap_from_terms_traced,
         match_terms_traced,
     )
-    from ..ops import u64
     from ..ops.chunked import decode_chunked_lanes
     from ..parallel.scan import _assemble_resident_lanes_traced
 
@@ -360,70 +418,10 @@ def _build_program(ast, dims):
         err = jnp.any(res.err.reshape(cap, n_blocks * c), axis=1)
 
         # ---- stage 5: consolidation onto the step grid
-        # range mask mirrors the staged fetch window [fetch_lo, fetch_hi)
-        valid = valid & ~u64.lt_u(ts, flo) & u64.lt_u(ts, fhi)
-        counts = jnp.sum(valid.astype(i32), axis=1)
-        # forward-fill valid points over invalid slots (log-time select
-        # chain; NO scatter — XLA CPU lowers 2D scatters to scalar
-        # loops). Timestamps are ascending over each row's valid points,
-        # so the filled row is monotone non-decreasing end to end:
-        # leading invalid slots carry (0, has=False), later invalid
-        # slots duplicate their predecessor — exactly what an upper
-        # bound needs (it lands after the duplicate run and the gather
-        # reads the run's fill value, i.e. the last valid point).
-        # fill only the search keys + a source-index plane; values gather
-        # once at the end through the filled index (3 filled arrays
-        # instead of 6)
-        src = jnp.broadcast_to(
-            jnp.arange(t_pts, dtype=i32)[None, :], (cap, t_pts)
+        counts, (g_vh, g_vl, g_pf, g_ml), ok = _consolidate_last(
+            ts, (vhi, vlo, pif.astype(i32), mlt), valid,
+            (g_hi, g_lo), flo, fhi, lb,
         )
-        have = valid
-        fill = [
-            jnp.where(valid, x, jnp.zeros_like(x))
-            for x in (ts[0], ts[1], src)
-        ]
-        sh = 1
-        while sh < t_pts:
-            prev_have = jnp.pad(have, ((0, 0), (sh, 0)))[:, :t_pts]
-            take = ~have & prev_have
-            fill = [
-                jnp.where(take, jnp.pad(x, ((0, 0), (sh, 0)))[:, :t_pts], x)
-                for x in fill
-            ]
-            have = have | prev_have
-            sh *= 2
-        fth, ftl, fsrc = fill
-        # vectorized upper bound per (series, grid step): first index
-        # with filled-ts > t_j — np.searchsorted(times, grid, "right")
-        gh = jnp.broadcast_to(g_hi[None, :], (cap, t_grid))
-        gl = jnp.broadcast_to(g_lo[None, :], (cap, t_grid))
-        lo_i = jnp.zeros((cap, t_grid), i32)
-        hi_i = jnp.full((cap, t_grid), t_pts, i32)
-        for _ in range(max(int(t_pts).bit_length(), 1)):
-            active = lo_i < hi_i
-            mid = (lo_i + hi_i) // 2
-            midc = jnp.clip(mid, 0, max(t_pts - 1, 0))
-            tm = (
-                jnp.take_along_axis(fth, midc, axis=1),
-                jnp.take_along_axis(ftl, midc, axis=1),
-            )
-            gt = u64.lt_u((gh, gl), tm)  # ts[mid] > t_j
-            hi_i = jnp.where(active & gt, mid, hi_i)
-            lo_i = jnp.where(active & ~gt, mid + 1, lo_i)
-        idx = lo_i - 1
-        idc = jnp.clip(idx, 0, max(t_pts - 1, 0))
-        ok = (idx >= 0) & jnp.take_along_axis(have, idc, axis=1)
-        st = (
-            jnp.take_along_axis(fth, idc, axis=1),
-            jnp.take_along_axis(ftl, idc, axis=1),
-        )
-        age = u64.sub((gh, gl), st)
-        ok = ok & u64.lt_u(age, lb)
-        pick = jnp.take_along_axis(fsrc, idc, axis=1)
-        g_vh = jnp.take_along_axis(vhi, pick, axis=1)
-        g_vl = jnp.take_along_axis(vlo, pick, axis=1)
-        g_pf = jnp.take_along_axis(pif.astype(i32), pick, axis=1)
-        g_ml = jnp.take_along_axis(mlt, pick, axis=1)
         return (bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml, ok)
 
     _M_COMPILES.inc()

@@ -144,6 +144,116 @@ def test_fused_vs_staged_bitexact_across_shapes(plan_db):
         _assert_bitexact(eng, query, SPAN)
 
 
+# -- stage 5 alone: the fused grid against engine.consolidate_row ------------
+
+_LB = 50  # lookback of the hand-built lanes, in their own small time unit
+_X = None  # an invalid slot
+
+
+def _lane_case(rows, grid, window=(-(10**6), 10**6)):
+    return rows, grid, window
+
+
+# each row: (ts, value) per slot, or _X for an invalid slot. Values alternate
+# float-mode and scaled int-mode so every rider plane is checked.
+_LANE_CASES = {
+    "step_on_timestamp": _lane_case(
+        [[(100, 1.5), (110, 2.5), (120, 3.5)]], [99, 100, 110, 119, 120, 121]),
+    "steps_before_first_point": _lane_case(
+        [[(500, 1.0), (510, 2.0)]], [100, 200, 499, 500, 505]),
+    "gap_longer_than_lookback": _lane_case(
+        [[(100, 1.0), (110, 2.0), (300, 3.0)]],
+        [110, 159, 160, 161, 250, 299, 300, 349, 350]),
+    "invalid_head": _lane_case(
+        [[_X, _X, (120, 3.0), (130, 4.0)]], [100, 119, 120, 125, 130, 140]),
+    "invalid_middle": _lane_case(
+        [[(100, 1.0), _X, _X, (130, 4.0), _X, (150, 6.0)]],
+        [100, 110, 120, 129, 130, 140, 149, 150, 160]),
+    "invalid_tail": _lane_case(
+        [[(100, 1.0), (110, 2.0), _X, _X]], [105, 110, 120, 159, 160, 170]),
+    "all_invalid_row": _lane_case(
+        [[_X, _X, _X, _X], [(100, 1.0), (110, 2.0), _X, (130, 9.0)]],
+        [90, 100, 115, 130, 200]),
+    # the compaction sentinel: an all-zero lane block (ts 0, nothing valid),
+    # under a grid that starts inside its lookback of zero
+    "sentinel_row": _lane_case(
+        [[_X, _X, _X], [(5, 7.0), (20, 8.0), _X]], [0, 1, 10, 49, 50, 60]),
+    "padded_grid_steps": _lane_case(
+        [[(100, 1.0), (110, 2.0), (120, 3.0)]],
+        [100, 110, 115, 115, 115, 115, 115, 115]),
+    # two blocks of four slots, the first block's tail and the second's
+    # head empty, and a hole longer than the lookback between them
+    "two_blocks_with_hole": _lane_case(
+        [[(100, 1.0), (110, 2.0), _X, _X, _X, (400, 5.0), (410, 6.0), _X],
+         [_X, _X, _X, _X, (400, 5.5), (410, 6.5), (420, 7.5), (430, 8.5)]],
+        [100, 120, 159, 160, 300, 399, 400, 405, 415, 440, 479, 480]),
+    "window_cuts_both_ends": _lane_case(
+        [[(100, 1.0), (110, 2.0), (120, 3.0), (130, 4.0), (140, 5.0)],
+         [(90, 1.0), (119, 2.0), (131, 3.0), _X, (150, 5.0)]],
+        [100, 110, 115, 120, 130, 135, 140, 150, 180], window=(110, 131)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LANE_CASES))
+def test_consolidate_last_bitexact_vs_host_rule(case):
+    """query/plan._consolidate_last (the program's stage 5, compare-and-
+    reduce) against engine.consolidate_row on hand-built lanes."""
+    import jax
+
+    from m3_tpu.query.engine import consolidate_row
+
+    rows, grid, (flo, fhi) = _LANE_CASES[case]
+    base = T0  # real unix nanos: both u32 halves of every pair are in play
+    unit = NANOS
+    cap, t_pts = len(rows), len(rows[0])
+    ts = np.zeros((cap, t_pts), np.uint64)
+    raw = np.zeros((cap, t_pts), np.uint64)
+    pif = np.zeros((cap, t_pts), np.int32)
+    mlt = np.zeros((cap, t_pts), np.int32)
+    valid = np.zeros((cap, t_pts), bool)
+    for r, row in enumerate(rows):
+        for i, slot in enumerate(row):
+            if slot is _X:
+                continue
+            t, v = slot
+            valid[r, i] = True
+            ts[r, i] = base + t * unit
+            if i % 2:
+                pif[r, i] = 1
+                raw[r, i] = np.float64(v).view(np.uint64)
+            else:  # int mode, two decimals
+                mlt[r, i] = 2
+                raw[r, i] = np.int64(round(v * 100)).view(np.uint64)
+    grid_ns = base + np.asarray(grid, np.int64) * unit
+    t_grid = qplan.pad_pow2(len(grid_ns), qplan._SENTINEL_GRID)
+    g = np.full(t_grid, grid_ns[-1], np.int64)
+    g[: len(grid_ns)] = grid_ns
+
+    def pair(x):
+        x = np.asarray(x).astype(np.uint64)
+        return (
+            (x >> np.uint64(32)).astype(np.uint32),
+            (x & np.uint64(0xFFFFFFFF)).astype(np.uint32),
+        )
+
+    w_lo, w_hi = base + flo * unit, base + fhi * unit
+    counts, planes, ok = jax.jit(qplan._consolidate_last)(
+        pair(ts), (*pair(raw), pif, mlt), valid, pair(g),
+        pair(w_lo), pair(w_hi), pair(_LB * unit),
+    )
+    got = qplan._finalize_grid(*planes, ok)[:, : len(grid_ns)]
+    vals = qplan._finalize_grid(*pair(raw), pif, mlt, np.ones_like(valid))
+    tsi = ts.astype(np.int64)
+    for r in range(cap):
+        keep = valid[r] & (tsi[r] >= w_lo) & (tsi[r] < w_hi)
+        want = consolidate_row(tsi[r][keep], vals[r][keep], grid_ns, _LB * unit)
+        assert int(counts[r]) == int(keep.sum())
+        np.testing.assert_array_equal(
+            got[r].view(np.uint64), want.view(np.uint64), err_msg=f"row {r}"
+        )
+    assert not np.isnan(got).all(), "the case consolidates nothing"
+
+
 def test_fused_matches_doc_ids_and_order(plan_db):
     _seed(plan_db)
     eng = Engine(M3Storage(plan_db, "ns"))

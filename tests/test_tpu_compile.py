@@ -23,7 +23,9 @@ the CPU here, so the resident body is steered by monkeypatching
 ``m3_tpu.device.on_tpu`` — in the test, not through an option.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -175,9 +177,14 @@ def test_device_index_kernels(sds):
     ).compile()
 
 
-def test_one_dispatch_plan_program(sds):
+@pytest.mark.parametrize("t_grid", [128, 1024])
+def test_one_dispatch_plan_program(sds, t_grid):
     """query/plan._build_program for ``metric{tag="v"}`` (two exact leaves
-    ANDed) over a 16,384-doc segment, one block, a 128-step grid."""
+    ANDed) over a 16,384-doc segment, one block, onto a window query's
+    128-step grid and a read-back's 1,024. The byte bound is what forbids
+    stage 5's [cap, t_grid, t_pts] cube in memory (ONE u32 plane of it is
+    6.2 GB at 128 steps), the gather check the per-element loop it
+    replaced (functions/temporal.py, "window index machinery")."""
     from m3_tpu.query import plan
 
     o, pool = _pool_shapes(sds)
@@ -186,7 +193,6 @@ def test_one_dispatch_plan_program(sds):
     slab = n_docs
     ast = ("and", (("terms", 0, 1, 0, slab), ("terms", 1, 1, slab, slab)), ())
     lp, sl, tables = _plan_rows(sds, o, n_docs + 1)
-    t_grid = 128
     dims = (n_words, n_docs, n_docs, 1, CHUNKS, CHUNK_K, WINDOW_WORDS, lp, sl,
             o.page_words, o.side_page_chunks, t_grid)
     pair = (sds((), U32), sds((), U32))
@@ -202,3 +208,17 @@ def test_one_dispatch_plan_program(sds):
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12 << 30
+    # no gather reads a [cap, t_pts] plane of decoded points, in either
+    # layout or flattened. HLO prints a gather's operands by name, so the
+    # shapes come from the lines that define them.
+    text = compiled.as_text()
+    elems = {
+        name: math.prod(int(d) for d in shape.split(",") if d)
+        for name, shape in re.findall(
+            r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]*)\]", text, re.M
+        )
+    }
+    operands = re.findall(r"= \S+ gather\((%[^,\s)]+)", text)
+    assert operands, "the pool's page gather at least is expected"
+    points = n_docs * CHUNKS * CHUNK_K
+    assert not [name for name in operands if elems[name] == points]
