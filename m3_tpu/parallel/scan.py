@@ -476,16 +476,26 @@ def _resident_gather(pool_words, side_words, page_rows, side_rows,
     rel = off & 31
     tb = jnp.asarray(total_bits, jnp.int32)[si]
     nbits = jnp.where(valid, jnp.clip(tb - (w0 << 5), 0, cw * 32), 0)
-    # windows: two gathers — word position -> page (tiny int table), then
-    # page*W + word%W into the flat pool. Trailing zero-page columns in
-    # page_rows guarantee w0 + cw - 1 stays in range and reads zeros.
+    # windows: word position -> page (tiny int table), then page*W +
+    # word%W into the flat pool. Trailing zero-page columns in page_rows
+    # guarantee w0 + cw - 1 stays in range and reads zeros.
     j = jnp.arange(cw, dtype=jnp.int32)[None, :]
     wabs = w0[:, None] + j  # [N, CW] absolute word index within the lane
     # 2-D indices, NOT takes over flattened tables: on the TPU the
     # reshape of the pool to 1-D is a re-layout copy of the whole pool per
     # program, and the flat take from page_rows compiled in minutes (94 s
     # against 0.9 s at 8192 series)
-    page = page_rows[si[:, None], wabs // w]
+    # the page of each word: a window of cw words touches at most
+    # ceil((cw - 1) / w) + 1 consecutive pages (two, at the deployed 512-word
+    # page), so it is that many page ids a LANE and a select a word, where
+    # a page id a WORD was a second element-wise gather over [N, CW] (a
+    # fifth of the plan program's device time: PERF.md section 6, PR 29)
+    p0 = w0 // w
+    last = page_rows.shape[1] - 1
+    page = page_rows[si, p0][:, None]
+    for k in range(1, (w + cw - 2) // w + 1):
+        page = jnp.where(wabs // w - p0[:, None] == k,
+                         page_rows[si, jnp.minimum(p0 + k, last)][:, None], page)
     words = jnp.asarray(pool_words, jnp.uint32)[page, wabs % w]
     windows = jnp.where(valid[:, None], words, jnp.uint32(0))
     return planes, windows, rel, nbits, valid

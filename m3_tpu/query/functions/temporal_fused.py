@@ -116,10 +116,56 @@ def fused_temporal(values, window: int, step_seconds: float, funcs: tuple[str, .
     return tuple(outs)
 
 
+# ---------------------------------------------------------------------------
+# exact selections over float64
+# ---------------------------------------------------------------------------
+
+# min / max / last of a window are SELECTIONS: the answer is one of the
+# window's samples, so it can be exact in float64 though the device has no
+# float64. The f32 path above selects among samples already rounded to
+# f32, which is exact only where every sample is an f32 (TSBS cpu gauges:
+# integers in [0, 100]); counters, byte gauges beyond 2^24 and float64
+# percents are not, and for those numpy selects among the float64 samples
+# where the engine already holds them, on the host.
+SELECTIONS = ("min_over_time", "max_over_time", "last_over_time")
+
+
+def f32_exact(values: np.ndarray) -> bool:
+    """Whether every sample survives float64 -> float32 -> float64 (NaN,
+    the missing sample, counts as surviving)."""
+    with np.errstate(over="ignore"):  # beyond f32's range: inf, not equal
+        narrowed = values.astype(np.float32)
+    return np.array_equal(narrowed.astype(np.float64), values, equal_nan=True)
+
+
+def select_over_time(name: str, values: np.ndarray, window: int) -> np.ndarray:
+    """``min_over_time`` / ``max_over_time`` / ``last_over_time`` of a host
+    float64[S, T] matrix (NaN = missing), bit for bit: float64[S, T].
+    Window t covers columns [t-window+1, t]; ``window`` passes over the
+    matrix, nothing larger than it in memory."""
+    values = np.asarray(values, np.float64)
+    t = values.shape[1]
+    out = np.full_like(values, np.nan)
+    for back in range(min(window, t) - 1, -1, -1):  # the oldest column first
+        dst, src = out[:, back:], values[:, : t - back]
+        if name == "last_over_time":
+            np.copyto(dst, src, where=~np.isnan(src))
+        elif name == "max_over_time":
+            np.fmax(dst, src, out=dst)  # fmax / fmin: NaN only if both are
+        else:
+            np.fmin(dst, src, out=dst)
+    return out
+
+
 def temporal_apply(name: str, values, window: int, step_seconds: float):
     """Single-function entry used by the query engine: fused on TPU (the
     intermediates of even ONE rate call are ~25 HBM passes unfused),
-    unfused elsewhere."""
+    unfused elsewhere. A selection over host float64 samples that f32
+    cannot hold is exact (:func:`select_over_time`)."""
+    if (name in SELECTIONS and isinstance(values, np.ndarray)
+            and values.dtype == np.float64 and values.ndim == 2
+            and not f32_exact(values)):
+        return select_over_time(name, values, window)
     if name in FUSABLE and device.on_tpu() and values.shape[0] >= BLOCK_ROWS:
         return fused_temporal(values, window, step_seconds, (name,))[0]
     v = jnp.asarray(values, jnp.float32)

@@ -92,6 +92,22 @@ _M_COALESCED = METRICS.counter(
     "device scan (N concurrent identical fetches -> 1 dispatch)",
 )
 
+# the newest built plan's dimensions (PERF.md section 3): every lane of the
+# segment pays the widest lane's window, whatever matched
+_G_PLAN_DIMS = {
+    "window_words": METRICS.gauge(
+        "query_plan_window_words", "cw of the newest built plan: 32-bit "
+        "words a chunk's decode window spans, set by the segment's widest lane"),
+    "chunks": METRICS.gauge(
+        "query_plan_chunks", "chunks per lane of the newest built plan"),
+    "decode_slots": METRICS.gauge(
+        "query_plan_decode_slots", "lanes the newest built plan decodes a "
+        "dispatch (cap x blocks), whatever matched"),
+    "gather_words": METRICS.gauge(
+        "query_plan_gather_words", "words the newest built plan's window "
+        "gather moves a dispatch (decode_slots x chunks x window_words)"),
+}
+
 # the fused program's dispatch seam: compile attribution + sampled
 # wall-time under the SAME profiler contract as every other kernel, and
 # the per-query device_dispatches counter ticks here — exactly once per
@@ -102,6 +118,21 @@ _SENTINEL_GRID = 8  # minimum padded grid length
 # grid steps per compare-and-reduce pass of stage 5: a power of two no
 # larger than _SENTINEL_GRID, so it divides every padded grid
 _GRID_TILE = 8
+
+
+def _bucket_window_words(cw: int) -> int:
+    """``cw`` rounded up to four significant bits (a multiple of 1 below
+    16 words, of 2 below 32, of 4 below 64, of 8 below 128: at most an
+    eighth more). The widest chunk span of a segment follows the VALUES by
+    a word or two (TSBS devops at 40 hosts: 73, 74, 75 or 76 words over 60
+    seeds), every distinct width is a plan program to compile, and the
+    window gather's device time is proportional to it: the same deployment
+    runs the same program, and does the same work, whatever the samples
+    were. The price is the wider gather: 19-20 % a request where 73-74
+    became 80 (PERF.md section 6, PR 29), until the gather stops costing
+    by the word (ROADMAP S8)."""
+    step = 1 << max(cw.bit_length() - 4, 0)
+    return -(-cw // step) * step
 
 
 def plan_enabled() -> bool:
@@ -762,7 +793,7 @@ class Planner:
             raise Ineligible("no-resident-lanes")
 
         o = pool.options
-        cw = window_words(max_span)
+        cw = _bucket_window_words(window_words(max_span))
         extra = -(-cw // o.page_words) + 1
         lp = max_pages + extra
         sl = max_side
@@ -830,6 +861,11 @@ class Planner:
             jnp.asarray(t_bhi), jnp.asarray(t_blo),
         )
         entry.fn = _build_program(ast, entry.dims)
+        slots = entry.cap * n_blocks
+        for name, value in (("window_words", cw), ("chunks", c),
+                            ("decode_slots", slots),
+                            ("gather_words", slots * c * cw)):
+            _G_PLAN_DIMS[name].set(value)
         # matched-doc cache: the matched set is a pure function of the
         # segment arrays and the matcher values, both frozen while the
         # stamp holds — so the per-doc tag materialization (the cost that
@@ -843,6 +879,7 @@ class Planner:
     def _execute(self, entry, ns, fetch_lo: int, fetch_hi: int,
                  grid: np.ndarray, lookback_nanos: int):
         from ..index.device import kernels
+        from . import stats
 
         # plan.enqueue: the lease, the arguments and the dispatch
         # returning; plan.device_wait: the blocked read-back
@@ -924,4 +961,9 @@ class Planner:
             )
             datapoints = int(counts[:n].sum())
             err_rows = np.nonzero(err[:n])[0]
+            n_blocks, cw = entry.dims[3], entry.dims[6]
+            stats.add_plan(
+                lanes_decoded=entry.cap * n_blocks, series_matched=n,
+                window_words=cw,
+            )
         return matched, values, datapoints, err_rows

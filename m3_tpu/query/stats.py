@@ -101,6 +101,12 @@ class QueryStats:
     # JOINING another concurrent query's in-flight device scan — this
     # query paid zero dispatches for them
     plan_coalesced: int = 0
+    # what this query's own plan dispatches cost and found (a coalesced
+    # follower adds nothing): lanes decoded (cap x blocks, whatever
+    # matched), series matched, and the widest decode window in words
+    plan_lanes_decoded: int = 0
+    plan_series_matched: int = 0
+    plan_window_words: int = 0
     # profiled device-kernel dispatches charged to this query (the
     # KernelProfiler seam, utils/instrument.set_dispatch_counter): the
     # fused pipeline's acceptance metric — a warm plan-served query is
@@ -144,6 +150,9 @@ class QueryStats:
             "planMisses": self.plan_misses,
             "planFallbacks": self.plan_fallbacks,
             "planCoalesced": self.plan_coalesced,
+            "planLanesDecoded": self.plan_lanes_decoded,
+            "planSeriesMatched": self.plan_series_matched,
+            "planWindowWords": self.plan_window_words,
             "deviceDispatches": self.device_dispatches,
             "traceId": self.trace_id,
             "error": self.error,
@@ -361,6 +370,17 @@ def add(
     st.plan_misses += plan_misses
     st.plan_fallbacks += plan_fallbacks
     st.plan_coalesced += plan_coalesced
+
+
+def add_plan(lanes_decoded: int, series_matched: int, window_words: int) -> None:
+    """One plan dispatch of this thread's active query (query/plan.py
+    ``_execute``): sums, and the widest window of the query's fetches."""
+    st = current()
+    if st is None:
+        return
+    st.plan_lanes_decoded += lanes_decoded
+    st.plan_series_matched += series_matched
+    st.plan_window_words = max(st.plan_window_words, window_words)
 
 
 def _count_dispatch(_kernel: str) -> None:

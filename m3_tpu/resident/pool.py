@@ -183,6 +183,11 @@ class ResidentEntry(NamedTuple):
     max_span_bits: int = 0  # widest chunk span (window sizing)
 
 
+# decode bodies by a chunk's fast-chunk flags (ops/sideplane.py word 8,
+# bits 1:0): index = flags & 3; both bits never meet on one chunk
+CHUNK_BODIES = ("general", "int_fast", "float_fast")
+
+
 class AdmitResult(NamedTuple):
     admitted: int
     rejected_span: int  # lanes over the max_lane_pages span limit
@@ -316,6 +321,18 @@ class ResidentPool:
             "born-resident admission (O(40B/chunk) metadata; the DATA "
             "pages never cross PCIe)",
         )
+        # chunks admitted with side planes, by the decode body each lane's
+        # own flags ask for (packed side word 8, bits 1:0). The kernel picks
+        # a body per TILE: one general lane makes its whole tile general
+        self._m_chunks = {
+            body: reg.counter(
+                "resident_chunks_total",
+                "chunks admitted with side planes, by the decode body their "
+                "own fast-chunk flags ask for",
+                labels={"body": body},
+            )
+            for body in CHUNK_BODIES
+        }
         self._g_bytes = reg.gauge("resident_pool_bytes", "compressed bytes resident")
         self._g_pages = reg.gauge("resident_pool_pages", "pages in use (excl. zero page)")
         self._g_free = reg.gauge("resident_pool_free_pages", "pages on the free list")
@@ -592,14 +609,16 @@ class ResidentPool:
                         self._publish_locked()
                 raise
             # ---- publish ----
+            published_rows: list = []
             with self._lock:
                 survivors = 0
-                for key, entry, stream, _snaps in batch_entries:
+                for key, entry, stream, rows in batch_entries:
                     present = self._pending.get(key) is entry
                     if present:
                         del self._pending[key]
                     if present and key in staged_keys:
                         survivors += 1
+                        published_rows.append(rows)
                         self._od[key] = entry
                         self._index_locked(key)
                         self._resident_bytes += entry.nbytes
@@ -642,6 +661,7 @@ class ResidentPool:
                 if rejected_span + rejected_budget:
                     self._m_rejections.inc(rejected_span + rejected_budget)
                 self._publish_locked()
+        self._count_chunk_bodies(published_rows)
         return AdmitResult(admitted, rejected_span, rejected_budget, complete)
 
     def admit_block_device(
@@ -823,15 +843,17 @@ class ResidentPool:
                             self._free_side.extend(entry.side_pages)
                         self._publish_locked()
                 raise
+            published_rows: list = []
             with self._lock:
                 survivors = 0
                 dev_survivors = 0
-                for key, entry, src, _rows in batch_entries:
+                for key, entry, src, rows in batch_entries:
                     present = self._pending.get(key) is entry
                     if present:
                         del self._pending[key]
                     if present and key in staged_keys:
                         survivors += 1
+                        published_rows.append(rows)
                         if isinstance(src, int):
                             dev_survivors += 1
                         self._od[key] = entry
@@ -860,7 +882,20 @@ class ResidentPool:
                 if rejected_span + rejected_budget:
                     self._m_rejections.inc(rejected_span + rejected_budget)
                 self._publish_locked()
+        self._count_chunk_bodies(published_rows)
         return AdmitResult(admitted, rejected_span, rejected_budget, complete)
+
+    def _count_chunk_bodies(self, packed_rows: list) -> None:
+        """``resident_chunks_total{body}`` for one published batch: each
+        element is a lane's packed side rows (or None: no side planes)."""
+        rows = [r for r in packed_rows if r is not None and len(r)]
+        if not rows:
+            return
+        flags = np.concatenate([np.asarray(r)[:, 8] for r in rows]) & 3
+        n = np.bincount(flags, minlength=4)
+        for body, count in zip(CHUNK_BODIES, (n[0], n[1] + n[3], n[2])):
+            if count:
+                self._m_chunks[body].inc(int(count))
 
     def _upload_device(
         self, words_src, src_rows: list, dst_pages: list, host_rows: list,

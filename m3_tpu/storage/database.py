@@ -50,6 +50,28 @@ _M_ENCODE_FALLBACK = METRICS.counter(
     "timestamps, mixed int/float, delta overflows) — encoded by the host "
     "codec, riding the same fileset and admission batch",
 )
+# every sealed lane once, by who encoded it: the device kernel (kind int /
+# float: the body ops/encode.py classify_lane chose) or the host codec
+# (kind none); and every host-codec lane once more by why the classifier
+# refused it (LaneClass.reason; "device_ingest_off" where no classifier ran)
+
+
+def _seal_lanes(encoder: str, kind: str):
+    return METRICS.counter(
+        "seal_lanes_total", "lanes sealed, by encoder (device kernel / host "
+        "codec) and the kind the device encoder classified them as",
+        labels={"encoder": encoder, "kind": kind},
+    )
+
+
+def _seal_host_lanes(reason: str):
+    return METRICS.counter(
+        "seal_host_lanes_total", "lanes sealed by the host codec, by the "
+        "reason the device encoder's classifier refused them",
+        labels={"reason": reason},
+    )
+
+
 _M_ENCODE_BYTES = METRICS.counter(
     "encode_device_bytes_total",
     "compressed stream bytes produced by the device encode kernel",
@@ -587,17 +609,27 @@ class Shard:
         side_rows: dict[bytes, object] = {}
         host_items: list[tuple] = []
         eligible: list[tuple] = []
+        refused: list[tuple] = []
         for sid, bucket in buckets:
             t, v, u = bucket.merged_points()
-            kind = dev.classify_lane(t, v, u).kind
-            if kind == dev.KIND_NONE:
-                stream = bucket.merged_stream()
-                if stream:
-                    series[sid] = stream
-                    host_items.append((sid, stream, len(t)))
+            cls = dev.classify_lane(t, v, u)
+            if cls.kind == dev.KIND_NONE:
+                refused.append((sid, bucket, len(t), cls.reason))
             else:
-                eligible.append((sid, t, v, kind))
-        _M_ENCODE_FALLBACK.inc(len(host_items))
+                eligible.append((sid, t, v, cls.kind))
+        if refused:
+            reasons: dict[str, int] = {}
+            with TRACER.stage("seal.encode.host"):
+                for sid, bucket, n_points, reason in refused:
+                    stream = bucket.merged_stream()
+                    if stream:
+                        series[sid] = stream
+                        host_items.append((sid, stream, n_points))
+                        reasons[reason] = reasons.get(reason, 0) + 1
+            _M_ENCODE_FALLBACK.inc(len(host_items))
+            _seal_lanes("host", "none").inc(len(host_items))
+            for reason, n in reasons.items():
+                _seal_host_lanes(reason).inc(n)
         if not eligible:
             return series, side_rows, None
         pw = (
@@ -606,13 +638,16 @@ class Shard:
             else 1
         )
         lanes = [(c[1], c[2]) for c in eligible]
-        res = dev.encode_lanes(
-            lanes, [c[3] for c in eligible], k=CHUNK_K, round_words_to=pw
-        )
-        rows = dev.side_rows_for(res, lanes, bs)
-        streams = res.streams()
+        kinds = [c[3] for c in eligible]
+        with TRACER.stage("seal.encode.device"):
+            res = dev.encode_lanes(lanes, kinds, k=CHUNK_K, round_words_to=pw)
+            rows = dev.side_rows_for(res, lanes, bs)
+            streams = res.streams()
         _M_ENCODE_LANES.inc(len(eligible))
         _M_ENCODE_BYTES.inc(int(res.nbytes.sum()))
+        n_float = kinds.count(dev.KIND_FLOAT)
+        _seal_lanes("device", "int").inc(len(kinds) - n_float)
+        _seal_lanes("device", "float").inc(n_float)
         dev_items = []
         for m, (sid, t, v, kind) in enumerate(eligible):
             series[sid] = streams[m]
@@ -672,6 +707,8 @@ class Shard:
                         if stream
                     }
                     side_rows, dev_payload = {}, None
+                    _seal_lanes("host", "none").inc(len(series))
+                    _seal_host_lanes("device_ingest_off").inc(len(series))
             if not series:
                 continue
             fid = FilesetID(self.namespace, self.id, bs, volume=0)

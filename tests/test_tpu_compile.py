@@ -87,10 +87,11 @@ def _pool_shapes(sds):
     )
 
 
-def _plan_rows(sds, o, rows: int):
+def _plan_rows(sds, o, rows: int, lp: int | None = None):
     # page rows: one data page + the trailing zero-page columns a window
     # may read into; side rows: ceil(CHUNKS / side_page_chunks)
-    lp = 1 + -(-WINDOW_WORDS // o.page_words) + 1
+    if lp is None:
+        lp = 1 + -(-WINDOW_WORDS // o.page_words) + 1
     sl = -(-CHUNKS // o.side_page_chunks)
     return lp, sl, (
         sds((rows, lp), I32), sds((rows, sl), I32), sds((rows,), I32),
@@ -177,23 +178,21 @@ def test_device_index_kernels(sds):
     ).compile()
 
 
-@pytest.mark.parametrize("t_grid", [128, 1024])
-def test_one_dispatch_plan_program(sds, t_grid):
+def _compile_plan_program(sds, n_docs: int, cw: int, lane_pages: int, t_grid: int):
     """query/plan._build_program for ``metric{tag="v"}`` (two exact leaves
-    ANDed) over a 16,384-doc segment, one block, onto a window query's
-    128-step grid and a read-back's 1,024. The byte bound is what forbids
-    stage 5's [cap, t_grid, t_pts] cube in memory (ONE u32 plane of it is
-    6.2 GB at 128 steps), the gather check the per-element loop it
-    replaced (functions/temporal.py, "window index machinery")."""
+    ANDed) over an ``n_docs`` segment, one block of CHUNKS chunks whose
+    widest lane spans ``cw`` window words and ``lane_pages`` pool pages,
+    onto a ``t_grid``-step grid: the compiled program, held to the device's
+    memory and to no gather over a [cap, t_pts] plane of decoded points."""
     from m3_tpu.query import plan
 
     o, pool = _pool_shapes(sds)
-    n_docs = 16_384
     n_words = n_docs // 32
     slab = n_docs
     ast = ("and", (("terms", 0, 1, 0, slab), ("terms", 1, 1, slab, slab)), ())
-    lp, sl, tables = _plan_rows(sds, o, n_docs + 1)
-    dims = (n_words, n_docs, n_docs, 1, CHUNKS, CHUNK_K, WINDOW_WORDS, lp, sl,
+    lp = lane_pages + -(-cw // o.page_words) + 1
+    _, sl, tables = _plan_rows(sds, o, n_docs + 1, lp)
+    dims = (n_words, n_docs, n_docs, 1, CHUNKS, CHUNK_K, cw, lp, sl,
             o.page_words, o.side_page_chunks, t_grid)
     pair = (sds((), U32), sds((), U32))
     leaves = sds((2,), I32)
@@ -222,3 +221,32 @@ def test_one_dispatch_plan_program(sds, t_grid):
     assert operands, "the pool's page gather at least is expected"
     points = n_docs * CHUNKS * CHUNK_K
     assert not [name for name in operands if elems[name] == points]
+    # and ONE gather yields a word per window slot: the pool's. The page id
+    # of each word is two ids a lane and a select (parallel/scan.py
+    # _resident_gather), not a second gather over [lanes, cw]
+    slots = n_docs * CHUNKS * cw
+    per_word = re.findall(r"^\s*(?:ROOT )?(%\S+) = \w+\[[\d,]*\]\S* gather\(", text, re.M)
+    assert len([name for name in per_word if elems[name] == slots]) == 1
+    return compiled
+
+
+@pytest.mark.parametrize("t_grid", [128, 1024])
+def test_one_dispatch_plan_program(sds, t_grid):
+    """A 16,384-doc segment of int lanes onto a window query's 128-step
+    grid and a read-back's 1,024. The byte bound is what forbids stage 5's
+    [cap, t_grid, t_pts] cube in memory (ONE u32 plane of it is 6.2 GB at
+    128 steps), the gather check the per-element loop it replaced
+    (functions/temporal.py, "window index machinery")."""
+    _compile_plan_program(sds, 16_384, WINDOW_WORDS, 1, t_grid)
+
+
+@pytest.mark.parametrize("t_grid", [128, 1024])
+def test_plan_program_at_the_devops_cell_dimensions(sds, t_grid):
+    """``devops.haystack`` (BENCHMARK.json): 4,040 series pad to a cap of
+    4,064, 23 chunks, and the float64 lanes (5.3 KB a block: three 2 KiB
+    pages) set every lane's window: 73-76 words by the seed, which the plan
+    rounds to 80 (``plan._bucket_window_words``)."""
+    from m3_tpu.query.plan import _bucket_window_words
+
+    assert {_bucket_window_words(cw) for cw in (73, 74, 75, 76)} == {80}
+    _compile_plan_program(sds, 4_064, 80, 3, t_grid)

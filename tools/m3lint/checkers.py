@@ -425,9 +425,14 @@ class MetricNameDiscipline(Checker):
     # "file": fileset file roles ("data", "digest", "checkpoint", ...) —
     # bounded by fs.SUFFIXES; the m3tpu_storage_corruption_total family
     # keys on it so a scrub alert names WHICH file of a volume rotted.
+    # "encoder": who sealed a lane, "device" or "host" — two constants of
+    # storage/database.py (m3tpu_seal_lanes_total{encoder,kind}).
+    # "body": the decode body a chunk's own flags ask for — the three
+    # constants of resident/pool.py CHUNK_BODIES
+    # (m3tpu_resident_chunks_total{body}).
     LABEL_KEYS = {"component", "op", "peer", "to", "kernel", "kind", "stage",
                   "ns", "group", "tenant", "scope", "shard", "reason",
-                  "objective", "window", "file"}
+                  "objective", "window", "file", "encoder", "body"}
 
     def check_file(self, ctx: FileContext):
         for node in ast.walk(ctx.tree):

@@ -18,6 +18,11 @@ the window's start and close. After the run it reduces the capture with
   seal and read-backs): wall and calls only, no capture runs there;
 - ``counters``: the window's growth of the commit-log, RPC byte, recv-wait
   and compile counters, and the stack sampler's overhead ratio at its close;
+- ``labelled``: set-up's seal by encoder and refusal reason
+  (``m3tpu_seal_lanes_total``, ``m3tpu_seal_host_lanes_total``) and the
+  admitted chunks by decode body (``m3tpu_resident_chunks_total``), child by
+  child at the window's close; the plan's dimensions are among ``counters``
+  (``m3tpu_query_plan_*``);
 - ``compiles_before_window``: jax's own events as the benchmark's hook
   counts them beside the program's ``m3tpu_jit_compiles`` (they must agree);
 - ``gaps``: idle seconds of the device by host stage, each stage's total
@@ -53,7 +58,15 @@ FAMILIES = (
     "m3tpu_profile_overhead_seconds_total",
 )
 GAUGES = ("m3tpu_profile_overhead_ratio", "m3tpu_commitlog_queue_depth",
-          "m3tpu_device_peak_bytes_in_use")
+          "m3tpu_device_peak_bytes_in_use",
+          # the plan the window ran (PR 29): every lane of the segment pays
+          # the widest lane's window
+          "m3tpu_query_plan_window_words", "m3tpu_query_plan_chunks",
+          "m3tpu_query_plan_decode_slots", "m3tpu_query_plan_gather_words")
+# read child by child at the window's close: set-up's seal by encoder and
+# refusal reason, the admitted chunks by decode body (PR 29)
+LABELLED = ("m3tpu_seal_lanes_total", "m3tpu_seal_host_lanes_total",
+            "m3tpu_resident_chunks_total")
 
 
 def parse_exposition(text: str) -> dict[str, float]:
@@ -129,6 +142,9 @@ def main(argv=None) -> int:
                     help="any platform, a small fleet (benchmark/run.py --rehearse)")
     ap.add_argument("--hosts", type=int, default=None)
     args = ap.parse_args(argv)
+    # the reduction below runs from ROOT, whatever this process was started
+    # from: a relative --out must name the same file in both
+    args.out = os.path.abspath(args.out)
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -149,12 +165,12 @@ def main(argv=None) -> int:
     # the reduction in a process of its own, on the CPU: this one never
     # imports jax (a chip belongs to one process)
     gaps_path = args.out + ".gaps.json"
-    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
     proc = subprocess.run(
         [sys.executable, "-m", "m3_tpu.profiling.gaps", cell.capture_dir, gaps_path],
         env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
-        stdout=subprocess.DEVNULL, timeout=900)
-    gaps = {"error": f"gaps exited {proc.returncode}"}
+        stdout=subprocess.DEVNULL, stderr=subprocess.PIPE, text=True, timeout=900)
+    gaps = {"error": f"gaps exited {proc.returncode}: {proc.stderr[-2000:]}"}
     if os.path.exists(gaps_path):
         with open(gaps_path) as f:
             gaps = json.load(f)
@@ -171,6 +187,8 @@ def main(argv=None) -> int:
             **{name: family_total(m1, name) - family_total(m0, name) for name in FAMILIES},
             **{name: family_total(m1, name) for name in GAUGES},
         },
+        "labelled": {key: value for key, value in sorted(m1.items())
+                     if key.startswith(tuple(name + "{" for name in LABELLED))},
         "compiles_before_window": {
             "jax_events_hook": s0["compiles"], "m3tpu_jit_compiles": s0["m3tpu_jit_compiles"]},
         "device_profile_stop": cell.profile_stat,
