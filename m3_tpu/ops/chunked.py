@@ -456,6 +456,31 @@ def _fetch4_select(cols, cw, base_rel, pos, max_widx: int | None = None):
     return (sh(ws[0], ws[1]), sh(ws[1], ws[2]), sh(ws[2], ws[3]), ws[3] << r)
 
 
+def _point_step(fetch4, nb, nt0, first_vec, int_optimized, state):
+    """Decode ONE record for every lane: the next DecodeState and the
+    record's seven output planes (ts pair, value pair, point_is_float,
+    mult, valid). Shared by the lax.scan below and the Pallas point kernel
+    (ops/fused.decode_points_pallas), so both emit the same bits."""
+    was_active = ~state.done & ~state.err
+    state, _ = _decode_timestamp(fetch4, nb, state, first_vec, nt=nt0)
+    ts_active = ~state.done & ~state.err
+    state = _decode_value(fetch4, state, first_vec, int_optimized)
+    now_active = ~state.done & ~state.err
+    valid = was_active & ts_active & now_active
+    point_is_float = jnp.logical_or(not int_optimized, state.is_float)
+    val = u64.select(point_is_float, state.prev_float_bits, state.int_val)
+    out = (
+        state.prev_time[0],
+        state.prev_time[1],
+        val[0],
+        val[1],
+        point_is_float,
+        state.mult,
+        valid,
+    )
+    return state, out
+
+
 @functools.partial(jax.jit, static_argnames=("k", "int_optimized"))
 def decode_chunked_lanes(
     windows,
@@ -505,25 +530,9 @@ def decode_chunked_lanes(
     nt0 = _extract(fetch4(zero_pos), 0, 64)
 
     def step(state, idx):
-        first_vec = first_chunk & (idx == 0)
-        was_active = ~state.done & ~state.err
-        state, _ = _decode_timestamp(fetch4, nb, state, first_vec, nt=nt0)
-        ts_active = ~state.done & ~state.err
-        state = _decode_value(fetch4, state, first_vec, int_optimized)
-        now_active = ~state.done & ~state.err
-        valid = was_active & ts_active & now_active
-        point_is_float = jnp.logical_or(not int_optimized, state.is_float)
-        val = u64.select(point_is_float, state.prev_float_bits, state.int_val)
-        out = (
-            state.prev_time[0],
-            state.prev_time[1],
-            val[0],
-            val[1],
-            point_is_float,
-            state.mult,
-            valid,
+        return _point_step(
+            fetch4, nb, nt0, first_chunk & (idx == 0), int_optimized, state
         )
-        return state, out
 
     final_state, outs = jax.lax.scan(step, state, jnp.arange(k))
     ts_hi, ts_lo, val_hi, val_lo, pif, mlt, valid = outs

@@ -29,8 +29,9 @@ import numpy as np
 
 from ..utils.instrument import KernelProfiler
 from . import u64
-from .chunked import _fetch4_select, _window_columns
+from .chunked import _fetch4_select, _point_step, _window_columns
 from .decode import (
+    DecodeResult,
     DecodeState,
     _decode_timestamp,
     _decode_value,
@@ -769,4 +770,142 @@ def lane_aggregates_pallas(
     s_sum, s_cnt, s_min, s_max, s_last, s_err = (o.reshape(npad)[:n] for o in outs)
     return LaneAggregates(
         sum=s_sum, count=s_cnt, min=s_min, max=s_max, last=s_last, err=s_err != 0
+    )
+
+
+# ---------------------------------------------------------------------------
+# Pallas TPU kernel — decoded POINTS, not aggregates (query/plan.py stage 4)
+# ---------------------------------------------------------------------------
+
+# the seven per-record planes of chunked._point_step, in its order
+_POINT_PLANES = 7
+# the kernel's per-lane inputs, one u32 plane each of ONE packed array (a
+# pad and a DMA a program where 17 arrays were 17 of each)
+_POINT_LANE_PLANES = (
+    "rel_pos", "num_bits", "first",
+    "prev_time_hi", "prev_time_lo", "prev_delta_hi", "prev_delta_lo",
+    "prev_float_bits_hi", "prev_float_bits_lo", "prev_xor_hi", "prev_xor_lo",
+    "int_val_hi", "int_val_lo", "time_unit", "sig", "mult", "is_float",
+)
+
+
+def _points_kernel(k, cw, int_optimized, win_ref, lane_ref, *out_refs):
+    """One (8, 128) lane tile: the K-record loop of decode_chunked_lanes
+    with its state on-chip, every record's planes stored at row ``i`` of
+    the [K, ...] output blocks. The loop stays rolled: the window columns
+    are re-read from VMEM by every fetch (refs are not loop carries), so
+    the body's code does not grow with ``cw`` x ``k``."""
+    point_refs, err_ref = out_refs[:_POINT_PLANES], out_refs[_POINT_PLANES]
+    zero = jnp.zeros(LANE_TILE, U32)
+    ln = lambda name: lane_ref[_POINT_LANE_PLANES.index(name), 0]
+    i32 = lambda name: jax.lax.bitcast_convert_type(ln(name), I32)
+    pair = lambda name: (ln(name + "_hi"), ln(name + "_lo"))
+    rel_pos, num_bits = i32("rel_pos"), i32("num_bits")
+
+    def fetch4(pos):
+        cols = [win_ref[j, 0] for j in range(cw)] + [zero, zero, zero]
+        return _fetch4_select(cols, cw, rel_pos, pos)
+
+    state = _init_state(
+        rel_pos, num_bits, pair("prev_time"), pair("prev_delta"),
+        pair("prev_float_bits"), pair("prev_xor"), pair("int_val"),
+        i32("time_unit"), i32("sig"), i32("mult"), ln("is_float") != 0,
+    )
+    first_chunk_i32 = i32("first")
+    nb = num_bits - rel_pos
+    nt0 = _extract(fetch4(jnp.zeros_like(rel_pos)), 0, 64)
+
+    # Mosaic can't round-trip i1 vectors through a loop carry (see
+    # _run_lane_tile): bool state travels as int32
+    def pack(st):
+        return st._replace(
+            done=st.done.astype(I32), err=st.err.astype(I32),
+            is_float=st.is_float.astype(I32),
+        )
+
+    def unpack(st):
+        return st._replace(
+            done=st.done != 0, err=st.err != 0, is_float=st.is_float != 0
+        )
+
+    def body(i, st):
+        first_vec = (first_chunk_i32 * jnp.where(i == 0, I32(1), I32(0))) != 0
+        st, out = _point_step(
+            fetch4, nb, nt0, first_vec, int_optimized, unpack(st)
+        )
+        for ref, x in zip(point_refs, out):
+            ref[i, 0] = x.astype(ref.dtype)
+        return pack(st)
+
+    state = jax.lax.fori_loop(0, k, body, pack(state))
+    err_ref[0] = state.err
+
+
+@functools.partial(
+    jax.jit, static_argnames=("k", "int_optimized", "interpret")
+)
+def decode_points_pallas(
+    windows, rel_pos, num_bits, first, prev_time, prev_delta, prev_float_bits,
+    prev_xor, int_val, time_unit, sig, mult, is_float, k: int,
+    int_optimized: bool = True, interpret: bool = False,
+) -> DecodeResult:
+    """decode_chunked_lanes as ONE device operation: the same arguments,
+    the same [N, K] planes bit for bit (both run chunked._point_step), with
+    ``values_f32`` left None (the plan program never read it). The lax.scan
+    form costs ~29 small XLA operations a record, whatever the lane count;
+    at a few thousand lanes that overhead is the decode's whole time."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    windows = jnp.asarray(windows, U32)
+    n, cw = windows.shape
+    tiles = -(-n // TILE_LANES)
+    npad = tiles * TILE_LANES
+    planes = dict(
+        rel_pos=rel_pos, num_bits=num_bits, first=first, time_unit=time_unit,
+        sig=sig, mult=mult, is_float=is_float,
+    )
+    for name, p in (("prev_time", prev_time), ("prev_delta", prev_delta),
+                    ("prev_float_bits", prev_float_bits),
+                    ("prev_xor", prev_xor), ("int_val", int_val)):
+        planes[name + "_hi"], planes[name + "_lo"] = p
+
+    def u32(x):
+        x = jnp.asarray(x)
+        if x.dtype == jnp.bool_:
+            return x.astype(U32)
+        return jax.lax.bitcast_convert_type(x.astype(I32), U32) \
+            if x.dtype != U32 else x
+
+    def tiled(x):  # [..., N] -> [..., tiles, 8, 128], zero lanes appended
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, npad - n)])
+        return x.reshape(*x.shape[:-1], tiles, *LANE_TILE)
+
+    # windows transposed to [CW, tiles, 8, 128] so each column is a clean
+    # tile; padding lanes have num_bits 0 <= rel_pos 0: done from the start
+    w = tiled(windows.T)
+    lanes = tiled(jnp.stack([u32(planes[p]) for p in _POINT_LANE_PLANES]))
+    lane_spec = pl.BlockSpec((1, *LANE_TILE), lambda i: (i, 0, 0))
+    block = lambda rows: pl.BlockSpec((rows, 1, *LANE_TILE), lambda i: (0, i, 0, 0))
+    point_dtypes = (U32, U32, U32, U32, I32, I32, I32)
+    outs = pl.pallas_call(
+        functools.partial(_points_kernel, k, cw, int_optimized),
+        grid=(tiles,),
+        in_specs=[block(cw), block(len(_POINT_LANE_PLANES))],
+        out_specs=[block(k)] * _POINT_PLANES + [lane_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((k, tiles, *LANE_TILE), d) for d in point_dtypes
+        ] + [jax.ShapeDtypeStruct((tiles, *LANE_TILE), I32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
+        interpret=interpret,
+    )(w, lanes)
+    ts_hi, ts_lo, val_hi, val_lo, pif, mlt, valid = (
+        o.reshape(k, npad)[:, :n].T for o in outs[:_POINT_PLANES]
+    )
+    return DecodeResult(
+        ts_hi=ts_hi, ts_lo=ts_lo, val_hi=val_hi, val_lo=val_lo,
+        point_is_float=pif != 0, mult=mlt, valid=valid != 0,
+        err=outs[_POINT_PLANES].reshape(npad)[:n] != 0, values_f32=None,
     )

@@ -443,7 +443,7 @@ RESIDENT_CHUNKED_PROF = KernelProfiler("resident_chunked_assemble")
 
 def _resident_gather(pool_words, side_words, page_rows, side_rows,
                      n_chunks, total_bits, block_hi, block_lo,
-                     si, ci, cw: int, w: int, spc: int):
+                     si, ci, cw: int, w: int, spc: int, per_lane=None):
     """Shared gather core for both lane layouts: (si, ci) lane->chunk
     coordinate vectors -> (planes dict, windows [N, CW], rel, nbits,
     valid). ``planes`` are the decoder-state lane planes unpacked from
@@ -451,30 +451,40 @@ def _resident_gather(pool_words, side_words, page_rows, side_rows,
     off the per-series block_start pair). Every array is built to be
     BIT-IDENTICAL to what ops/chunked.assemble_chunked produces for the
     same streams (windows zeroed on invalid lanes, all-zero state for
-    padding) so the shared decode programs yield bit-identical results."""
+    padding) so the shared decode programs yield bit-identical results.
+
+    ``per_lane(x)`` maps a per-series table [S] to its per-lane values
+    and ``per_lane(x, col)`` picks column ``col`` [N] of each lane's row of
+    an [S, X] table: ``x[si]`` and ``x[si, col]`` where not given. A caller
+    whose ``si`` is known when it traces passes the same thing without a
+    gather over the series: on the TPU ``x[si]`` is an element-wise loop,
+    or (small S) a select chain over one mask a series, each mask a device
+    operation of its own."""
     from ..ops.sideplane import SIDE_WORDS, unpack_side_planes
+
+    if per_lane is None:
+        per_lane = lambda x, col=None: x[si] if col is None else x[si, col]
 
     page_rows = jnp.asarray(page_rows, jnp.int32)
     side_rows = jnp.asarray(side_rows, jnp.int32)
-    sl = side_rows.shape[1]
-    valid = ci < jnp.asarray(n_chunks, jnp.int32)[si]
+    valid = ci < per_lane(jnp.asarray(n_chunks, jnp.int32))
     # side slot: page-granular indirection (chunk ci sits at slot ci%spc
     # of side page ci//spc); invalid lanes hit reserved zero page 0
-    sp = jnp.take(side_rows.reshape(-1), si * sl + jnp.where(valid, ci, 0) // spc)
+    sp = per_lane(side_rows, jnp.where(valid, ci, 0) // spc)
     slot = jnp.where(valid, sp * spc + ci % spc, 0)
     side = jnp.take(
         jnp.asarray(side_words, jnp.uint32).reshape(-1, SIDE_WORDS),
         slot, axis=0,
     )  # [N, SIDE_WORDS] packed rows
     bs = (
-        jnp.asarray(block_hi, jnp.uint32)[si],
-        jnp.asarray(block_lo, jnp.uint32)[si],
+        per_lane(jnp.asarray(block_hi, jnp.uint32)),
+        per_lane(jnp.asarray(block_lo, jnp.uint32)),
     )
     planes = unpack_side_planes(side, bs, valid)
     off = planes["off"].astype(jnp.int32)
     w0 = off >> 5
     rel = off & 31
-    tb = jnp.asarray(total_bits, jnp.int32)[si]
+    tb = per_lane(jnp.asarray(total_bits, jnp.int32))
     nbits = jnp.where(valid, jnp.clip(tb - (w0 << 5), 0, cw * 32), 0)
     # windows: word position -> page (tiny int table), then page*W +
     # word%W into the flat pool. Trailing zero-page columns in page_rows
@@ -492,10 +502,11 @@ def _resident_gather(pool_words, side_words, page_rows, side_rows,
     # fifth of the plan program's device time: PERF.md section 6, PR 29)
     p0 = w0 // w
     last = page_rows.shape[1] - 1
-    page = page_rows[si, p0][:, None]
+    page = per_lane(page_rows, p0)[:, None]
     for k in range(1, (w + cw - 2) // w + 1):
         page = jnp.where(wabs // w - p0[:, None] == k,
-                         page_rows[si, jnp.minimum(p0 + k, last)][:, None], page)
+                         per_lane(page_rows, jnp.minimum(p0 + k, last))[:, None],
+                         page)
     words = jnp.asarray(pool_words, jnp.uint32)[page, wabs % w]
     windows = jnp.where(valid[:, None], words, jnp.uint32(0))
     return planes, windows, rel, nbits, valid
@@ -512,9 +523,19 @@ def _assemble_resident_lanes_traced(pool_words, side_words, page_rows,
     lane = jnp.arange(n, dtype=jnp.int32)
     si = lane // c
     ci = lane % c
+    # series-major lanes: a series' row c times over and a pick along
+    # it, no gather over the series
+    def per_lane(x, col=None):
+        rows = jnp.broadcast_to(
+            x[:, None], (s, c) + x.shape[1:]
+        ).reshape((n,) + x.shape[1:])
+        if col is None:
+            return rows
+        return jnp.take_along_axis(rows, col[:, None], axis=1, mode="clip")[:, 0]
+
     planes, windows, rel, nbits, valid = _resident_gather(
         pool_words, side_words, page_rows, side_rows, n_chunks, total_bits,
-        block_hi, block_lo, si, ci, cw, w, spc,
+        block_hi, block_lo, si, ci, cw, w, spc, per_lane=per_lane,
     )
     return dict(
         windows=windows,

@@ -12,12 +12,16 @@ them inside ONE jit program per query *shape*:
 
     term binary-search match  (index/device/kernels.match_terms_traced)
       → postings bitmaps + bitwise AST set algebra (same kernels)
-      → matched-doc compaction (cumsum over the doc bitmap)
+      → matched-doc compaction (cumsum over the doc bitmap) into ``cap``
+        decode slots: the power-of-two bucket of the matched count, which
+        one index resolve at plan build takes (a full match is the
+        bitmap's width)
       → per-lane page-table gather  (plan tables uploaded once per
         (segment, block set) and cached)
-      → resident chunked decode  (parallel/scan assembly +
-        ops/chunked.decode_chunked_lanes, straight from the pool's
-        pages + packed side planes)
+      → resident chunked decode  (parallel/scan assembly, then on the
+        chip ops/fused.decode_points_pallas, ONE device operation, and
+        off it the same step as ops/chunked.decode_chunked_lanes'
+        lax.scan; straight from the pool's pages + packed side planes)
       → step-grid consolidation  (per step the newest valid point at
         or before it: a masked max over the point axis and one-hot
         select-sums, u64-pair compares, no gather — TPU gathers lower
@@ -92,8 +96,19 @@ _M_COALESCED = METRICS.counter(
     "device scan (N concurrent identical fetches -> 1 dispatch)",
 )
 
-# the newest built plan's dimensions (PERF.md section 3): every lane of the
-# segment pays the widest lane's window, whatever matched
+
+def _plan_builds(cap: int):
+    return METRICS.counter(
+        "query_plan_builds_total", "device query plans built, by decode "
+        "capacity: the power-of-two bucket of the matched-series count (one "
+        "compiled program a query shape and bucket)",
+        labels={"cap": str(cap)},
+    )
+
+
+# the newest built plan's dimensions (PERF.md section 3): every decoded
+# lane pays the window of the segment's widest lane, and the lanes decoded
+# are the bucket of what matched (``cap``), not the segment
 _G_PLAN_DIMS = {
     "window_words": METRICS.gauge(
         "query_plan_window_words", "cw of the newest built plan: 32-bit "
@@ -102,7 +117,8 @@ _G_PLAN_DIMS = {
         "query_plan_chunks", "chunks per lane of the newest built plan"),
     "decode_slots": METRICS.gauge(
         "query_plan_decode_slots", "lanes the newest built plan decodes a "
-        "dispatch (cap x blocks), whatever matched"),
+        "dispatch (cap x blocks; cap is the matched-series count rounded up "
+        "to a power of two)"),
     "gather_words": METRICS.gauge(
         "query_plan_gather_words", "words the newest built plan's window "
         "gather moves a dispatch (decode_slots x chunks x window_words)"),
@@ -115,9 +131,16 @@ _G_PLAN_DIMS = {
 PROF = KernelProfiler("query_plan")
 
 _SENTINEL_GRID = 8  # minimum padded grid length
-# grid steps per compare-and-reduce pass of stage 5: a power of two no
-# larger than _SENTINEL_GRID, so it divides every padded grid
+_MIN_CAP = 8  # minimum decode capacity (matched-series slots)
+# grid steps per compare-and-reduce pass of stage 5, at least: a power of
+# two no larger than _SENTINEL_GRID, so it divides every padded grid
 _GRID_TILE = 8
+# [cap, steps, t_pts] compare cells a pass of stage 5 walks: what a
+# whole-segment plan walks at _GRID_TILE steps a pass (4,064 x 8 x 736 =
+# 2^24.5, the size measured best on the v5e: PERF.md, PR 28). A plan whose
+# cap follows a small match takes more steps a pass, the whole grid where
+# it fits: each pass is ~13 device operations whatever its size
+_GRID_PASS_CELLS = 1 << 25
 
 
 def _bucket_window_words(cw: int) -> int:
@@ -297,8 +320,10 @@ def _consolidate_last(ts, planes, valid, g, flo, fhi, lb):
     axis, and each plane rides along as a one-hot select-sum. Integer
     compares, selects and adds only. XLA fuses broadcast, compare and
     reduce, so the [cap, t_grid, t_pts] cube never exists in memory;
-    lax.map walks the grid _GRID_TILE steps at a time (measured faster
-    on the v5e than one reduce over the whole grid: PERF.md, PR 28)."""
+    lax.map walks the grid _GRID_PASS_CELLS compare cells at a time
+    (_GRID_TILE steps for a whole segment: measured faster on the v5e
+    than one reduce over the whole grid, PERF.md, PR 28; the whole grid
+    in one pass where cap is small)."""
     import jax
     import jax.numpy as jnp
 
@@ -325,10 +350,15 @@ def _consolidate_last(ts, planes, valid, g, flo, fhi, lb):
         ok = (pick >= 0) & u64.lt_u(age, lb)
         return tuple(sel(x) for x in planes), ok
 
-    out, ok = jax.lax.map(
-        tile, tuple(x.reshape(-1, _GRID_TILE) for x in g)
-    )
-    # [tiles, cap, _GRID_TILE] -> [cap, t_grid]
+    t_grid = g[0].shape[0]
+    # both powers of two, so the pass divides the padded grid
+    fit = _GRID_PASS_CELLS // max(valid.size, 1)
+    steps = min(t_grid, max(_GRID_TILE, 1 << max(fit.bit_length() - 1, 0)))
+    if steps == t_grid:
+        out, ok = tile(g)
+        return counts, out, ok
+    out, ok = jax.lax.map(tile, tuple(x.reshape(-1, steps) for x in g))
+    # [passes, cap, steps] -> [cap, t_grid]
     flat = lambda x: jnp.moveaxis(x, 0, 1).reshape(x.shape[1], -1)
     return counts, tuple(flat(x) for x in out), flat(ok)
 
@@ -349,12 +379,20 @@ def _build_program(ast, dims):
         bitmap_from_terms_traced,
         match_terms_traced,
     )
+    from .. import device
     from ..ops.chunked import decode_chunked_lanes
+    from ..ops.fused import decode_points_pallas
     from ..parallel.scan import _assemble_resident_lanes_traced
 
     (n_words, n_docs_pad, cap, n_blocks, c, k, cw, lp, sl,
      page_words, spc, t_grid) = dims
     t_pts = n_blocks * c * k
+    # on the chip the K-record decode is one Pallas operation; the lax.scan
+    # form (the same step, the same bits) is ~29 small operations a record
+    # whatever the lane count, and since cap follows the match that
+    # overhead was the decode's whole time (and 2/3 of a request's device
+    # operations: PERF.md section 5, PR 30)
+    decode = decode_points_pallas if device.on_tpu() else decode_chunked_lanes
 
     def program(term_keys, term_lens, post_idx, post_data, all_words,
                 q_keys, q_lens, q_lo, q_hi, r_lo, r_hi,
@@ -439,7 +477,7 @@ def _build_program(ast, dims):
             t_bits[lane_rows], t_bhi[lane_rows], t_blo[lane_rows],
             c=c, cw=cw, w=page_words, spc=spc,
         )
-        res = decode_chunked_lanes(**kw, k=k)
+        res = decode(**kw, k=k)
 
         rs = lambda x: x.reshape(cap, t_pts)
         ts = (rs(res.ts_hi), rs(res.ts_lo))
@@ -519,6 +557,10 @@ class Planner:
         # scan coalescing (singleflight): identical concurrent fetches
         # keyed by (plan key, window, grid) share ONE gathered dispatch
         self._flights: dict[tuple, _Flight] = {}
+        # plan key -> the matched count a plan's own program reported when
+        # it exceeded the capacity its build had counted (_drop): the
+        # rebuild's floor, so a persistent disagreement costs one fallback
+        self._reported: dict[tuple, int] = {}
         # cache stats for /debug surfaces
         self.hits = 0
         self.misses = 0
@@ -548,7 +590,18 @@ class Planner:
             ]
             for k in stale:
                 del self._cache[k]
+            if stale:
+                self._reported.clear()  # counts of segments that are gone
         return len(stale)
+
+    def _drop(self, entry, n_matched: int) -> None:
+        """Forget a cached plan whose stamp still holds (``_execute``'s
+        capacity guard): the next request for its key rebuilds, at no less
+        than the ``n_matched`` this plan's program counted."""
+        with self._lock:
+            for k in [k for k, e in self._cache.items() if e is entry]:
+                del self._cache[k]
+                self._reported[k] = n_matched
 
     def run(self, matchers, fetch_lo: int, fetch_hi: int, grid: np.ndarray,
             lookback_nanos: int):
@@ -659,7 +712,8 @@ class Planner:
                 entry, ns, fetch_lo, fetch_hi, grid, lookback_nanos
             )
         with TRACER.stage("plan.build"):
-            entry = self._build(q, seg, arrays, ns, pool, blocks, t_grid)
+            entry = self._build(q, seg, arrays, ns, pool, blocks, t_grid,
+                                self._reported.get(key, 0))
         with self._lock:
             self._cache[key] = entry
             self._cache.move_to_end(key)
@@ -730,11 +784,13 @@ class Planner:
 
     # -- build -------------------------------------------------------------
 
-    def _build(self, q, seg, arrays, ns, pool, blocks, t_grid) -> _PlanEntry:
+    def _build(self, q, seg, arrays, ns, pool, blocks, t_grid,
+               n_reported: int = 0) -> _PlanEntry:
         import jax.numpy as jnp
 
         from ..cache.block_cache import BlockKey
         from ..index.device import kernels
+        from ..index.query import search_segment
         from ..ops.chunked import window_words
 
         # stamp BEFORE the page-table walk: an eviction racing the walk
@@ -840,10 +896,18 @@ class Planner:
         entry.ast = ast
         entry.seg = seg
         entry.arrays = arrays
-        # today cap == n_docs_pad (decode capacity = bitmap width); cap
-        # is the seam an adaptive-capacity policy would shrink for
-        # persistently sparse matches
-        entry.cap = n_docs_pad
+        # decode capacity follows what matched, not the segment: the
+        # matched set is a pure function of the segment arrays and the
+        # matcher values, both frozen while the stamp holds, so one index
+        # resolve a build counts it (on the device; on the host where the
+        # tier answers None). Stage 3 compacts the matched docs into the
+        # first n of cap slots; a power of two, so matchers whose counts
+        # share a bucket share a compiled program, and a full match is
+        # the whole-segment program. n_reported: what this key's last
+        # plan counted in its own program when that exceeded its cap
+        n_matched = max(len(search_segment(seg, q)), n_reported)
+        entry.cap = min(n_docs_pad, pad_pow2(n_matched, _MIN_CAP))
+        _plan_builds(entry.cap).inc()
         entry.chunk_k = chunk_k
         entry.stamp = stamp
         entry.dims = (
@@ -937,9 +1001,12 @@ class Planner:
         with TRACER.stage("plan.finalize"):
             n = int(n_matched)
             if n > entry.cap:
-                # more matches than the compiled capacity (a doc-count jump
-                # since build): fall back for THIS query; the stamp check
-                # rebuilds at the larger size next time
+                # more matches than the capacity the build counted (the two
+                # resolves of one frozen segment disagree: not expected):
+                # fall back for THIS query and drop the entry, whose stamp
+                # still holds, so the next request rebuilds, at the count
+                # the program gave
+                self._drop(entry, n)
                 raise Ineligible("plan-capacity")
             if entry.matched is not None and len(entry.matched[0]) == n:
                 matched = entry.matched

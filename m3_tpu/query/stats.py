@@ -102,8 +102,9 @@ class QueryStats:
     # query paid zero dispatches for them
     plan_coalesced: int = 0
     # what this query's own plan dispatches cost and found (a coalesced
-    # follower adds nothing): lanes decoded (cap x blocks, whatever
-    # matched), series matched, and the widest decode window in words
+    # follower adds nothing): lanes decoded (cap x blocks; cap is the
+    # power-of-two bucket of the plan's matched count), series matched,
+    # and the widest decode window in words
     plan_lanes_decoded: int = 0
     plan_series_matched: int = 0
     plan_window_words: int = 0

@@ -147,16 +147,19 @@ def test_every_value_class_answers_bit_for_bit(served, cls, fn):
 
 
 def test_reply_is_plan_served_and_says_what_it_decoded(served):
+    from m3_tpu.index.device.kernels import pad_pow2
+
     reply, got = served.ask("max_over_time", "mem_used_percent")
     assert got["differ"] == 0
     st = reply["stats"]
     assert st["planHits"] + st["planMisses"] >= 1 and st["planFallbacks"] == 0
     assert st["residentMisses"] == 0 and st["indexDeviceMisses"] == 0
-    # every lane of the segment is decoded whatever matched, at the window
-    # of its widest lane: the float64 percents
-    n_series = len(served.table)
-    assert n_series <= st["planLanesDecoded"] < n_series + 32
+    # the lanes decoded are the power-of-two bucket of what matched (never
+    # under 8), not the segment's series; each at the window of the
+    # segment's widest lane: the float64 percents
     assert st["planSeriesMatched"] == HOSTS
+    assert st["planLanesDecoded"] == pad_pow2(HOSTS, 8)
+    assert st["planLanesDecoded"] < len(served.table)
     gauges = {name: family("query_plan_" + name)[()] for name in (
         "window_words", "chunks", "decode_slots", "gather_words")}
     assert st["planWindowWords"] == gauges["window_words"] > 60

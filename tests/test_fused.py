@@ -355,3 +355,27 @@ def test_fused_auto_backend_on_cpu_is_jnp():
     # On the CI CPU mesh this would raise in lowering if 'pallas' were chosen.
     out = _fused(batch, args, "auto")
     _assert_matches(out, _oracle(batch, args))
+
+
+@pytest.mark.parametrize("kind,k", [("gauge", 16), ("float", 32), ("counter", 32)])
+def test_point_kernel_interpret_bit_identical_to_the_scan(kind, k):
+    """ops/fused.decode_points_pallas (the plan program's decode on the
+    chip, ONE device operation) against ops/chunked.decode_chunked_lanes
+    (the lax.scan it stands in for): every plane of every record, bit for
+    bit, over int-optimised, full-precision float and counter lanes, with
+    a lane count that is no multiple of the 1,024-lane tile."""
+    from m3_tpu.ops import fused
+    from m3_tpu.ops.chunked import decode_chunked_lanes, lane_kwargs
+
+    streams = synthetic_streams(24, 97, seed=11, kind=kind)
+    batch = tile_chunked(build_chunked(streams, k=k), 56)
+    kw = lane_kwargs(batch)
+    want = decode_chunked_lanes(**kw, k=k)
+    got = fused.decode_points_pallas(**kw, k=k, interpret=True)
+    assert int(np.asarray(want.valid).sum()) == 56 * 97
+    for field in want._fields:
+        if field == "values_f32":  # not computed: the plan never read it
+            continue
+        a, b = np.asarray(getattr(want, field)), np.asarray(getattr(got, field))
+        assert a.dtype == b.dtype and a.shape == b.shape, field
+        np.testing.assert_array_equal(a, b, err_msg=field)

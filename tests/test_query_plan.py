@@ -23,6 +23,7 @@ from m3_tpu.query.promql import Matcher
 from m3_tpu.resident.pool import ResidentOptions
 from m3_tpu.rules.rules import encode_tags_id
 from m3_tpu.storage.database import Database, NamespaceOptions
+from m3_tpu.utils.instrument import DEFAULT as METRICS
 
 NANOS = 1_000_000_000
 HOUR = 3600 * NANOS
@@ -401,6 +402,127 @@ def test_plan_cache_hits_and_lru(plan_db):
     assert len(storage.planner._cache) == 1
 
 
+# ---------------------------------------------------------------------------
+# decode capacity: the power-of-two bucket of what matched
+# ---------------------------------------------------------------------------
+
+# (value of the ``grp`` tag, series carrying it): disjoint groups of one
+# field, so matchers that differ only in the value share an AST shape
+GROUPS = (("n1", 1), ("n7", 7), ("n8", 8), ("n9", 9), ("n40", 40), ("rest", 5))
+N_GROUPED = sum(n for _, n in GROUPS)  # 70 docs: a 96-bit bitmap, not a power of two
+
+
+@pytest.fixture(scope="module")
+def bucket_db(tmp_path_factory):
+    db = Database(
+        str(tmp_path_factory.mktemp("buckets") / "db"),
+        num_shards=2,
+        commitlog_enabled=False,
+        resident_options=ResidentOptions(max_bytes=16 << 20),
+        index_device_options=IndexDeviceOptions(max_bytes=64 << 20),
+    )
+    db.create_namespace("ns", NamespaceOptions(block_size_nanos=HOUR))
+    rng = np.random.default_rng(30)
+    i = 0
+    for grp, n in GROUPS:
+        for _ in range(n):
+            tags = ((b"__name__", b"pm"), (b"grp", grp.encode()), (b"s", b"%03d" % i))
+            sid = encode_tags_id(tags)
+            db.write_tagged("ns", tags, T0, float(i))
+            vals = rng.standard_normal(47) if i % 2 else rng.integers(0, 9, 47)
+            db.write_batch("ns", [
+                (sid, T0 + (j + 1) * STEP, float(v)) for j, v in enumerate(vals)])
+            i += 1
+    db.flush("ns", T0 + 4 * HOUR)
+    storage = M3Storage(db, "ns")
+    yield storage, Engine(storage)
+    db.close()
+
+
+def _plan_builds_by_cap() -> dict:
+    fam = METRICS.collect().get("m3tpu_query_plan_builds_total", {"children": []})
+    return {int(c["labels"]["cap"]): c["value"] for c in fam["children"]}
+
+
+@pytest.mark.parametrize("query,matched,cap", [
+    ('pm{grp="n1"}', 1, 8),
+    ('pm{grp="n7"}', 7, 8),
+    ('pm{grp="n8"}', 8, 8),
+    ('pm{grp="n9"}', 9, 16),
+    ('pm{grp="n40"}', 40, 64),
+    # every doc of the segment: the bitmap's width, today's program
+    ("pm", N_GROUPED, 96),
+])
+def test_decode_capacity_is_the_bucket_of_what_matched(bucket_db, query, matched, cap):
+    storage, eng = bucket_db
+    built = _plan_builds_by_cap().get(cap, 0)
+    st = _assert_bitexact(eng, query, SPAN)
+    d = st.to_dict()
+    assert d["planSeriesMatched"] == matched and d["planFallbacks"] == 0
+    assert d["planLanesDecoded"] == cap * 1  # one block
+    entry = next(reversed(storage.planner._cache.values()))  # the newest used
+    n_docs_pad = entry.dims[1]
+    assert n_docs_pad == 96 and entry.dims[2] == entry.cap == cap
+    assert _plan_builds_by_cap()[cap] == built + 1
+
+
+# four steps: a padded grid no other test of this module compiles for, so
+# the programs counted below are built here
+SHORT_SPAN = (T0 + 60 * NANOS, T0 + 120 * NANOS, 20 * NANOS)
+
+
+def test_matchers_in_one_bucket_share_a_program(bucket_db):
+    _storage, eng = bucket_db
+    programs = lambda: qplan._build_program.cache_info().misses
+    before = programs()
+    _assert_bitexact(eng, 'pm{grp="n1"}', SHORT_SPAN)
+    assert programs() == before + 1
+    _assert_bitexact(eng, 'pm{grp="n8"}', SHORT_SPAN)  # 1 and 8 match: cap 8 both
+    assert programs() == before + 1
+    _assert_bitexact(eng, 'pm{grp="n9"}', SHORT_SPAN)  # 9 match: cap 16
+    assert programs() == before + 2
+
+
+def test_more_matches_than_capacity_falls_back_once_and_rebuilds(bucket_db, monkeypatch):
+    import m3_tpu.index.query as iq
+
+    storage, eng = bucket_db
+    q = 'pm{grp="n9",s=~".*"}'  # a matcher set no other test has cached
+    real = iq.search_segment
+    # the build's count and the program's disagree, and go on disagreeing
+    # (in every plan build, not in the staged path's own resolve): cap 8
+    # for 9 matches
+    building, real_build = [], storage.planner._build
+
+    def build(*a, **kw):
+        building.append(1)
+        try:
+            return real_build(*a, **kw)
+        finally:
+            building.pop()
+
+    monkeypatch.setattr(storage.planner, "_build", build)
+    monkeypatch.setattr(
+        iq, "search_segment",
+        lambda seg, query, *a, **kw: real(seg, query, *a, **kw)[:3 if building else None])
+    cached = len(storage.planner._cache)
+    vf, mf, st = _run(eng, q, SPAN, explain=True)
+    assert st.plan_fallbacks == 1 and st.plan_misses == 1
+    reasons = [r["reason"] for r in st.routing if r["path"] == "staged"]
+    assert reasons == ["plan:plan-capacity"]
+    assert len(storage.planner._cache) == cached  # the entry is gone
+    vs, ms, _ = _run(eng, q, SPAN, staged=True)
+    assert mf == ms and len(mf) == 9
+    assert ((vf == vs) | (np.isnan(vf) & np.isnan(vs))).all()
+    # the next request rebuilds at the count the program gave (not the
+    # build's own, which is still 3) and is plan-served: one fallback, not
+    # one a request
+    st = _assert_bitexact(eng, q, SPAN)
+    assert st.plan_misses == 1 and st.plan_fallbacks == 0
+    assert st.to_dict()["planLanesDecoded"] == 16
+    assert len(storage.planner._cache) == cached + 1
+
+
 def test_plan_invalidates_on_volume_bump(plan_db):
     from m3_tpu.codec.m3tsz import Encoder
     from m3_tpu.storage.fs import FilesetID, write_fileset
@@ -753,3 +875,40 @@ def test_batched_leaf_match_across_segments(tmp_path):
     assert ctr.value == before + 1  # ONE launch for three segments
     assert dev == host and len(dev) == 24
     db.close()
+
+@pytest.mark.parametrize("query,cap", [('pm{grp="n40"}', 64), ("pm", 96)])
+def test_the_chips_point_kernel_serves_the_same_answers(bucket_db, monkeypatch, query, cap):
+    """On the chip stage 4 decodes with ops/fused.decode_points_pallas (one
+    device operation) where the CPU runs the lax.scan: here the plan's
+    program is built as on the chip, with the kernel interpreted, and the
+    reply compared bit for bit with the staged path over float and int
+    lanes, at a bucketed capacity and at the whole segment's."""
+    import functools
+
+    import m3_tpu.device as device
+    import m3_tpu.ops.fused as fused
+
+    storage, eng = bucket_db
+    kernel, traced = fused.decode_points_pallas, []
+
+    def interpreted(*a, **kw):
+        traced.append(kw["k"])
+        return kernel(*a, **kw, interpret=True)
+
+    monkeypatch.setattr(fused, "decode_points_pallas", interpreted)
+    real_build = qplan._build_program
+
+    @functools.wraps(real_build)
+    def build_as_on_the_chip(ast, dims):
+        with monkeypatch.context() as m:
+            m.setattr(device, "on_tpu", lambda: True)
+            return real_build.__wrapped__(ast, dims)
+
+    monkeypatch.setattr(qplan, "_build_program", build_as_on_the_chip)
+    storage.planner._cache.clear()
+    try:
+        st = _assert_bitexact(eng, query, SPAN)
+        assert st.plan_misses == 1 and st.to_dict()["planLanesDecoded"] == cap
+        assert traced, "the program was not built with the point kernel"
+    finally:
+        storage.planner._cache.clear()  # no later test meets this program

@@ -91,7 +91,9 @@ def test_soak_smoke(db, tmp_path):
 
         # the loop is closed when availability has a recorded ratio and
         # the probes have run: poll the live status surface
-        deadline = time.monotonic() + 35
+        # leaves as soon as both hold (about 10 s alone); the room is for a
+        # machine shared with five other test workers
+        deadline = time.monotonic() + 90
         avail = dura = None
         while time.monotonic() < deadline:
             rows = {r["name"]: r

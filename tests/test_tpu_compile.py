@@ -178,21 +178,30 @@ def test_device_index_kernels(sds):
     ).compile()
 
 
-def _compile_plan_program(sds, n_docs: int, cw: int, lane_pages: int, t_grid: int):
+def _compile_plan_program(sds, monkeypatch, n_docs: int, cw: int, lane_pages: int,
+                          t_grid: int, cap: int | None = None):
     """query/plan._build_program for ``metric{tag="v"}`` (two exact leaves
     ANDed) over an ``n_docs`` segment, one block of CHUNKS chunks whose
     widest lane spans ``cw`` window words and ``lane_pages`` pool pages,
-    onto a ``t_grid``-step grid: the compiled program, held to the device's
-    memory and to no gather over a [cap, t_pts] plane of decoded points."""
+    onto a ``t_grid``-step grid, decoding ``cap`` matched-series slots (the
+    whole segment where not given): the compiled program, held to the
+    device's memory and to no gather over a [cap, t_pts] plane of decoded
+    points."""
+    from m3_tpu import device
     from m3_tpu.query import plan
 
+    # the chip's program: on it the decode is the Pallas point kernel,
+    # elsewhere the lax.scan (query/plan._build_program asks device.on_tpu)
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    plan._build_program.cache_clear()
     o, pool = _pool_shapes(sds)
     n_words = n_docs // 32
     slab = n_docs
     ast = ("and", (("terms", 0, 1, 0, slab), ("terms", 1, 1, slab, slab)), ())
     lp = lane_pages + -(-cw // o.page_words) + 1
     _, sl, tables = _plan_rows(sds, o, n_docs + 1, lp)
-    dims = (n_words, n_docs, n_docs, 1, CHUNKS, CHUNK_K, cw, lp, sl,
+    cap = n_docs if cap is None else cap
+    dims = (n_words, n_docs, cap, 1, CHUNKS, CHUNK_K, cw, lp, sl,
             o.page_words, o.side_page_chunks, t_grid)
     pair = (sds((), U32), sds((), U32))
     leaves = sds((2,), I32)
@@ -205,6 +214,7 @@ def _compile_plan_program(sds, n_docs: int, cw: int, lane_pages: int, t_grid: in
         *pool, *tables,
         sds((t_grid,), U32), sds((t_grid,), U32), pair, pair, pair,
     ).compile()
+    plan._build_program.cache_clear()  # nothing later meets the chip's program
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12 << 30
     # no gather reads a [cap, t_pts] plane of decoded points, in either
@@ -219,29 +229,29 @@ def _compile_plan_program(sds, n_docs: int, cw: int, lane_pages: int, t_grid: in
     }
     operands = re.findall(r"= \S+ gather\((%[^,\s)]+)", text)
     assert operands, "the pool's page gather at least is expected"
-    points = n_docs * CHUNKS * CHUNK_K
+    points = cap * CHUNKS * CHUNK_K
     assert not [name for name in operands if elems[name] == points]
     # and ONE gather yields a word per window slot: the pool's. The page id
     # of each word is two ids a lane and a select (parallel/scan.py
     # _resident_gather), not a second gather over [lanes, cw]
-    slots = n_docs * CHUNKS * cw
+    slots = cap * CHUNKS * cw
     per_word = re.findall(r"^\s*(?:ROOT )?(%\S+) = \w+\[[\d,]*\]\S* gather\(", text, re.M)
     assert len([name for name in per_word if elems[name] == slots]) == 1
     return compiled
 
 
 @pytest.mark.parametrize("t_grid", [128, 1024])
-def test_one_dispatch_plan_program(sds, t_grid):
+def test_one_dispatch_plan_program(sds, monkeypatch, t_grid):
     """A 16,384-doc segment of int lanes onto a window query's 128-step
     grid and a read-back's 1,024. The byte bound is what forbids stage 5's
     [cap, t_grid, t_pts] cube in memory (ONE u32 plane of it is 6.2 GB at
     128 steps), the gather check the per-element loop it replaced
     (functions/temporal.py, "window index machinery")."""
-    _compile_plan_program(sds, 16_384, WINDOW_WORDS, 1, t_grid)
+    _compile_plan_program(sds, monkeypatch, 16_384, WINDOW_WORDS, 1, t_grid)
 
 
 @pytest.mark.parametrize("t_grid", [128, 1024])
-def test_plan_program_at_the_devops_cell_dimensions(sds, t_grid):
+def test_plan_program_at_the_devops_cell_dimensions(sds, monkeypatch, t_grid):
     """``devops.haystack`` (BENCHMARK.json): 4,040 series pad to a cap of
     4,064, 23 chunks, and the float64 lanes (5.3 KB a block: three 2 KiB
     pages) set every lane's window: 73-76 words by the seed, which the plan
@@ -249,4 +259,20 @@ def test_plan_program_at_the_devops_cell_dimensions(sds, t_grid):
     from m3_tpu.query.plan import _bucket_window_words
 
     assert {_bucket_window_words(cw) for cw in (73, 74, 75, 76)} == {80}
-    _compile_plan_program(sds, 4_064, 80, 3, t_grid)
+    _compile_plan_program(sds, monkeypatch, 4_064, 80, 3, t_grid)
+
+
+@pytest.mark.parametrize("n_docs,cap,cw,lane_pages", [
+    (4_064, 64, 80, 3),   # devops.haystack: 40 of 4,040 series match
+    (4_000, 512, 15, 1),  # cpu-only.haystack: 400 of 4,000
+    (4_064, 8, 80, 3),    # the floor: one series matches
+])
+def test_plan_program_at_the_capacity_of_what_matched(sds, monkeypatch, n_docs, cap, cw,
+                                                      lane_pages):
+    """The two query cells' programs since the decode capacity follows the
+    matched count (``Planner._build``: its power-of-two bucket), and the
+    smallest there is. The whole-segment cases above are what a full match
+    still compiles."""
+    compiled = _compile_plan_program(sds, monkeypatch, n_docs, cw, lane_pages, 128, cap=cap)
+    # the decode is one operation (the point kernel), not a scan's loop
+    assert "tpu_custom_call" in compiled.as_text()

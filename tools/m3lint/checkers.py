@@ -430,9 +430,13 @@ class MetricNameDiscipline(Checker):
     # "body": the decode body a chunk's own flags ask for — the three
     # constants of resident/pool.py CHUNK_BODIES
     # (m3tpu_resident_chunks_total{body}).
+    # "cap": a plan's decode capacity, the power-of-two bucket of its
+    # matched-series count between 8 and the segment's bitmap width — at
+    # most log2(docs) values a segment, ten at 4,064 docs
+    # (m3tpu_query_plan_builds_total{cap}).
     LABEL_KEYS = {"component", "op", "peer", "to", "kernel", "kind", "stage",
                   "ns", "group", "tenant", "scope", "shard", "reason",
-                  "objective", "window", "file", "encoder", "body"}
+                  "objective", "window", "file", "encoder", "body", "cap"}
 
     def check_file(self, ctx: FileContext):
         for node in ast.walk(ctx.tree):
