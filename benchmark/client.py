@@ -6,7 +6,10 @@ handed its requests (for writes: its series and their slice of the seeded
 matrix, from which it builds every tick's entry list BEFORE it says READY)
 and hands back its records; the parent compares. Nothing the benchmark
 computes per request sits between a send and the next: replies are kept as
-they came and reduced only on ``dump``, after the window.
+they came and reduced only on ``dump``, after the window. For the window a
+query client turns its cyclic collector off: it keeps every reply on
+purpose, and a collector walking that growing heap would run its full
+passes inside timed requests, longer as the window goes on.
 
 The client's wire codec (``m3_tpu/net/client.py``) stays in the path: it is
 what a coordinator runs in front of a dbnode. This process never imports
@@ -20,9 +23,11 @@ without sending it.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import pickle
+import resource
 import sys
 import time
 
@@ -45,7 +50,13 @@ def wait_until(t: float) -> None:
         time.sleep(min(left, 0.05))
 
 
-class WriteClient:
+class Client:
+    def usage(self, _cmd: dict) -> dict:
+        """This process's peak resident set (Linux reports it in KiB)."""
+        return {"peak_rss_bytes": resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024}
+
+
+class WriteClient(Client):
     def __init__(self, spec: dict, node: RemoteNode) -> None:
         self.node = node
         self.ns = spec["ns"]
@@ -115,30 +126,35 @@ class WriteClient:
         return {"records": len(self.sent)}
 
 
-class QueryClient:
+class QueryClient(Client):
     def __init__(self, spec: dict, node: RemoteNode) -> None:
         self.node = node
         self.ns = spec["ns"]
-        self.requests = spec["requests"]  # {"warmup": [...], "window": [...]}
+        # {"warmup": [(query, start, end, step), ...], "window": [...]}
+        self.requests = spec["requests"]
         self.timeout_s = spec["timeout_s"]
         self.fault = spec.get("fault")
         self.raw: list = []  # (index, send, recv, reply or None, error)
 
     def query(self, cmd: dict) -> dict:
-        reqs = self.requests[cmd["which"]]
+        reqs = self.requests[cmd["which"]][:cmd.get("limit")]
         record = cmd.get("record", False)
         self.node.timeout = cmd.get("timeout", self.timeout_s)
+        if record:
+            gc.collect()
+            gc.freeze()
+            gc.disable()
         wait_until(cmd.get("t_go", 0.0))
         t_end = cmd.get("t_end", float("inf"))
         query_range = self.node.query_range
         ns = self.ns
         n = failed = 0
-        for i, r in enumerate(reqs):
+        for i, (query, start, end, step) in enumerate(reqs):
             t_send = time.perf_counter()
             if t_send >= t_end:
                 break
             try:
-                resp = query_range(ns, r["query"], r["start"], r["end"], r["step"])
+                resp = query_range(ns, query, start, end, step)
                 err = None
             except Exception as exc:  # a failed request is a record, not a crash
                 resp, err = None, f"{type(exc).__name__}: {exc}"
@@ -147,6 +163,8 @@ class QueryClient:
             if record:
                 self.raw.append((i, t_send, t_recv, resp, err))
             n += 1
+        if record:
+            gc.enable()
         if "t_end" in cmd and n == len(reqs) and time.perf_counter() < t_end:
             return {"error": f"ran out of requests ({n}) before the window closed"}
         return {"requests": n, "failed": failed}

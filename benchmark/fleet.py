@@ -34,7 +34,7 @@ NANOS = 1_000_000_000
 T0_BLOCKS = 222_223
 
 # independent seed streams (numpy SeedSequence spawn keys)
-STREAM_VALUES, STREAM_TRAFFIC, STREAM_READBACK = 1, 2, 3
+STREAM_VALUES, STREAM_TRAFFIC, STREAM_READBACK, STREAM_TRAFFIC_MORE = 1, 2, 3, 4
 
 
 def load_json(*parts: str) -> dict:
@@ -49,8 +49,8 @@ def load_config(name: str) -> dict:
     return cfg
 
 
-def rng_for(seed: int, stream: int) -> np.random.Generator:
-    return np.random.default_rng(np.random.SeedSequence([int(seed), stream]))
+def rng_for(seed: int, stream: int, *sub: int) -> np.random.Generator:
+    return np.random.default_rng(np.random.SeedSequence([int(seed), stream, *sub]))
 
 
 def t0_nanos(cfg: dict) -> int:

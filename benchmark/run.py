@@ -244,6 +244,8 @@ class Cell:
     def close_window(self, clients: list[Client]) -> None:
         cpu1 = self.procs_cpu(clients)
         self.window["cpu_s"] = {k: cpu1[k] - v for k, v in self.window["cpu0"].items()}
+        self.window["client_peak_rss_bytes"] = [
+            c.call(cmd="usage")["peak_rss_bytes"] for c in clients]
         if self.trace:
             self.window["traced_s"] = time.perf_counter() - self.window["trace_t0"]
             self.node.hook(cmd="trace_stop")
@@ -313,20 +315,24 @@ class Cell:
     def run_query(self) -> None:
         cfg, tr, node = self.cfg, self.traffic, self.node
         self.load_block()
-        plan = traffic_mod.query_plan(cfg, tr, self.t0, self.n_points, self.seed)
+        plan = traffic_mod.query_plan(cfg, tr, self.t0, self.n_points, self.seed,
+                                      seconds=self.seconds)
         t = time.perf_counter()
         clients = []
         for w in range(tr["workers"]):
             spec = {"kind": "query", "endpoint": node.endpoint, "ns": cfg["namespace"],
                     "timeout_s": tr["timeout_s"], "fault": self.fault,
-                    "requests": {k: plan[k][w] for k in plan}}
+                    "requests": {k: plan[k][w].wire() for k in plan}}
             c = Client(spec, os.path.join(node.base, f"querier{w}.pickle"))
             clients.append(c)
             self.clients.append(c)
         for c in clients:
             c.recv()
         # warm-up: the cell's own shapes, through the clients' own
-        # connections; first sight of a shape compiles, so no time limit
+        # connections; first sight of a shape compiles, so no time limit.
+        # The first request goes from one client alone, so that one thread
+        # of the dbnode makes the plan program and not four at once
+        clients[0].call(cmd="query", which="warmup", limit=1, timeout=1500.0)
         all_calls(clients, [{"cmd": "query", "which": "warmup", "timeout": 1500.0}] * len(clients))
         self.phases["warmup_s"] = time.perf_counter() - t
         self.say_setup()
@@ -385,11 +391,19 @@ class Cell:
         self.window["memory_peak_bytes"] = mem
 
         lat = np.asarray([r["latency_s"] for r in replies]) * 1e3
+        srv = np.asarray([(r["stats"].get("durationSecs") or 0) * 1e3 for r in replies])
         order = np.argsort([r["send"] for r in replies])
         half = len(order) // 2
+        split = self.window["split"] = {
+            "requests": [r["requests"] for r in res],
+            "server_p50_ms": float(np.median(srv)),
+            "rest_p50_ms": float(np.median(lat - srv)),
+            "first_half_p50_ms": float(np.median(lat[order[:half]])),
+            "second_half_p50_ms": float(np.median(lat[order[half:]])),
+        }
         say(f"window: {len(lat)} requests, first-half median "
-            f"{np.median(lat[order[:half]]):.1f} ms, second-half median "
-            f"{np.median(lat[order[half:]]):.1f} ms")
+            f"{split['first_half_p50_ms']:.1f} ms, second-half median "
+            f"{split['second_half_p50_ms']:.1f} ms")
         hit = np.asarray([bool(r["stats"].get("planHits") or r["stats"].get("planCoalesced")) for r in replies])
         for what, mask in (("plan hits", hit), ("plan misses", ~hit)):
             if mask.any():
@@ -579,7 +593,16 @@ class Cell:
         say(f"end-to-end setup_s: {self.phases['setup_s']}")
         return {"correct": self.checks.ok, "attempted": self.window["attempted"],
                 "failed": self.window["failed"], "metrics": metrics, "device": device,
-                **out, "compared": self.checks.items}
+                **out, "harness": self.harness(), "compared": self.checks.items}
+
+    def harness(self) -> dict:
+        """What the load generator itself cost, and where a request's time
+        went: for whoever re-bounds a metric (README.md)."""
+        cpu = self.window["cpu_s"]
+        return {"cpu_count": os.cpu_count(), "dbnode_cpu_s": cpu["dbnode"],
+                "client_cpu_s": [v for k, v in cpu.items() if k != "dbnode"],
+                "client_peak_rss_bytes": self.window["client_peak_rss_bytes"],
+                **self.window.get("split", {})}
 
 
 def metric_total(expo: str, name: str) -> float:

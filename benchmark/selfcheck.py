@@ -13,11 +13,17 @@
   shard and the same index terms, and different sample values, request
   draws and read-back samples;
 - a one-host query class asks for every host of the fleet once, warm-up
-  included, before it asks for any host again, at every seed.
+  included, before it asks for any host again, at every seed;
+- the request list follows the window: a client that answers in 5 ms has
+  requests left when a window of ``run_seconds`` closes, and the first
+  ``requests_per_worker`` requests of every worker are the ones the
+  generator dealt before the list was extended (``DEALT_BEFORE``: digests
+  taken from PR 30's ``traffic.py``).
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import re
@@ -36,6 +42,26 @@ import traffic as traffic_mod  # noqa: E402
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SEEDS = (1, 2_500_000_011)
+FAST_CLIENT_SECS = 0.005  # a fifth of the fastest cell's median today
+# sha256 (16 hex digits) over warm-up and the first requests_per_worker
+# window requests of every worker, as PR 30's generator dealt them
+DEALT_BEFORE = {
+    ("haystack", 1): "b49d7cba27f5f88f",
+    ("haystack", 2_500_000_011): "420a6b08897c42d2",
+    ("devops-haystack", 1): "234adba342ba96b9",
+    ("devops-haystack", 2_500_000_011): "4cdb2f7e203d509b",
+}
+
+
+def dealt_digest(plan: dict, n_base: int) -> str:
+    h = hashlib.sha256()
+    for phase in ("warmup", "window"):
+        for reqs in plan[phase]:
+            for r in reqs[:n_base]:
+                h.update(repr(tuple(r[k] for k in (
+                    "query", "start", "end", "step", "host", "first_idx", "stride",
+                    "n_steps", "window_steps", "fn", "metric"))).encode())
+    return h.hexdigest()[:16]
 
 
 def problems() -> list[str]:
@@ -104,6 +130,25 @@ def problems() -> list[str]:
             if tr["kind"] == "query":
                 plan = traffic_mod.query_plan(cfg, tr, fleet.t0_nanos(cfg), n, seed)
                 draws = [(r["query"], r["start"]) for reqs in plan["window"] for r in reqs[:50]]
+                # the list follows the window, and starts as it always did
+                n_base = tr["requests_per_worker"]
+                sized = traffic_mod.query_plan(cfg, tr, fleet.t0_nanos(cfg), n, seed,
+                                               seconds=bench["run_seconds"])
+                short = [len(reqs) for reqs in sized["window"]
+                         if len(reqs) * FAST_CLIENT_SECS <= bench["run_seconds"]]
+                if short:
+                    bad.append(f"{w['name']}: a client that answers in {FAST_CLIENT_SECS * 1e3:.0f} ms "
+                               f"runs out of its {short[0]} requests inside the window")
+                want = DEALT_BEFORE.get((w["traffic"], seed))
+                if want is not None and {dealt_digest(plan, n_base),
+                                         dealt_digest(sized, n_base)} != {want}:
+                    bad.append(f"{w['name']} seed {seed}: the first {n_base} requests a worker "
+                               "are not the ones the generator dealt before")
+                tail = [(r["query"], r["start"]) for reqs in sized["window"]
+                        for r in reqs[n_base:n_base + 50]]
+                if len(set(tail)) < len(tail) // 2:
+                    bad.append(f"{w['name']} seed {seed}: the requests after the first "
+                               f"{n_base} repeat each other")
                 rb = [r["query"] for r in traffic_mod.readback_requests(
                     cfg, table, fleet.t0_nanos(cfg), n, seed, tr["readback_per_class"])]
             else:

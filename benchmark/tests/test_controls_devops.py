@@ -9,9 +9,9 @@ this one is where a program that carries values in float32 anywhere
 between ingest and reply stops being ``correct``.
 
 ``test_controls.py``'s ``test_float32_is_not_separable_over_small_integers``
-is parametrised over every query cell and asserts integer classes, so it
-fails for this cell: the next ``benchmark`` PR narrows it to the
-integer-only cells (PERF.md section 7).
+holds only the cells whose value classes are all small integers (PR 32);
+this cell is held by its ``test_float32_fails_every_other_query_cell``
+and, for how widely, by the cases here.
 
     python3 -m pytest benchmark/tests/test_controls_devops.py -q
 """

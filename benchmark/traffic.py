@@ -4,9 +4,10 @@
 ``kind: "query"`` — closed-loop range queries. ``classes`` lists query
 classes ``{fn, metric, range_secs, step_secs, span_secs, hosts}`` (``hosts``
 is 1 for one host of the whole fleet, or "all"); every worker gets
-``warmup_per_worker`` warm-up requests and ``requests_per_worker`` window
-requests, the classes in equal shares, each with a start drawn from the
-seed on an ``align_secs`` grid. The hosts of the one-host classes are DEALT
+``warmup_per_worker`` warm-up requests and window requests for as long as
+the window lasts (``requests_per_worker`` from the shared stream, then its
+own), the classes in equal shares, each with a start drawn from the seed
+on an ``align_secs`` grid. The hosts of the one-host classes are DEALT
 from one permutation of the whole fleet drawn from the seed, without
 replacement, the warm-up first and the window after it, round-robin over
 the workers: TSBS draws each query's host from all of them, and so does
@@ -32,7 +33,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from fleet import NANOS, STREAM_READBACK, STREAM_TRAFFIC, rng_for
+from fleet import NANOS, STREAM_READBACK, STREAM_TRAFFIC, STREAM_TRAFFIC_MORE, rng_for
 
 # ---------------------------------------------------------------------------
 # queries
@@ -74,15 +75,53 @@ def _start_slots(cfg: dict, n_points: int, cls: dict, align_secs: int) -> np.nda
     return grid[grid >= lo] // interval
 
 
-def query_plan(cfg: dict, traffic: dict, t0: int, n_points: int, seed: int) -> dict:
-    """Per-worker request lists: ``warmup`` then ``window``.
+class Requests:
+    """One worker's requests of one phase, kept as their draws (class,
+    host, start slot) and made into request dicts on demand: ``reqs[i]``,
+    ``reqs[:n]``, iteration. ``wire()`` is what the client sends."""
+
+    def __init__(self, cfg: dict, t0: int, classes: list[dict],
+                 which: np.ndarray, hosts: np.ndarray, first_idx: np.ndarray) -> None:
+        self.cfg, self.t0, self.classes = cfg, t0, classes
+        self.which, self.hosts, self.first_idx = which, hosts, first_idx
+
+    def __len__(self) -> int:
+        return len(self.which)
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [self[k] for k in range(*i.indices(len(self)))]
+        cls = self.classes[int(self.which[i])]
+        host = int(self.hosts[i]) if cls["hosts"] == 1 else None
+        return _query_request(self.cfg, self.t0, cls, host, int(self.first_idx[i]))
+
+    def wire(self) -> list[tuple]:
+        """(query, start, end, step) of every request, in order."""
+        return [(r["query"], r["start"], r["end"], r["step"]) for r in self]
+
+
+# No served request answers faster: one round trip through the dbnode's
+# Python RPC server and one device dispatch. The window's list is sized by it.
+LATENCY_FLOOR_SECS = 0.001
+
+
+def query_plan(cfg: dict, traffic: dict, t0: int, n_points: int, seed: int,
+               seconds: float | None = None) -> dict:
+    """Per-worker ``Requests``: ``warmup`` then ``window``.
 
     The plan of a matcher is built at its first sight, which costs more
     than a later sight, so hosts drawn with replacement would mix the two
     in a ratio that follows the draw. Dealing them from one permutation of
     the fleet keeps the source's distribution (any host, each as likely)
     and gives every seed the same mix: request k over all workers, warm-up
-    included, asks for host ``deal[k % hosts]``."""
+    included, asks for host ``deal[k % hosts]``.
+
+    The list follows the window: with ``seconds`` a worker gets as many
+    window requests as a client answered in ``LATENCY_FLOOR_SECS`` could
+    send, ``requests_per_worker`` at least. The first
+    ``requests_per_worker`` are drawn as they always were (one stream, all
+    workers in turn), so a seed is the work it was; those after them come
+    from a stream of the worker's own."""
     rng = rng_for(seed, STREAM_TRAFFIC)
     classes = traffic["classes"]
     workers = traffic["workers"]
@@ -90,29 +129,49 @@ def query_plan(cfg: dict, traffic: dict, t0: int, n_points: int, seed: int) -> d
         if cls["hosts"] not in (1, "all"):
             raise ValueError(f"query class hosts {cls['hosts']!r}: 1 or \"all\"")
     deal = rng.permutation(cfg["hosts"])
+    slots = [_start_slots(cfg, n_points, cls, traffic["align_secs"]) for cls in classes]
 
-    def requests(n: int, worker: int, dealt: int) -> list[dict]:
-        """``n`` requests of one worker; its k-th takes deal position
+    def dealt_hosts(n: int, worker: int, dealt: int) -> np.ndarray:
+        """The k-th request of a worker takes deal position
         ``dealt + k * workers + worker``."""
+        return deal[(dealt + np.arange(n) * workers + worker) % len(deal)]
+
+    def draw(n: int) -> tuple[np.ndarray, np.ndarray]:
+        """Classes in equal shares and a start slot each, from the one
+        stream all workers share: call for call what PR 26 drew."""
         which = np.arange(n) % len(classes)
         rng.shuffle(which)
-        out = []
-        for k, c in enumerate(which.tolist()):
-            cls = classes[c]
-            host = None
-            if cls["hosts"] == 1:
-                host = int(deal[(dealt + k * workers + worker) % len(deal)])
-            slots = _start_slots(cfg, n_points, cls, traffic["align_secs"])
-            out.append(_query_request(cfg, t0, cls, host,
-                                      int(slots[rng.integers(len(slots))])))
-        return out
+        first = np.asarray([slots[c][rng.integers(len(slots[c]))] for c in which.tolist()],
+                           dtype=np.int64)
+        return which, first
+
+    def more(n: int, worker: int) -> tuple[np.ndarray, np.ndarray]:
+        """The same, from the worker's own stream: drawing them moves no
+        other worker's requests."""
+        own = rng_for(seed, STREAM_TRAFFIC_MORE, worker)
+        which = np.arange(n) % len(classes)
+        own.shuffle(which)
+        first = np.zeros(n, np.int64)
+        for c in range(len(classes)):
+            mine = which == c
+            first[mine] = slots[c][own.integers(len(slots[c]), size=int(mine.sum()))]
+        return which, first
 
     n_warm = traffic["warmup_per_worker"]
-    return {
-        "warmup": [requests(n_warm, w, 0) for w in range(workers)],
-        "window": [requests(traffic["requests_per_worker"], w, n_warm * workers)
-                   for w in range(workers)],
-    }
+    n_base = traffic["requests_per_worker"]
+    n_window = n_base if seconds is None else max(
+        n_base, int(np.ceil(seconds / LATENCY_FLOOR_SECS)))
+    plan: dict = {"warmup": [], "window": []}
+    for w in range(workers):
+        which, first = draw(n_warm)
+        plan["warmup"].append(Requests(cfg, t0, classes, which,
+                                       dealt_hosts(n_warm, w, 0), first))
+    for w in range(workers):
+        which, first = (np.concatenate(pair) for pair in zip(
+            draw(n_base), more(n_window - n_base, w)))
+        plan["window"].append(Requests(cfg, t0, classes, which,
+                                       dealt_hosts(n_window, w, n_warm * workers), first))
+    return plan
 
 
 def readback_requests(cfg: dict, table: list, t0: int, n_points: int,
