@@ -229,7 +229,7 @@ def stderr_tails(procs) -> str:
 
 
 def kernel_parity_child() -> None:
-    """Mosaic-lowered kernels vs their jnp twins on whatever device jax
+    """Mosaic-lowered kernels vs their jnp references on whatever device jax
     finds (interpret mode / unfused off-TPU, so the rehearsal runs it too).
     Run as ``python -c "import chip_smoke; chip_smoke.kernel_parity_child()"``."""
     import functools
@@ -242,7 +242,6 @@ def kernel_parity_child() -> None:
     from m3_tpu.parallel.scan import (
         chunked_device_args,
         chunked_scan_aggregate,
-        chunked_scan_aggregate_fused,
         chunked_scan_aggregate_packed,
     )
     from m3_tpu.query.functions.temporal_fused import FUSABLE, fused_temporal
@@ -253,7 +252,7 @@ def kernel_parity_child() -> None:
     print("DEVICE %s %d %s" % device.require_device(), flush=True)
     print(f"compile cache: {cache}", flush=True)
 
-    # the flagship decode+aggregate kernels at the served chunk size
+    # the served decode+aggregate kernel at the served chunk size
     streams = synthetic_streams(32, POINTS, seed=11)
     batch = tile_chunked(build_chunked(streams, k=CHUNK_K), 1024)
     args = chunked_device_args(batch)
@@ -262,31 +261,26 @@ def kernel_parity_child() -> None:
     # TOLERANCE.md, aggregation: two f32 paths, each k*ulp per chunk plus
     # O(log C) + O(log S) tree terms — 2e-5 relative covers both sides
     rtol = 2e-5
-    got = jax.jit(functools.partial(
-        chunked_scan_aggregate_fused, **dims, backend="auto"))(args)
-    assert int(got.total_count) == int(want.total_count)
-    np.testing.assert_allclose(
-        float(got.total_sum), float(want.total_sum), rtol=rtol)
     packed = fused.pack_lane_inputs(batch)
     assert packed.tile_flags.sum() > 0, "no fast tiles classified"
-    got2 = jax.jit(functools.partial(
+    got = jax.jit(functools.partial(
         chunked_scan_aggregate_packed, n=packed.n, **dims,
         interpret=not device.on_tpu(),
     ))(packed.windows4, packed.lanes4, packed.tile_flags)
-    assert int(got2.total_count) == int(want.total_count)
+    assert int(got.total_count) == int(want.total_count)
     np.testing.assert_allclose(
-        float(got2.total_sum), float(want.total_sum), rtol=rtol)
+        float(got.total_sum), float(want.total_sum), rtol=rtol)
     np.testing.assert_allclose(
-        np.asarray(got2.series_sum), np.asarray(want.series_sum), rtol=rtol)
+        np.asarray(got.series_sum), np.asarray(want.series_sum), rtol=rtol)
     np.testing.assert_array_equal(
-        np.asarray(got2.series_count), np.asarray(want.series_count))
-    print(f"KERNEL_PARITY packed+fused lane aggregates k={CHUNK_K} ok", flush=True)
+        np.asarray(got.series_count), np.asarray(want.series_count))
+    print(f"KERNEL_PARITY packed lane aggregates k={CHUNK_K} ok", flush=True)
 
-    # is block_until_ready a barrier here? (parallel/stream.py relies on
-    # it.) Enqueue a chain of dependent matmuls (~0.4 s on a v5e); if the
-    # wait returns only when the work is done, the scalar fetch after it
-    # is immediate. The fetch's own slice program is compiled by the
-    # warm-up, so it is not what the last interval times.
+    # is block_until_ready a barrier here? (every host timing that ends in
+    # it relies on that.) Enqueue a chain of dependent matmuls (~0.4 s on a
+    # v5e); if the wait returns only when the work is done, the scalar
+    # fetch after it is immediate. The fetch's own slice program is compiled
+    # by the warm-up, so it is not what the last interval times.
     import jax.numpy as jnp
 
     iters = 4000 if device.on_tpu() else 20

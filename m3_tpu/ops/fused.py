@@ -3,14 +3,12 @@
 Round-1 decode materialized 7 u64 [S, T] outputs from the scan and aggregated
 afterwards — every step streamed a multi-hundred-MB carry plus outputs through
 HBM. Here the whole K-step decode loop runs with its state resident on-chip
-and only per-LANE aggregates (sum/count/min/max/last) leave the kernel:
-
-  - Pallas path (TPU): grid over lane tiles of 8x128; each program loads its
-    tile's window columns into VMEM once and runs the K-record loop as a
-    fori_loop, state in vector registers/VMEM. HBM traffic = windows once +
-    [N] accumulators once.
-  - jnp path (CPU fallback + oracle): identical math as a lax.scan with
-    accumulators in the carry and NO per-step outputs.
+and only per-LANE aggregates (sum/count/min/max/last) leave the kernel: a
+Pallas grid over lane tiles; each program loads its tile's window columns
+into VMEM once and runs the K-record loop as a fori_loop, state in vector
+registers/VMEM. HBM traffic = windows once + [N] accumulators once. Off the
+chip the same kernel body runs in interpret mode; the plain-jnp reference it
+is tested against is parallel/scan.chunked_scan_aggregate.
 
 Record semantics are decode.py's branchless M3TSZ step (reference hot loop:
 /root/reference/src/dbnode/encoding/m3tsz/iterator.go:64, istream.go:97);
@@ -29,7 +27,7 @@ import numpy as np
 
 from ..utils.instrument import KernelProfiler
 from . import u64
-from .chunked import _fetch4_select, _point_step, _window_columns
+from .chunked import _fetch4_select, _point_step
 from .decode import (
     DecodeResult,
     DecodeState,
@@ -48,11 +46,8 @@ I32 = jnp.int32
 U32 = jnp.uint32
 F32 = jnp.float32
 
-# device-tier observability for the fused lane-aggregate kernels (see
-# ops/chunked.PROFILER): the dispatch key carries the backend
-# (pallas/jnp), so compile attribution separates the Mosaic kernel from
-# the lax.scan fallback while one kernel label covers the path
-PROFILER_FUSED = KernelProfiler("fused_lane_agg")
+# device-tier observability for the fused lane-aggregate kernel (see
+# ops/chunked.PROFILER)
 PROFILER_PACKED = KernelProfiler("packed_lane_agg")
 
 LANE_TILE = (8, 128)  # native f32/i32 VPU tile
@@ -133,7 +128,7 @@ def _fused_step(fetch4, nb, nt0, first_chunk_i32, int_optimized, carry, idx):
 def _run_lane_tile(windows_cols, rel_pos, num_bits, first, prev_time, prev_delta,
                    prev_float_bits, prev_xor, int_val, time_unit, sig, mult,
                    is_float, k: int, cw: int, int_optimized: bool,
-                   use_scan: bool, unroll: bool = False) -> LaneAggregates:
+                   unroll: bool = False) -> LaneAggregates:
     """Shared body: decode K records over one set of lanes (any shape) with
     window columns already materialized, accumulating aggregates."""
     rel_pos = jnp.asarray(rel_pos, I32)
@@ -157,38 +152,33 @@ def _run_lane_tile(windows_cols, rel_pos, num_bits, first, prev_time, prev_delta
     step = functools.partial(
         _fused_step, fetch4, nb, nt0, first_chunk_i32, int_optimized
     )
-    if use_scan:
-        (state, acc), _ = jax.lax.scan(
-            lambda c, i: (step(c, i), None), (state, acc0), jnp.arange(k)
+    # Mosaic can't round-trip i1 vectors through a fori_loop carry, so
+    # bool state fields travel as int32 and are re-compared each step.
+    def pack(st):
+        return st._replace(
+            done=st.done.astype(I32), err=st.err.astype(I32),
+            is_float=st.is_float.astype(I32),
         )
-    else:
-        # Mosaic can't round-trip i1 vectors through a fori_loop carry, so
-        # bool state fields travel as int32 and are re-compared each step.
-        def pack(st):
-            return st._replace(
-                done=st.done.astype(I32), err=st.err.astype(I32),
-                is_float=st.is_float.astype(I32),
-            )
 
-        def unpack(st):
-            return st._replace(
-                done=st.done != 0, err=st.err != 0, is_float=st.is_float != 0
-            )
-
-        def body(i, c):
-            st, ac = c
-            st, ac = step((unpack(st), ac), i)
-            return pack(st), ac
-
-        # fully unrolled on hardware: Mosaic schedules the straight-line
-        # record bodies much better than the rolled loop (+16% measured);
-        # Pallas only supports unroll=1 or unroll=num_steps. Interpret mode
-        # keeps the rolled loop (the interpreter executes per-op, and the
-        # 24x traced body is pathologically slow there).
-        state, acc = jax.lax.fori_loop(
-            0, k, body, (pack(state), acc0), unroll=k if unroll else 1
+    def unpack(st):
+        return st._replace(
+            done=st.done != 0, err=st.err != 0, is_float=st.is_float != 0
         )
-        state = unpack(state)
+
+    def body(i, c):
+        st, ac = c
+        st, ac = step((unpack(st), ac), i)
+        return pack(st), ac
+
+    # fully unrolled on hardware: Mosaic schedules the straight-line
+    # record bodies much better than the rolled loop (+16% measured);
+    # Pallas only supports unroll=1 or unroll=num_steps. Interpret mode
+    # keeps the rolled loop (the interpreter executes per-op, and the
+    # 24x traced body is pathologically slow there).
+    state, acc = jax.lax.fori_loop(
+        0, k, body, (pack(state), acc0), unroll=k if unroll else 1
+    )
+    state = unpack(state)
     s_sum, s_cnt, s_min, s_max, s_last = acc
     return LaneAggregates(
         sum=s_sum, count=s_cnt, min=s_min, max=s_max, last=s_last, err=state.err
@@ -344,31 +334,11 @@ def _run_lane_tile_fast_float(windows_cols, rel_pos, num_bits,
 
 
 # ---------------------------------------------------------------------------
-# jnp fallback path (CPU tests, oracle, non-TPU backends)
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnames=("k", "int_optimized"))
-def lane_aggregates_jnp(
-    windows, rel_pos, num_bits, first, prev_time, prev_delta, prev_float_bits,
-    prev_xor, int_val, time_unit, sig, mult, is_float, k: int,
-    int_optimized: bool = True,
-) -> LaneAggregates:
-    windows = jnp.asarray(windows, U32)
-    cols = _window_columns(windows)
-    return _run_lane_tile(
-        cols, rel_pos, num_bits, first, prev_time, prev_delta, prev_float_bits,
-        prev_xor, int_val, time_unit, sig, mult, is_float, k,
-        windows.shape[1], int_optimized, use_scan=True,
-    )
-
-
-# ---------------------------------------------------------------------------
 # Pallas TPU kernel — packed layout (the fast path)
 # ---------------------------------------------------------------------------
 #
-# Profiling on real TPU showed the original kernel below is DMA-issue bound,
-# not compute bound: each grid program pulled 24 strided window columns + 17
+# Profiling on real TPU showed the first kernel, one array a field, DMA-issue
+# bound, not compute bound: each grid program pulled 24 strided window columns + 17
 # separate 4KB lane arrays + 6 outputs (~47 small DMAs, ~7us/program), while
 # the decode math itself was fully hidden. The packed layout moves the same
 # bytes in 3 large contiguous DMAs per program: windows [tiles, CW, 8, 128],
@@ -553,7 +523,6 @@ def _pallas_kernel_packed(
                 k,
                 cw,
                 int_optimized,
-                use_scan=False,
                 unroll=unroll,
             )
         )
@@ -651,125 +620,6 @@ def lane_aggregates_packed(
     return LaneAggregates(
         sum=s_sum, count=s_cnt.astype(I32), min=s_min, max=s_max,
         last=s_last, err=s_err != 0,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU kernel — original per-field layout (kept for comparison/tests)
-# ---------------------------------------------------------------------------
-
-
-def _pallas_kernel(k, cw, int_optimized, unroll, win_ref, rel_ref, nbits_ref, first_ref,
-                   pt_hi, pt_lo, pd_hi, pd_lo, pfb_hi, pfb_lo, pxr_hi, pxr_lo,
-                   iv_hi, iv_lo, tu_ref, sig_ref, mult_ref, isf_ref,
-                   sum_ref, cnt_ref, min_ref, max_ref, last_ref, err_ref):
-    cols = [win_ref[j, 0] for j in range(cw)]
-    zero = jnp.zeros(LANE_TILE, U32)
-    cols = cols + [zero, zero, zero]
-    agg = _run_lane_tile(
-        cols,
-        rel_ref[0],
-        nbits_ref[0],
-        first_ref[0] != 0,
-        (pt_hi[0], pt_lo[0]),
-        (pd_hi[0], pd_lo[0]),
-        (pfb_hi[0], pfb_lo[0]),
-        (pxr_hi[0], pxr_lo[0]),
-        (iv_hi[0], iv_lo[0]),
-        tu_ref[0],
-        sig_ref[0],
-        mult_ref[0],
-        isf_ref[0] != 0,
-        k,
-        cw,
-        int_optimized,
-        use_scan=False,
-        unroll=unroll,
-    )
-    sum_ref[0] = agg.sum
-    cnt_ref[0] = agg.count
-    min_ref[0] = agg.min
-    max_ref[0] = agg.max
-    last_ref[0] = agg.last
-    err_ref[0] = agg.err.astype(I32)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k", "int_optimized", "interpret")
-)
-def lane_aggregates_pallas(
-    windows, rel_pos, num_bits, first, prev_time, prev_delta, prev_float_bits,
-    prev_xor, int_val, time_unit, sig, mult, is_float, k: int,
-    int_optimized: bool = True, interpret: bool = False,
-) -> LaneAggregates:
-    """Tiled Pallas execution over [N] lanes (N padded to 1024 multiples).
-
-    Host-side callers should pass numpy/jnp arrays; padding lanes decode
-    zero bits and contribute identity values to every aggregate.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    windows = jnp.asarray(windows, U32)
-    n, cw = windows.shape
-    tiles = -(-n // TILE_LANES)
-    npad = tiles * TILE_LANES
-
-    def pad_to(x, fill=0):
-        x = jnp.asarray(x)
-        if x.shape[0] == npad:
-            return x
-        return jnp.concatenate(
-            [x, jnp.full((npad - x.shape[0],) + x.shape[1:], fill, x.dtype)]
-        )
-
-    # windows transposed to [CW, tiles, 8, 128] so each column is a clean tile
-    w = pad_to(windows).T.reshape(cw, tiles, *LANE_TILE)
-
-    def lanes(x, fill=0, dtype=None):
-        x = pad_to(jnp.asarray(x) if dtype is None else jnp.asarray(x, dtype), fill)
-        return x.reshape(tiles, *LANE_TILE)
-
-    args = [
-        w,
-        lanes(rel_pos),
-        lanes(num_bits),
-        lanes(jnp.asarray(first).astype(I32)),
-        lanes(prev_time[0]), lanes(prev_time[1]),
-        lanes(prev_delta[0]), lanes(prev_delta[1]),
-        lanes(prev_float_bits[0]), lanes(prev_float_bits[1]),
-        lanes(prev_xor[0]), lanes(prev_xor[1]),
-        lanes(int_val[0]), lanes(int_val[1]),
-        lanes(time_unit),
-        lanes(sig),
-        lanes(mult),
-        lanes(jnp.asarray(is_float).astype(I32)),
-    ]
-
-    lane_spec = pl.BlockSpec((1, *LANE_TILE), lambda i: (i, 0, 0), memory_space=pltpu.VMEM)
-    win_spec = pl.BlockSpec((cw, 1, *LANE_TILE), lambda i: (0, i, 0, 0), memory_space=pltpu.VMEM)
-    out_shape = [
-        jax.ShapeDtypeStruct((tiles, *LANE_TILE), F32),
-        jax.ShapeDtypeStruct((tiles, *LANE_TILE), I32),
-        jax.ShapeDtypeStruct((tiles, *LANE_TILE), F32),
-        jax.ShapeDtypeStruct((tiles, *LANE_TILE), F32),
-        jax.ShapeDtypeStruct((tiles, *LANE_TILE), F32),
-        jax.ShapeDtypeStruct((tiles, *LANE_TILE), I32),
-    ]
-    outs = pl.pallas_call(
-        functools.partial(_pallas_kernel, k, cw, int_optimized, not interpret),
-        grid=(tiles,),
-        in_specs=[win_spec] + [lane_spec] * (len(args) - 1),
-        out_specs=[lane_spec] * 6,
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
-        ),
-        interpret=interpret,
-    )(*args)
-    s_sum, s_cnt, s_min, s_max, s_last, s_err = (o.reshape(npad)[:n] for o in outs)
-    return LaneAggregates(
-        sum=s_sum, count=s_cnt, min=s_min, max=s_max, last=s_last, err=s_err != 0
     )
 
 

@@ -14,9 +14,8 @@ Design constraints, in order:
 - **Low overhead.** One ``sys._current_frames()`` call per tick (a dict
   copy under the GIL), frame-walk and fold in plain Python, no
   allocation proportional to history (bounded per-bucket tables). The
-  sampler meters its own cost (``m3tpu_profile_overhead_*``) and the
-  PROFILE.md acceptance row holds it under 2% of the decode-aggregate
-  bench at the default rate.
+  sampler meters its own cost (``m3tpu_profile_overhead_*``), which is
+  to stay under 2% of a busy process's wall time at the default rate.
 - **Deterministic scheduling.** Ticks ride a
   :class:`~m3_tpu.utils.schedule.FixedRateTicker` (absolute schedule +
   per-instance phase), so a fleet of samplers spreads over the interval

@@ -1,9 +1,7 @@
 """Device query plans: a query is ONE XLA program.
 
-The recurring cost is the host round trip per dispatch (PROFILE.md; its
-per-dispatch milliseconds are from an earlier chip run, record removed,
-not re-measured) — and the staged executor
-pays it 4–6 times per query because `query/m3_storage.py` stitches the
+The recurring cost is the host round trip per dispatch — and the staged
+executor pays it 4–6 times per query because `query/m3_storage.py` stitches the
 stages with host-side types: the device index resolves doc ids to the
 host, the host walks per-doc block keys, the resident pool plans a
 gather, the decode dispatches, and consolidation runs a per-series
@@ -18,7 +16,7 @@ them inside ONE jit program per query *shape*:
         bitmap's width)
       → per-lane page-table gather  (plan tables uploaded once per
         (segment, block set) and cached)
-      → resident chunked decode  (parallel/scan assembly, then on the
+      → resident chunked decode  (resident/gather assembly, then on the
         chip ops/fused.decode_points_pallas, ONE device operation, and
         off it the same step as ops/chunked.decode_chunked_lanes'
         lax.scan; straight from the pool's pages + packed side planes)
@@ -382,7 +380,7 @@ def _build_program(ast, dims):
     from .. import device
     from ..ops.chunked import decode_chunked_lanes
     from ..ops.fused import decode_points_pallas
-    from ..parallel.scan import _assemble_resident_lanes_traced
+    from ..resident.gather import assemble_lanes_traced
 
     (n_words, n_docs_pad, cap, n_blocks, c, k, cw, lp, sl,
      page_words, spc, t_grid) = dims
@@ -471,7 +469,7 @@ def _build_program(ast, dims):
         lane_rows = (
             sel[:, None] * n_blocks + jnp.arange(n_blocks, dtype=i32)[None, :]
         ).reshape(-1)
-        kw = _assemble_resident_lanes_traced(
+        kw = assemble_lanes_traced(
             pool_words, side_words,
             t_pages[lane_rows], t_sides[lane_rows], t_chunks[lane_rows],
             t_bits[lane_rows], t_bhi[lane_rows], t_blo[lane_rows],

@@ -7,6 +7,11 @@ device gather of page rows instead of a host select/pack. The design of
 the reference TSDB's in-memory tier (M3/M3TSZ after Pelkonen et al.'s
 Gorilla), restated as a paged KV-cache-style memory manager for the
 scan-and-aggregate hot path.
+
+The package's own names are the pool's, which import no jax: a process that
+only stores (or only routes) pays nothing for the scan side. gather.py (the
+read side of the page format) and scan.py (the scans over it) import jax and
+are imported by what dispatches them (query/m3_storage.py, query/plan.py).
 """
 
 from .heat import ShardHeat
@@ -18,7 +23,6 @@ from .pool import (
     ResidentPool,
     ResidentPoolError,
 )
-from .scan import resident_fetch_arrays, resident_scan_totals
 
 __all__ = [
     "AdmitResult",
@@ -28,6 +32,4 @@ __all__ = [
     "ResidentPool",
     "ResidentPoolError",
     "ShardHeat",
-    "resident_fetch_arrays",
-    "resident_scan_totals",
 ]

@@ -2,9 +2,8 @@
 
 The memory-manager analogue of a paged KV cache in an inference stack,
 applied to the scan-and-aggregate hot path: instead of streaming sealed
-blocks' compressed bytes host->device on every scan (PROFILE.md's 50M×720
-row: earlier chip run, record removed, not re-measured), the m3tsz bytes stay RESIDENT in
-device memory — at compressed density (~1–2.4B/datapoint) a v5e-8 holds
+blocks' compressed bytes host->device on every scan, the m3tsz bytes stay
+RESIDENT in device memory — at compressed density (~1–2.4B/datapoint) a v5e-8 holds
 the whole 50M-series working set — and scans decode straight from HBM.
 
 Layout:

@@ -1,12 +1,12 @@
 """Decode-from-HBM scan orchestration over the paged resident pool.
 
 Bridges the host page table (pool.py) and the device scan path
-(parallel/scan.py). Since the side planes landed in the pool (PR 11),
-the resident scan is CHUNK-PARALLEL: plan_chunked hands over O(series)
-int vectors, assemble_resident_packed builds the PackedLanes view by
-device gather over page rows + side planes, and the SAME packed fused
-kernel the streamed pipeline (parallel/stream.py) dispatches decodes it
-— no host rebuild of chunk tables, no T-step whole-stream scan.
+(gather.py, then parallel/scan.py). Since the side planes landed in the
+pool (PR 11), the resident scan is CHUNK-PARALLEL: plan_chunked hands over
+O(series) int vectors, gather.py builds the PackedLanes view by device
+gather over page rows + side planes, and the SAME packed fused kernel the
+streamed scan below dispatches decodes it — no host rebuild of chunk
+tables, no T-step whole-stream scan.
 
 Bit-exactness contract: ``resident_scan_totals`` and
 ``streamed_scan_totals`` funnel through ONE shared decode + aggregation
@@ -21,17 +21,30 @@ from __future__ import annotations
 
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .. import device
+from ..ops.chunked import build_chunked, decode_chunked_lanes
+from ..ops.decode import DecodeResult, finalize_decode
+from ..ops.fused import pack_lane_inputs
+from ..parallel.scan import chunked_scan_aggregate_packed
 from ..storage.fs import CHUNK_K
 from ..utils.instrument import DEFAULT as METRICS
+from .gather import (
+    RESIDENT_CHUNKED_PROF,
+    assemble_resident_lanes,
+    make_sharded_resident_chunked_scan,
+    pad_chunked_plan,
+    resident_chunked_local_fn,
+)
 
 # host->device block bytes moved by the STREAMED scan path (the fallback
 # when matched blocks are not fully resident); warm resident scans leave
 # this and resident_upload_bytes_total untouched — the zero-transfer
 # acceptance test asserts on both counters
-_M_STREAMED_BYTES = METRICS.counter(
+STREAMED_BYTES = METRICS.counter(
     "scan_streamed_bytes_total",
     "host->device block bytes uploaded by the streamed scan fallback",
 )
@@ -43,21 +56,16 @@ def _pow2(n: int, lo: int = 1) -> int:
     return max(lo, 1 << max(int(n) - 1, 0).bit_length())
 
 
-def resident_scan_totals(pool, keys: list, mesh=None, device_out: bool = False):
+def resident_scan_totals(pool, keys: list, mesh=None):
     """Scan-and-aggregate the resident lanes for ``keys`` (one lane per
     (series, block) key) through the chunk-parallel kernels. Returns a
     ScanAggregates with the series arrays sliced back to ``len(keys)``,
     or None when any key is not resident (or carries no side planes —
     the caller streams instead, keeping the parity contract trivially).
 
-    ``mesh``: shard the lanes across a device mesh (parallel/scan.py
+    ``mesh``: shard the lanes across a device mesh (gather.py
     make_sharded_resident_chunked_scan, psum reduction unchanged);
-    None = single device. ``device_out``: skip the host conversion and
-    return the PADDED device aggregates — callers that pipeline scans
-    (bench, batched executors) drain results themselves so dispatch of
-    scan N+1 overlaps compute of scan N."""
-    from ..parallel.scan import RESIDENT_CHUNKED_PROF, pad_chunked_plan
-
+    None = single device."""
     with pool.read_lease():
         plan = pool.plan_chunked(keys)
         if plan is None:
@@ -78,7 +86,7 @@ def resident_scan_totals(pool, keys: list, mesh=None, device_out: bool = False):
             ("scan", s_pad, *shape_key, mesh is not None)
         ) as d:
             aggs = d.done(fn(plan.words, plan.side, *vecs))
-        return aggs if device_out else _slice_series(aggs, s)
+        return _slice_series(aggs, s)
 
 
 @functools.lru_cache(maxsize=32)
@@ -86,19 +94,13 @@ def _packed_scan_fn(c: int, k: int, cw: int, w: int, spc: int):
     """ONE jitted program per plan shape: PackedLanes assembly (device
     gathers over the pool + side planes) fused with the packed decode
     kernel — the gathered lane arrays never materialize between
-    dispatches. The body is parallel/scan.resident_chunked_local_fn,
-    shared with the sharded variant so the two paths can't diverge."""
-    import jax
-
-    from ..parallel.scan import resident_chunked_local_fn
-
+    dispatches. The body is gather.resident_chunked_local_fn, shared
+    with the sharded variant so the two paths can't diverge."""
     return jax.jit(resident_chunked_local_fn(c, k, cw, w, spc))
 
 
 @functools.lru_cache(maxsize=32)
 def _sharded_chunked(mesh, c: int, k: int, cw: int, w: int, spc: int):
-    from ..parallel.scan import make_sharded_resident_chunked_scan
-
     return make_sharded_resident_chunked_scan(mesh, c, k, cw, w, spc)
 
 
@@ -111,12 +113,6 @@ def streamed_scan_totals(segments: list, k: int = CHUNK_K):
     resident path decodes with (the fileset's chunkK) for the bit-exact
     parity contract — the chunk decomposition sets the f32 reduction
     order."""
-    import jax
-
-    from ..ops.chunked import build_chunked
-    from ..ops.fused import pack_lane_inputs
-    from ..parallel.scan import chunked_scan_aggregate_packed
-
     s = len(segments)
     s_pad = _pow2(s, _MIN_LANES)
     batch = build_chunked(list(segments) + [b""] * (s_pad - s), k=k)
@@ -129,7 +125,7 @@ def streamed_scan_totals(segments: list, k: int = CHUNK_K):
     # name/help, shard heat, and the upload_bytes comparison) — NOT the
     # packed lane arrays, which duplicate overlapping window words
     # across chunks and would silently rescale dashboards several-fold
-    _M_STREAMED_BYTES.inc(sum(len(seg) for seg in segments))
+    STREAMED_BYTES.inc(sum(len(seg) for seg in segments))
     aggs = chunked_scan_aggregate_packed(
         windows4, lanes4, tile_flags,
         n=packed.n, s=s_pad, c=batch.num_chunks, k=k,
@@ -148,7 +144,7 @@ def _slice_series(aggs, s: int):
     out = {}
     for name in _SERIES_FIELDS:
         v = getattr(aggs, name)
-        # m3lint: disable=M3L010 -- sanctioned end-of-scan host finalize: the one device->host copy after the fused dispatch (device_out=True is the zero-copy pipelining escape)
+        # m3lint: disable=M3L010 -- sanctioned end-of-scan host finalize: the one device->host copy after the fused dispatch
         out[name] = np.asarray(v)[:s] if v is not None else None
     return aggs._replace(**out)
 
@@ -162,10 +158,6 @@ def resident_fetch_arrays(pool, keys: list):
     can re-read those through the host path.
 
     Returns None when any key is not resident."""
-    from ..ops.chunked import decode_chunked_lanes
-    from ..ops.decode import DecodeResult, finalize_decode
-    from ..parallel.scan import RESIDENT_CHUNKED_PROF, assemble_resident_lanes
-
     with pool.read_lease():
         plan = pool.plan_chunked(keys)
         if plan is None:
@@ -178,8 +170,6 @@ def resident_fetch_arrays(pool, keys: list):
             ("fetch", tuple(lane_args["windows"].shape), int(k))
         ) as d:
             res = d.done(decode_chunked_lanes(**lane_args, k=k))
-
-    import jax.numpy as jnp
 
     rs = lambda x: x.reshape(s_pad, c * k)
     res = DecodeResult(

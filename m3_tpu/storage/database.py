@@ -1412,11 +1412,11 @@ class Database:
         are zero across a warm resident scan)."""
         if self.resident_pool is None:
             return {"enabled": False}
-        from ..resident.scan import _M_STREAMED_BYTES
+        from ..resident.scan import STREAMED_BYTES
 
         return {
             **self.resident_pool.stats(),
-            "streamed_bytes": _M_STREAMED_BYTES.value,
+            "streamed_bytes": STREAMED_BYTES.value,
         }
 
     def resident_clear(self) -> int:

@@ -195,8 +195,8 @@ class Registry:
 
     def collect(self) -> dict:
         """Structured snapshot of every family — the machine-readable
-        sibling of :meth:`expose` (bench.py's metrics JSON line and
-        tools/check_metrics.py consume this instead of re-parsing text).
+        sibling of :meth:`expose` (tools/check_metrics.py consumes this
+        instead of re-parsing text).
 
         Returns {name: {"kind", "help", "children": [{"labels", ...}]}}
         where counter/gauge children carry {"value"} and histogram children
@@ -404,9 +404,9 @@ class KernelProfiler:
 
     Usage::
 
-        _PROF = KernelProfiler("m3tsz_decode")
-        with _PROF.dispatch((words.shape, max_points)) as d:
-            d.done(decode_batched(...))
+        _PROF = KernelProfiler("packed_lane_agg")
+        with _PROF.dispatch((windows4.shape, n, k)) as d:
+            d.done(lane_aggregates_packed(...))
     """
 
     def __init__(self, kernel: str, registry: Registry | None = None,

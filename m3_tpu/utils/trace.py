@@ -216,6 +216,14 @@ class _Stage:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         wall = self.wall_ns = time.perf_counter_ns() - self._t0
+        span = self.span
+        if span is not None:
+            # a span ends on the clock that started it, read in program
+            # order (after its children's ends, as its start was read
+            # before their starts), so it covers them whatever the load;
+            # start_nanos + wall would not, a thread can be held between
+            # the two clocks' readings in __enter__
+            span.end_nanos = time.time_ns()
         cpu = time.thread_time_ns() - self._c0 if self._c0 >= 0 else 0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
@@ -228,12 +236,10 @@ class _Stage:
             rec.current_stage = self._prev
         if self.root:
             loc.op = self._prev_op
-        span = self.span
         if span is not None:
             stack = loc.stack
             if stack and stack[-1] is span:
                 stack.pop()
-            span.end_nanos = span.start_nanos + wall
             if exc is not None:
                 span.error = f"{exc_type.__name__}: {exc}"
             tracer._record(span)

@@ -256,7 +256,7 @@ def test_random_ast_property_suite():
 
 
 def test_multichip_dryrun_regexp_parity():
-    """The MULTICHIP_r05 parity surface: a 65k-series index, regexp
+    """The multi-chip dry run's parity surface: a 65k-series index, regexp
     matching a ~5% slice (__graft_entry__.dryrun_multichip's query),
     resolved by the device executor bit-identically to the host."""
     n_series = 65536 + 3

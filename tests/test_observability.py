@@ -451,7 +451,7 @@ def test_sampled_request_yields_one_stage_tree(served, kind, op, want):
     roots = [s for s in spans if s["parentId"] not in by_id]
     assert [s["name"] for s in roots] == [f"rpc.server.{op}"]
     assert roots[0]["parentId"] == f"{parent:016x}"
-    slack = 1_000_000  # span starts are wall clock, durations perf_counter
+    slack = 1_000_000  # a span starts and ends on the wall clock
     for s in spans:
         p = by_id.get(s["parentId"])
         if p is not None:

@@ -15,14 +15,12 @@ import pytest
 
 from m3_tpu.cache.block_cache import BlockKey
 from m3_tpu.codec.m3tsz import Encoder, decode
-from m3_tpu.resident import (
-    ResidentOptions,
-    ResidentPool,
-    ResidentPoolError,
+from m3_tpu.resident import ResidentOptions, ResidentPool, ResidentPoolError
+from m3_tpu.resident.scan import (
     resident_fetch_arrays,
     resident_scan_totals,
+    streamed_scan_totals,
 )
-from m3_tpu.resident.scan import streamed_scan_totals
 
 NANOS = 1_000_000_000
 T0 = 1_600_000_000 * NANOS
@@ -431,7 +429,7 @@ def test_eviction_mid_plan_scan_stays_consistent():
         # eviction lands while the scan's lease is active: the planned
         # arrays (host int vectors + device buffer refs) stay usable
         pool.invalidate_series_block("ns", 0, b"v1", T0)
-        from m3_tpu.parallel.scan import assemble_resident_packed
+        from m3_tpu.resident.gather import assemble_resident_packed
 
         (w4, l4, tf), s_pad = assemble_resident_packed(plan, 8)
         assert w4.shape[0] >= 1  # assembly from the snapshot still works
@@ -486,7 +484,7 @@ def test_admission_donates_inplace_unless_scan_lease_active():
         assert st["copy_admissions"] == 1
         assert st["inplace_admissions"] == base["inplace_admissions"]
         # the leased snapshot still decodes scan-consistent totals
-        from m3_tpu.parallel.scan import assemble_resident_packed
+        from m3_tpu.resident.gather import assemble_resident_packed
 
         assert plan is not None
         assemble_resident_packed(plan, 8)
@@ -576,12 +574,37 @@ def test_streamed_scan_bytes_counts_block_bytes():
     resident path eliminates) — not the packed lane expansion, which
     duplicates window words across chunks and would silently rescale
     dashboards and heat comparisons several-fold."""
-    from m3_tpu.resident.scan import _M_STREAMED_BYTES, streamed_scan_totals
+    from m3_tpu.resident.scan import STREAMED_BYTES, streamed_scan_totals
 
     streams, _bounds, _ = _random_series(np.random.default_rng(5), 6)
-    before = _M_STREAMED_BYTES.value
+    before = STREAMED_BYTES.value
     streamed_scan_totals(streams)
-    assert _M_STREAMED_BYTES.value - before == sum(len(s) for s in streams)
+    assert STREAMED_BYTES.value - before == sum(len(s) for s in streams)
+
+
+def test_streamed_scan_totals_over_fileset_segments(tmp_path):
+    """Disk -> FilesetReader segments -> the served streamed scan: count
+    and sum equal the arithmetic totals, at the chunk size the fileset
+    was written with (the scan's default)."""
+    from m3_tpu.codec.m3tsz import encode_series
+    from m3_tpu.storage.fs import CHUNK_K, FilesetID, FilesetReader, write_fileset
+
+    series = {
+        f"s{i}".encode(): encode_series(
+            [T0 + j * NANOS for j in range(40)],
+            [float(i + j) for j in range(40)],
+        )
+        for i in range(20)
+    }
+    fid = FilesetID("ns", 0, T0, 0)
+    write_fileset(str(tmp_path), fid, series, 2 * 3600 * NANOS, CHUNK_K)
+    reader = FilesetReader(str(tmp_path), fid)
+
+    aggs = streamed_scan_totals([reader.stream(sid) for sid in reader.series_ids])
+    assert int(aggs.total_count) == 20 * 40
+    assert np.asarray(aggs.series_count).tolist() == [40] * 20
+    want = sum(float(i + j) for i in range(20) for j in range(40))
+    np.testing.assert_allclose(float(aggs.total_sum), want, rtol=1e-6)
 
 
 def test_explain_never_claims_resident_when_chunked_plan_fails(resident_db, monkeypatch):

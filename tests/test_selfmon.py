@@ -237,29 +237,26 @@ def test_kernel_profiler_excludes_compiles_from_dispatch_histogram():
 
 
 def test_scan_dispatch_profiled(monkeypatch):
-    """The flagship decode path actually feeds the dispatch counters."""
-    from m3_tpu.parallel import scan as pscan
-    from m3_tpu.segment.batched import BatchedSegments
+    """The served decode path actually feeds the dispatch counters."""
     from m3_tpu.codec.m3tsz import Encoder
+    from m3_tpu.ops.fused import PROFILER_PACKED
+    from m3_tpu.resident.scan import streamed_scan_totals
     from m3_tpu.utils.instrument import DEFAULT as METRICS
 
     enc = Encoder(T0)
     for i in range(4):
         enc.encode(T0 + i * NANOS, float(i))
-    segs = BatchedSegments.from_streams([enc.stream()])
-    before = pscan._JIT_DECODE._n
-    monkeypatch.setattr(pscan._JIT_DECODE, "sample_rate", 1.0)
+    before = PROFILER_PACKED._n
+    monkeypatch.setattr(PROFILER_PACKED, "sample_rate", 1.0)
     # twice: the first call per signature is compile-attributed and
     # deliberately excluded from the dispatch histogram
     for _ in range(2):
-        aggs = pscan.scan_aggregate(
-            segs.words, segs.num_bits, segs.initial_units(), max_points=8
-        )
+        aggs = streamed_scan_totals([enc.stream()])
     assert int(aggs.total_count) == 4
-    assert pscan._JIT_DECODE._n == before + 2
+    assert PROFILER_PACKED._n == before + 2
     fam = METRICS.collect()["m3tpu_kernel_dispatch_seconds"]
     counts = {c["labels"]["kernel"]: c["count"] for c in fam["children"]}
-    assert counts.get("m3tsz_decode", 0) >= 1
+    assert counts.get("packed_lane_agg", 0) >= 1
 
 
 # --- exemplars: slow bucket -> stitched trace -> slow-query record ---

@@ -124,13 +124,13 @@ def test_fused_temporal_kernel(sds, name):
 
 
 def test_resident_assemble_and_decode(sds, monkeypatch):
-    """parallel/scan.resident_chunked_local_fn — the whole warm-scan
+    """resident/gather.resident_chunked_local_fn — the whole warm-scan
     program (pool + side-plane gathers fused with the packed kernel) at the
     buffers a 1 GiB --resident-bytes allocates. The temp bound is the
     regression guard for the side-plane layout: a [pages, spc, 10] side
     buffer cost a 9.8 GB re-layout temp inside this program."""
     from m3_tpu import device
-    from m3_tpu.parallel.scan import resident_chunked_local_fn
+    from m3_tpu.resident.gather import resident_chunked_local_fn
 
     monkeypatch.setattr(device, "on_tpu", lambda: True)
     o, pool = _pool_shapes(sds)
@@ -232,7 +232,7 @@ def _compile_plan_program(sds, monkeypatch, n_docs: int, cw: int, lane_pages: in
     points = cap * CHUNKS * CHUNK_K
     assert not [name for name in operands if elems[name] == points]
     # and ONE gather yields a word per window slot: the pool's. The page id
-    # of each word is two ids a lane and a select (parallel/scan.py
+    # of each word is two ids a lane and a select (resident/gather.py
     # _resident_gather), not a second gather over [lanes, cw]
     slots = cap * CHUNKS * cw
     per_word = re.findall(r"^\s*(?:ROOT )?(%\S+) = \w+\[[\d,]*\]\S* gather\(", text, re.M)
