@@ -396,8 +396,15 @@ def _build_program(ast, dims):
                 q_keys, q_lens, q_lo, q_hi, r_lo, r_hi,
                 pool_words, side_words,
                 t_pages, t_sides, t_chunks, t_bits, t_bhi, t_blo,
-                g_hi, g_lo, flo, fhi, lb):
+                request):
         i32 = jnp.int32
+        # what a request brings, in one array (one transfer to the device,
+        # where two arrays and six scalars were eight: _pack_request)
+        g_hi, g_lo = request[:t_grid], request[t_grid:2 * t_grid]
+        flo, fhi, lb = (
+            (request[2 * t_grid + 2 * i], request[2 * t_grid + 2 * i + 1])
+            for i in range(3)
+        )
 
         # ---- stage 1: batched term match (every exact leaf, one search)
         if q_keys.shape[0]:
@@ -489,10 +496,53 @@ def _build_program(ast, dims):
             ts, (vhi, vlo, pif.astype(i32), mlt), valid,
             (g_hi, g_lo), flo, fhi, lb,
         )
-        return (bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml, ok)
+        # ONE array out, as one came in (_unpack_reply): every output read
+        # back on its own is a blocking call that hands the interpreter
+        # lock away, and beside other handler threads each one ends in a
+        # wait to get it back (PERF.md section 6, PR 34)
+        return jnp.concatenate([
+            x.reshape(-1) if x.dtype == jnp.uint32
+            else jax.lax.bitcast_convert_type(x.astype(i32), jnp.uint32).reshape(-1)
+            for x in (n_matched, bitmap, counts, err, g_vh, g_vl, g_pf, g_ml, ok)
+        ])
 
     _M_COMPILES.inc()
     return jax.jit(program)
+
+
+def _pack_request(grid: np.ndarray, t_grid: int, fetch_lo: int,
+                  fetch_hi: int, lookback_nanos: int) -> np.ndarray:
+    """A request's own arguments of the plan program as ONE u32 array:
+    the padded step grid as hi and lo words, then the fetch bounds and the
+    lookback as (hi, lo) pairs. Each host array or scalar handed to the
+    program is a transfer of its own, 0.17 ms apiece on the chip (PERF.md
+    section 6, PR 34)."""
+    g = np.zeros(t_grid, np.int64)
+    g[: len(grid)] = grid
+    if len(grid):
+        g[len(grid):] = grid[-1]  # padded steps are discarded
+    g = g.astype(np.uint64)
+    out = np.empty(2 * t_grid + 6, np.uint32)
+    out[:t_grid] = g >> np.uint64(32)
+    out[t_grid:2 * t_grid] = g & np.uint64(0xFFFFFFFF)
+    for i, v in enumerate((fetch_lo, fetch_hi, lookback_nanos)):
+        v = int(v) & ((1 << 64) - 1)
+        out[2 * t_grid + 2 * i] = v >> 32
+        out[2 * t_grid + 2 * i + 1] = v & 0xFFFFFFFF
+    return out
+
+
+def _unpack_reply(packed: np.ndarray, n_words: int, cap: int, t_grid: int):
+    """The plan program's one output array (the count, the bitmap's
+    ``n_words``, counts and error flags a slot, then five [cap, t_grid]
+    planes) -> (bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml,
+    ok), as views of it."""
+    n_matched, rest = int(packed[0]), packed[1:]
+    bitmap, rest = rest[:n_words], rest[n_words:]
+    counts, err, rest = rest[:cap].view(np.int32), rest[cap:2 * cap], rest[2 * cap:]
+    g_vh, g_vl, g_pf, g_ml, ok = rest.reshape(5, cap, t_grid)
+    return (bitmap, n_matched, counts, err != 0, g_vh, g_vl, g_pf,
+            g_ml.view(np.int32), ok != 0)
 
 
 def _finalize_grid(vhi, vlo, pif, mult, ok) -> np.ndarray:
@@ -948,20 +998,8 @@ class Planner:
         with TRACER.stage("plan.enqueue"):
             pool = self.db.resident_pool
             t_grid = entry.dims[-1]
-            g = np.zeros(t_grid, np.int64)
-            g[: len(grid)] = grid
-            if len(grid):
-                g[len(grid):] = grid[-1]  # padded steps discarded below
-            gu = g.astype(np.uint64)
-            g_hi = (gu >> np.uint64(32)).astype(np.uint32)
-            g_lo = (gu & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-
-            def pair(v: int):
-                v = int(v) & ((1 << 64) - 1)
-                return (
-                    np.uint32(v >> 32),
-                    np.uint32(v & 0xFFFFFFFF),
-                )
+            request = _pack_request(
+                grid, t_grid, fetch_lo, fetch_hi, lookback_nanos)
 
             with pool.read_lease():
                 # buffer snapshots under the lease (same discipline as the
@@ -988,13 +1026,13 @@ class Planner:
                         *entry.inputs,
                         words, side,
                         *entry.tables,
-                        g_hi, g_lo, pair(fetch_lo), pair(fetch_hi),
-                        pair(lookback_nanos),
+                        request,
                     ))
         with TRACER.stage("plan.device_wait"):
             (bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml, ok) = (
-                # m3lint: disable=M3L010 -- sanctioned end-of-query host finalize: the ONE device->host readback after the fused program dispatch
-                np.asarray(x) for x in outs
+                _unpack_reply(
+                    # m3lint: disable=M3L010 -- sanctioned end-of-query host finalize: the ONE device->host readback after the fused program dispatch
+                    np.asarray(outs), entry.dims[0], entry.cap, t_grid)
             )
         with TRACER.stage("plan.finalize"):
             n = int(n_matched)

@@ -255,6 +255,47 @@ def test_consolidate_last_bitexact_vs_host_rule(case):
     assert not np.isnan(got).all(), "the case consolidates nothing"
 
 
+@pytest.mark.parametrize("steps,t_grid", [(0, 8), (5, 8), (61, 128), (128, 128)])
+def test_pack_request_is_the_program_s_eight_arguments_in_one(steps, t_grid):
+    """The plan program takes a request's grid, fetch bounds and lookback
+    as one u32 array (one host-to-device transfer): the words are those the
+    eight separate arguments carried, padded steps repeating the last."""
+    grid = np.int64(1_700_000_000_000_000_000) + np.arange(steps, dtype=np.int64) * 10**10
+    lo, hi, lb = int(grid[0]) - 5 * 10**9 if steps else 0, (1 << 63) + 7, 300 * 10**9
+    got = qplan._pack_request(grid, t_grid, lo, hi, lb)
+    assert got.dtype == np.uint32 and got.shape == (2 * t_grid + 6,)
+    padded = np.concatenate([grid, np.full(t_grid - steps, grid[-1] if steps else 0)])
+    assert (got[:t_grid].astype(np.uint64) << np.uint64(32) | got[t_grid:2 * t_grid]
+            == padded.astype(np.uint64)).all()
+    pairs = got[2 * t_grid:].astype(object)
+    assert [(int(pairs[i]) << 32) | int(pairs[i + 1]) for i in (0, 2, 4)] == [lo, hi, lb]
+
+
+@pytest.mark.parametrize("n_words,cap,t_grid", [(1, 8, 8), (127, 64, 128), (4, 512, 16)])
+def test_unpack_reply_is_the_program_s_nine_outputs(n_words, cap, t_grid):
+    """The plan program hands back ONE u32 array (one read-back): the
+    count, the bitmap, the per-slot counts and error flags, then the five
+    [cap, t_grid] planes, signed and boolean ones bit-cast. What comes out
+    is what went in, dtype for dtype where the finalize reads one."""
+    rng = np.random.default_rng(n_words)
+    u32 = lambda *shape: rng.integers(0, 1 << 32, shape, dtype=np.uint64).astype(np.uint32)
+    want = (u32(n_words), 37, rng.integers(0, 736, cap).astype(np.int32),
+            rng.random(cap) < 0.1, u32(cap, t_grid), u32(cap, t_grid),
+            rng.integers(0, 2, (cap, t_grid)).astype(np.int32),
+            rng.integers(-6, 7, (cap, t_grid)).astype(np.int32),  # mult may be negative
+            rng.random((cap, t_grid)) < 0.7)
+    n, bitmap, *rest = want[1], want[0], *want[2:]
+    packed = np.concatenate(
+        [np.asarray([n], np.int32).view(np.uint32), bitmap]
+        + [np.asarray(x).astype(np.int32).view(np.uint32).reshape(-1) for x in rest])
+    got = qplan._unpack_reply(packed, n_words, cap, t_grid)
+    assert len(got) == 9 and got[1] == 37
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+    assert got[2].dtype == np.int32 and got[7].dtype == np.int32
+    assert got[3].dtype == bool and got[8].dtype == bool
+
+
 def test_fused_matches_doc_ids_and_order(plan_db):
     _seed(plan_db)
     eng = Engine(M3Storage(plan_db, "ns"))

@@ -203,7 +203,6 @@ def _compile_plan_program(sds, monkeypatch, n_docs: int, cw: int, lane_pages: in
     cap = n_docs if cap is None else cap
     dims = (n_words, n_docs, cap, 1, CHUNKS, CHUNK_K, cw, lp, sl,
             o.page_words, o.side_page_chunks, t_grid)
-    pair = (sds((), U32), sds((), U32))
     leaves = sds((2,), I32)
     compiled = plan._build_program(ast, dims).lower(
         sds((N_TERMS, KEY_WORDS), U32), sds((N_TERMS,), I32),
@@ -212,7 +211,7 @@ def _compile_plan_program(sds, monkeypatch, n_docs: int, cw: int, lane_pages: in
         sds((2, KEY_WORDS), U32), leaves, leaves, leaves,
         sds((1,), I32), sds((1,), I32),
         *pool, *tables,
-        sds((t_grid,), U32), sds((t_grid,), U32), pair, pair, pair,
+        sds((2 * t_grid + 6,), U32),
     ).compile()
     plan._build_program.cache_clear()  # nothing later meets the chip's program
     mem = compiled.memory_analysis()
