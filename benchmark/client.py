@@ -1,4 +1,4 @@
-"""One closed-loop client: a process of its own with its own ``RemoteNode``.
+"""One client: a process of its own with its own ``RemoteNode``.
 
 Started by ``run.py`` as ``python client.py <spec.pickle>``; obeys one-line
 JSON commands on stdin and answers each with one JSON line on stdout. It is
@@ -15,7 +15,12 @@ The client's wire codec (``m3_tpu/net/client.py``) stays in the path: it is
 what a coordinator runs in front of a dbnode. This process never imports
 jax.
 
-``fault`` in the spec plants a fault for ``tests/test_faults.py`` (never
+In a ``live`` mix a query client makes the range of every request at the
+send, ending at now by the wall clock and the paced writer's schedule
+(``traffic.end_tick``), and records the end tick it asked for; a write
+client paces its ticks on the wall clock (``pace_secs``).
+
+``fault`` in the spec plants a fault for ``tests/test_faults*.py`` (never
 set by ``run.py``'s command line): ``alter_reply`` changes one value of
 one reply where it is received, ``drop_batch`` acknowledges one tick
 without sending it.
@@ -40,6 +45,7 @@ sys.path.insert(0, HERE)
 from m3_tpu.net.client import RemoteNode  # noqa: E402
 
 from reference import rows_by_host  # noqa: E402
+from traffic import end_tick  # noqa: E402
 
 
 def wait_until(t: float) -> None:
@@ -89,7 +95,9 @@ class WriteClient(Client):
     def write(self, cmd: dict) -> dict:
         """Ticks [first, last] in time order, one ``write_batch`` a tick;
         ``shard`` narrows to one dbnode shard's series, ``ns`` to another
-        namespace (warm-up replays). Stops at ``t_end``."""
+        namespace (warm-up replays). Stops at ``t_end``. With
+        ``pace_secs`` tick j is due at ``t_go + (j - first) * pace_secs``
+        (open loop: a late tick goes at once, and every tick goes)."""
         first, last = cmd["first"], cmd["last"]
         ns = cmd.get("ns", self.ns)
         record = cmd.get("record", False)
@@ -97,8 +105,10 @@ class WriteClient(Client):
         keep = None
         if shard is not None:
             keep = [i for i, s in enumerate(self.shards) if s == shard]
-        wait_until(cmd.get("t_go", 0.0))
+        t_go = cmd.get("t_go", 0.0)
+        wait_until(t_go)
         t_end = cmd.get("t_end", float("inf"))
+        pace = cmd.get("pace_secs")
         write_batch = self.node.write_batch
         n = 0
         dropped = False
@@ -106,10 +116,13 @@ class WriteClient(Client):
             entries = self.ticks[j]
             if keep is not None:
                 entries = [entries[i] for i in keep]
+            if pace is not None:
+                wait_until(t_go + (j - first) * pace)
             t_send = time.perf_counter()
-            if t_send >= t_end:
+            if pace is None and t_send >= t_end:
                 break
-            if self.fault == "drop_batch" and record and not dropped and j > first:
+            if self.fault == "drop_batch" and record and not dropped and (
+                    j > first or pace is not None):
                 dropped = True  # acknowledged here, never sent
             else:
                 write_batch(ns, entries)
@@ -130,11 +143,14 @@ class QueryClient(Client):
     def __init__(self, spec: dict, node: RemoteNode) -> None:
         self.node = node
         self.ns = spec["ns"]
-        # {"warmup": [(query, start, end, step), ...], "window": [...]}
+        # {"warmup": [(query, start, end, step), ...], "window": [...]};
+        # in a live mix (query, span, step): the range is made at the send
         self.requests = spec["requests"]
+        # a live mix: t0, interval_nanos, first_tick, last_tick, pace_secs
+        self.live = spec.get("live")
         self.timeout_s = spec["timeout_s"]
         self.fault = spec.get("fault")
-        self.raw: list = []  # (index, send, recv, reply or None, error)
+        self.raw: list = []  # (index, send, recv, reply or None, error, end tick)
 
     def query(self, cmd: dict) -> dict:
         reqs = self.requests[cmd["which"]][:cmd.get("limit")]
@@ -144,15 +160,25 @@ class QueryClient(Client):
             gc.collect()
             gc.freeze()
             gc.disable()
-        wait_until(cmd.get("t_go", 0.0))
+        t_go = cmd.get("t_go", float("inf"))  # none: warm-up, before any window
+        if "t_go" in cmd:
+            wait_until(t_go)
         t_end = cmd.get("t_end", float("inf"))
         query_range = self.node.query_range
         ns = self.ns
         n = failed = 0
-        for i, (query, start, end, step) in enumerate(reqs):
+        live = self.live
+        for i, req in enumerate(reqs):
             t_send = time.perf_counter()
             if t_send >= t_end:
                 break
+            k_end = None
+            if live is not None:  # ends at now
+                k_end = end_tick(t_send, t_go, live["pace_secs"], live["first_tick"],
+                                 live["last_tick"])
+                end = live["t0"] + k_end * live["interval_nanos"]
+                req = (req[0], end - req[1], end, req[2])
+            query, start, end, step = req
             try:
                 resp = query_range(ns, query, start, end, step)
                 err = None
@@ -161,7 +187,7 @@ class QueryClient(Client):
                 failed += 1
             t_recv = time.perf_counter()
             if record:
-                self.raw.append((i, t_send, t_recv, resp, err))
+                self.raw.append((i, t_send, t_recv, resp, err, k_end))
             n += 1
         if record:
             gc.enable()
@@ -173,7 +199,7 @@ class QueryClient(Client):
         """Reduce the kept replies (after the window): per request the
         times, hostname -> row, and the server's own stats."""
         out = []
-        for k, (i, t_send, t_recv, resp, err) in enumerate(self.raw):
+        for k, (i, t_send, t_recv, resp, err, k_end) in enumerate(self.raw):
             rows, stats = {}, {}
             if resp is not None:
                 rows = rows_by_host(resp)
@@ -182,12 +208,12 @@ class QueryClient(Client):
                     "durationSecs", "deviceDispatches", "planHits", "planMisses",
                     "planFallbacks", "planCoalesced", "residentHits",
                     "residentMisses", "indexDeviceHits", "indexDeviceMisses",
-                    "seriesScanned", "bytesScanned")}
+                    "seriesScanned", "bytesScanned", "stages")}
                 if self.fault == "alter_reply" and k == len(self.raw) // 2 and rows:
                     first = next(iter(rows.values()))
                     first[len(first) // 2] += 1.0
             out.append({"i": i, "send": t_send, "recv": t_recv, "rows": rows,
-                        "stats": stats, "error": err})
+                        "stats": stats, "error": err, "end_tick": k_end})
         with open(cmd["path"], "wb") as f:
             pickle.dump({"replies": out}, f)
         return {"records": len(out)}

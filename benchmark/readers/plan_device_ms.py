@@ -1,6 +1,7 @@
-"""Device milliseconds a request: the traced device-busy seconds of the
-window (the union of the device's operation intervals in the profiler's
-trace of the dbnode) over the window's answered requests, x 1000. Every
+"""Device milliseconds a request: the traced device-busy seconds (the union
+of the device's operation intervals in the profiler's trace of the dbnode)
+over the answered requests of the traced slice of the window (a traced
+run's ``window["replies"]``: sent and received before the stop), x 1000. Every
 request of a haystack cell is one plan program and one temporal kernel, so
 this is what one request costs the device whatever the host adds."""
 

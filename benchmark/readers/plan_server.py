@@ -1,5 +1,6 @@
 """The server's own seconds per query (QueryStats.durationSecs in each
-reply), median over the window, in ms."""
+reply), median over the window (in a traced run: over its traced slice),
+in ms."""
 
 import statistics
 

@@ -1,4 +1,5 @@
-"""Share of the HBM roofline the window's queries reached, in %: the bytes
+"""Share of the HBM roofline the queries of the traced slice of the window
+reached (a traced run's ``window["replies"]``), in %: the bytes
 they HAD to move (the compressed bytes of the matched series' blocks in
 range, by the resident pool's own bytes per entry, plus each result's
 float64 cells), over the chip's HBM bandwidth (peaks.json, keyed by
@@ -37,5 +38,5 @@ def read(ctx, layer):
     kind = ctx.device_kind
     if kind not in peaks:
         raise KeyError(f"device kind {kind!r} is not in peaks.json")
-    # the replies counted span the whole window; the trace spans it too
+    # the replies counted are the traced slice's, and so is the trace
     return 100.0 * need / peaks[kind]["hbm_bytes_per_s"] / ts["busy_s"]

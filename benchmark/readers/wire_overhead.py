@@ -1,5 +1,6 @@
 """Client latency less the server's own seconds (QueryStats.durationSecs),
-median over the window's replies, in ms: wire codec both ways, socket,
+median over the window's replies (a traced run's: those of its traced
+slice), in ms: wire codec both ways, socket,
 dispatch to the handler, and the reply's encoding."""
 
 import statistics

@@ -10,8 +10,8 @@ t - range .. t, both ends included.
 Every comparison here is exact (limit 0): ingest to decode is lossless
 (values travel as float64 bit patterns), ``max``/``min`` are selections and
 a plain selector hands back stored values. The controls in
-``tests/test_controls.py`` put a broken reference in the program's place and
-must fail these same functions.
+``tests/test_controls*.py`` put a broken reference in the program's place
+and must fail these same functions.
 """
 
 from __future__ import annotations
@@ -73,6 +73,23 @@ def mismatches(got: dict, want_hosts: list[str], want: np.ndarray) -> int:
         if host not in seen:
             bad += max(int(np.size(g)), 1)
     return bad
+
+
+def ends_past_acknowledged(end_ticks, sends, first_tick: int, acked_at: np.ndarray) -> int:
+    """Requests of a live window that asked for a sample some writer had
+    not had acknowledged when the request was sent: the harness's own
+    pacing at fault, not the program. ``acked_at[w, k - first_tick]`` is
+    the instant writer w's tick k was acknowledged (infinite where it
+    never was); a tick before ``first_tick`` was acknowledged in set-up."""
+    end_ticks = np.asarray(end_ticks, np.int64)
+    sends = np.asarray(sends, np.float64)
+    window = end_ticks >= first_tick
+    rel = end_ticks[window] - first_tick
+    acked_at = np.asarray(acked_at, np.float64)
+    late = np.full(rel.shape, np.inf)
+    inside = rel < acked_at.shape[1]
+    late[inside] = acked_at[:, rel[inside]].max(axis=0)
+    return int(np.count_nonzero(late >= sends[window]))
 
 
 def read_mismatches(got_t, got_v, want_t: np.ndarray, want_v: np.ndarray) -> int:

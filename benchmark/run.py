@@ -6,10 +6,15 @@
 Starts one device-tier dbnode (the only process that holds the chip), makes
 the data from ``--seed`` over the configuration's fixed fleet, loads it over
 the wire with the commit log on, seals it, warms the cell's own shapes (all
-of that is ``setup_s``), drives the cell's traffic from closed-loop client
-processes for ``--seconds``, compares every answer with the plain reference,
-kills the dbnode on every way out and prints one JSON object as its last
-line. ``BENCHMARK.json`` names; files under ``benchmark/`` hold (README.md).
+of that is ``setup_s``), drives the cell's traffic from client processes
+for ``--seconds`` (closed-loop queriers or writers; in a ``live`` mix
+queriers beside a paced writer, over one sealed block and the open one),
+compares every answer with the plain reference, kills the dbnode on every
+way out and prints one JSON object as its last line. ``BENCHMARK.json``
+names; files under ``benchmark/`` hold (README.md).
+
+``--trace 1`` keeps the profiler on for the window's first
+``TRACE_SLICE_SECS`` only: the clients run on to ``--seconds``.
 
 ``--rehearse`` is the sandbox mode: any platform, at most 64 hosts, result
 printed after the word REHEARSAL and never as the result line.
@@ -40,10 +45,14 @@ sys.path.insert(0, HERE)
 import fleet  # noqa: E402
 import reference  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
+from client import wait_until  # noqa: E402
 from node import SCRATCH_NS, Node, cpu_seconds  # noqa: E402
 
 NANOS = fleet.NANOS
 REHEARSAL_MAX_HOSTS = 64
+# what --trace 1 traces: stopping the profiler costs the dbnode about 130 us
+# a device event, and a whole window of a fast cell holds millions
+TRACE_SLICE_SECS = 10.0
 
 
 def say(line: str) -> None:
@@ -160,6 +169,7 @@ class Cell:
         self._vals: np.ndarray | None = None
         self.t0 = fleet.t0_nanos(self.cfg)
         self.n_points = fleet.points_per_block(self.cfg)
+        self.n_ticks = traffic_mod.total_ticks(self.cfg, self.traffic, self.n_points, seconds)
         self.dt = self.cfg["interval_secs"] * NANOS
 
     # -- fleet ---------------------------------------------------------
@@ -171,6 +181,7 @@ class Cell:
         cfg = self.cfg
         self.hosts = fleet.hosts(cfg)
         self.table = fleet.series_table(cfg)
+        self.row_of = {(h, m): i for i, (h, m, _) in enumerate(self.table)}
         self.tags = [fleet.series_tags(self.hosts[h], metric)
                      for h, metric, _ in self.table]
         self.sids = [bytes(encode_tags(t)) for t in self.tags]
@@ -185,9 +196,11 @@ class Cell:
 
     # -- write clients ---------------------------------------------------
 
-    def spawn_writers(self, workers: int, n_ticks: int) -> list[Client]:
-        """Worker w owns the series of the shards s with s % workers == w."""
-        vals = self.values(n_ticks)
+    def spawn_writers(self, workers: int, first: int, last: int) -> list[Client]:
+        """Worker w owns the series of the shards s with s % workers == w;
+        it is handed the ticks ``first .. last - 1`` and counts them from 0
+        (``c.tick0`` is its tick 0)."""
+        vals = self.values()[:, first:last]
         clients = []
         for w in range(workers):
             mine = [i for i, s in enumerate(self.shards) if s % workers == w]
@@ -197,20 +210,21 @@ class Cell:
                 "tags": [self.tags[i] for i in mine],
                 "sids": [self.sids[i] for i in mine],
                 "shards": [self.shards[i] for i in mine],
-                "vals": vals[mine], "t0": self.t0, "interval_nanos": self.dt,
-                "fault": self.fault,
+                "vals": vals[mine], "t0": self.t0 + first * self.dt,
+                "interval_nanos": self.dt, "fault": self.fault,
             }
-            c = Client(spec, os.path.join(self.node.base, f"writer{w}.pickle"))
-            c.series = mine
+            c = Client(spec, os.path.join(self.node.base, f"writer{first}-{w}.pickle"))
+            c.series, c.tick0 = mine, first
             clients.append(c)
             self.clients.append(c)
         for c in clients:
             c.recv()  # READY: every tick's entries are built
         return clients
 
-    def values(self, n_ticks: int) -> np.ndarray:
-        if self._vals is None or self._vals.shape[1] != n_ticks:
-            self._vals = fleet.values(self.cfg, self.seed, n_ticks)
+    def values(self) -> np.ndarray:
+        """The run's whole matrix, every tick it will write a column."""
+        if self._vals is None:
+            self._vals = fleet.values(self.cfg, self.seed, self.n_ticks)
         return self._vals
 
     # -- the hook ----------------------------------------------------------
@@ -235,20 +249,34 @@ class Cell:
             # not under the node's directory: it is reduced after the node has gone
             self.trace_dir = tempfile.mkdtemp(prefix="m3bench-trace-")
             self.node.hook(cmd="trace_start", dir=self.trace_dir)
-            self.window["trace_t0"] = time.perf_counter()
         self.window["cpu0"] = self.procs_cpu(clients)
         t_go = time.perf_counter() + 0.25
         self.phases["setup_s"] = t_go - T_PROCESS
         return t_go
+
+    def drive(self, clients: list[Client], cmds: list[dict], t_go: float,
+              t_end: float) -> list[dict]:
+        """The window: every client its command, every answer awaited.
+        A traced run stops the profiler ``TRACE_SLICE_SECS`` into the
+        window while the clients run on; the traced seconds count from
+        ``t_go`` (nothing is sent before it) to the instant the stop is
+        asked for, and the trace is cut there when it is reduced."""
+        for c, cmd in zip(clients, cmds):
+            c.send(**cmd)
+        if self.trace:
+            wait_until(min(t_go + TRACE_SLICE_SECS, t_end))
+            self.window["trace_until"] = time.perf_counter()
+            self.window["traced_s"] = self.window["trace_until"] - t_go
+            self.node.hook(cmd="trace_stop")
+            self.window["trace_stop_s"] = time.perf_counter() - self.window["trace_until"]
+        self.window["t_go"], self.window["t_end"] = t_go, t_end
+        return [c.recv() for c in clients]
 
     def close_window(self, clients: list[Client]) -> None:
         cpu1 = self.procs_cpu(clients)
         self.window["cpu_s"] = {k: cpu1[k] - v for k, v in self.window["cpu0"].items()}
         self.window["client_peak_rss_bytes"] = [
             c.call(cmd="usage")["peak_rss_bytes"] for c in clients]
-        if self.trace:
-            self.window["traced_s"] = time.perf_counter() - self.window["trace_t0"]
-            self.node.hook(cmd="trace_stop")
         s0, s1 = self.window["stat0"], self.stat()
         self.window["stat1"] = s1
         self.counters["compiles_in_window"] = s1["compiles"] - s0["compiles"]
@@ -266,6 +294,10 @@ class Cell:
             f"at its start {s0.get('bytes_in_use')}, at its close {s1.get('bytes_in_use')}")
         say("window: CPU seconds " + " ".join(
             f"{k} {v:.2f}" for k, v in self.window["cpu_s"].items()))
+        if self.trace:
+            say(f"trace: the window's first {self.window['traced_s']:.3f}s, stopped in "
+                f"{self.window['trace_stop_s']:.1f}s while the clients ran on; the readers "
+                "see the replies of that slice")
 
     # -- phases shared by the kinds ---------------------------------------
 
@@ -275,7 +307,7 @@ class Cell:
         the seal, then the admission checks."""
         cfg, node = self.cfg, self.node
         t = time.perf_counter()
-        writers = self.spawn_writers(cfg["load_workers"], self.n_points)
+        writers = self.spawn_writers(cfg["load_workers"], 0, self.n_points)
         all_calls(writers, [{"cmd": "register"}] * len(writers))
         all_calls(writers, [{"cmd": "write", "first": 1, "last": self.n_points - 1}] * len(writers))
         for c in writers:
@@ -313,83 +345,95 @@ class Cell:
     # -- a query cell ------------------------------------------------------
 
     def run_query(self) -> None:
-        cfg, tr, node = self.cfg, self.traffic, self.node
+        tr = self.traffic
         self.load_block()
-        plan = traffic_mod.query_plan(cfg, tr, self.t0, self.n_points, self.seed,
+        plan = traffic_mod.query_plan(self.cfg, tr, self.t0, self.n_points, self.seed,
                                       seconds=self.seconds)
+        clients = self.spawn_queriers(plan)
+        self.say_setup()
+
+        t_go = self.open_window(clients)
+        t_end = t_go + self.seconds
+        res = self.drive(clients, [self.window_queries(t_go, t_end)] * len(clients),
+                         t_go, t_end)
+        self.close_window(clients)
+        mem = self.window["stat1"].get("peak_bytes")
+        self.judge_replies(clients, plan, res)
+
+        # after the window: a plain selector over the whole block for
+        # series of every value class the segment holds
+        rb_not_device = self.read_back_selectors(self.n_points, "the whole block")
+        self.checks.add("readback_not_served_by_device", rb_not_device, 0)
+        self.window["memory_peak_bytes"] = mem
+
+    def spawn_queriers(self, plan: dict, live: dict | None = None) -> list[Client]:
+        """The query clients, warmed up: the cell's own shapes, through
+        the clients' own connections; first sight of a shape compiles, so
+        no time limit. The first request goes from one client alone, so
+        that one thread of the dbnode makes the plan program and not four
+        at once."""
+        cfg, tr, node = self.cfg, self.traffic, self.node
         t = time.perf_counter()
         clients = []
         for w in range(tr["workers"]):
             spec = {"kind": "query", "endpoint": node.endpoint, "ns": cfg["namespace"],
-                    "timeout_s": tr["timeout_s"], "fault": self.fault,
-                    "requests": {k: plan[k][w].wire() for k in plan}}
+                    "timeout_s": tr["timeout_s"], "fault": self.fault, "live": live,
+                    "requests": {k: plan[k][w].wire() if live is None
+                                 else plan[k][w].wire_now() for k in plan}}
             c = Client(spec, os.path.join(node.base, f"querier{w}.pickle"))
             clients.append(c)
             self.clients.append(c)
         for c in clients:
             c.recv()
-        # warm-up: the cell's own shapes, through the clients' own
-        # connections; first sight of a shape compiles, so no time limit.
-        # The first request goes from one client alone, so that one thread
-        # of the dbnode makes the plan program and not four at once
         clients[0].call(cmd="query", which="warmup", limit=1, timeout=1500.0)
         all_calls(clients, [{"cmd": "query", "which": "warmup", "timeout": 1500.0}] * len(clients))
         self.phases["warmup_s"] = time.perf_counter() - t
-        self.say_setup()
+        return clients
 
-        t_go = self.open_window(clients)
-        t_end = t_go + self.seconds
-        res = all_calls(clients, [{"cmd": "query", "which": "window", "record": True,
-                                   "t_go": t_go, "t_end": t_end,
-                                   "timeout": tr["timeout_s"]}] * len(clients))
-        self.close_window(clients)
-        self.window["t_go"], self.window["t_end"] = t_go, t_end
-        mem = self.window["stat1"].get("peak_bytes")
+    def window_queries(self, t_go: float, t_end: float) -> dict:
+        return {"cmd": "query", "which": "window", "record": True, "t_go": t_go,
+                "t_end": t_end, "timeout": self.traffic["timeout_s"]}
 
-        # every reply of the window against the reference
-        vals = self.values(self.n_points)
-        row_of = {(h, m): i for i, (h, m, _) in enumerate(self.table)}
+    def judge_replies(self, clients: list[Client], plan: dict, res: list[dict]) -> list[dict]:
+        """Every reply of the window against the reference, and the
+        window's latencies; returns them all. A request of a live mix is
+        compared as the request it became at the end tick the client
+        recorded. The readers of a traced run are left the replies of the
+        traced slice (``window["replies"]``): the profiler's stop runs in
+        the dbnode beside the rest of the window and slows it."""
+        tr = self.traffic
+        vals = self.values()
         replies = []
         bad = failed = not_device = 0
+        by_class: dict[str, list] = {}
         for w, c in enumerate(clients):
             for rec in c.dump()["replies"]:
                 req = plan["window"][w][rec["i"]]
+                if rec["end_tick"] is not None:
+                    req = traffic_mod.at_end_tick(req, self.t0, self.dt, rec["end_tick"])
                 rec["latency_s"] = rec["recv"] - rec["send"]
                 if rec["error"] is not None:
                     failed += 1
                     rec["latency_s"] = float(tr["timeout_s"])
+                    if failed <= 3:
+                        say(f"failed: {req['query']} {rec['error']}")
                 else:
-                    bad += self.compare_reply(vals, row_of, req, rec["rows"])
+                    bad += self.compare_reply(vals, req, rec["rows"])
                     if not served_by_device(rec["stats"]):
                         not_device += 1
                         if not_device <= 3:
                             say(f"not served by the device: {req['query']} stats {rec['stats']}")
+                by_class.setdefault(f"{req['fn']}({req['metric']})", []).append(rec["latency_s"])
                 replies.append(rec)
-        self.window["replies"] = replies
+        self.window["replies"] = replies if not self.trace else [
+            r for r in replies if r["recv"] <= self.window["trace_until"]]
         self.window["attempted"] = sum(r["requests"] for r in res)
         self.window["failed"] = failed
         self.checks.add("window_requests_failed", failed, 0)
         self.checks.add("window_reply_cells_differ", bad, 0)
         self.checks.add("window_replies_not_served_by_device", not_device, 0)
 
-        # after the window: a plain selector over the whole block for
-        # series of every value class the segment holds
-        t = time.perf_counter()
-        rb_bad = rb_not_device = 0
-        rb = traffic_mod.readback_requests(
-            cfg, self.table, self.t0, self.n_points, self.seed, tr["readback_per_class"])
-        for req in rb:
-            resp = node.client.query_range(
-                cfg["namespace"], req["query"], req["start"], req["end"], req["step"])
-            rb_bad += self.compare_reply(vals, row_of, req, reference.rows_by_host(resp))
-            rb_not_device += int(not served_by_device(resp.get("stats") or {}))
-        self.phases["readback_s"] = time.perf_counter() - t
-        self.checks.add("readback_cells_differ", rb_bad, 0)
-        self.checks.add("readback_not_served_by_device", rb_not_device, 0)
-        say(f"read-back: {len(rb)} selectors over the whole block "
-            f"({sorted({r['class'] for r in rb})}) in {self.phases['readback_s']:.1f}s")
-        self.window["memory_peak_bytes"] = mem
-
+        t_go = self.window["t_go"]
         lat = np.asarray([r["latency_s"] for r in replies]) * 1e3
         srv = np.asarray([(r["stats"].get("durationSecs") or 0) * 1e3 for r in replies])
         order = np.argsort([r["send"] for r in replies])
@@ -409,28 +453,131 @@ class Cell:
             if mask.any():
                 say(f"window: {int(mask.sum())} {what}, median {np.median(lat[mask]):.1f} ms, "
                     f"server median {np.median([r['stats'].get('durationSecs') or 0 for r, m in zip(replies, mask) if m]) * 1e3:.1f} ms")
+        if len(by_class) > 1:
+            say("window: requests and median ms by class: " + "; ".join(
+                f"{k} {len(v)} {np.median(v) * 1e3:.1f}" for k, v in sorted(by_class.items())))
         if 0 < (~hit).sum() <= 8:
             say("window: plan misses sent at (s into the window) " + " ".join(
                 f"{r['send'] - t_go:.2f}" for r, h in zip(replies, hit) if not h))
+        stages: dict[str, float] = {}
+        for r in replies:
+            for k, v in (r["stats"].get("stages") or {}).items():
+                stages[k] = stages.get(k, 0.0) + v
+        if stages:
+            say("window: the server's stages, mean ms a request: " + " ".join(
+                f"{k} {v * 1e3 / len(replies):.2f}"
+                for k, v in sorted(stages.items(), key=lambda kv: -kv[1])))
         self.window["latencies_ms"] = lat
         self.e2e = {
             "query_p50_ms": float(np.percentile(lat, 50)),
             "query_p95_ms": float(np.percentile(lat, 95)),
         }
+        return replies
 
-    def compare_reply(self, vals, row_of, req: dict, rows: dict) -> int:
+    def read_back_selectors(self, n_ticks: int, what: str) -> int:
+        """A plain selector over ticks ``0 .. n_ticks - 1``, every sample
+        a step, for ``readback_per_class`` series of every value class
+        (check ``readback_cells_differ``); returns how many of them no
+        device dispatch served."""
+        cfg, node = self.cfg, self.node
+        t = time.perf_counter()
+        rb_bad = rb_not_device = 0
+        rb = traffic_mod.readback_requests(
+            cfg, self.table, self.t0, n_ticks, self.seed, self.traffic["readback_per_class"])
+        for req in rb:
+            resp = node.client.query_range(
+                cfg["namespace"], req["query"], req["start"], req["end"], req["step"])
+            rb_bad += self.compare_reply(self.values(), req, reference.rows_by_host(resp))
+            rb_not_device += int(not served_by_device(resp.get("stats") or {}))
+        self.phases["readback_s"] = time.perf_counter() - t
+        self.checks.add("readback_cells_differ", rb_bad, 0)
+        say(f"read-back: {len(rb)} selectors over {what} "
+            f"({sorted({r['class'] for r in rb})}) in {self.phases['readback_s']:.1f}s, "
+            f"{rb_not_device} not served by the device")
+        return rb_not_device
+
+    def compare_reply(self, vals, req: dict, rows: dict) -> int:
         hosts = ([req["host"]] if req["host"] is not None
                  else list(range(self.cfg["hosts"])))
-        idx = np.asarray([row_of[(h, req["metric"])] for h in hosts])
+        idx = np.asarray([self.row_of[(h, req["metric"])] for h in hosts])
         want = reference.answer(vals, idx, req)
         return reference.mismatches(rows, [f"host_{h}" for h in hosts], want)
+
+    # -- a live cell -------------------------------------------------------
+
+    def run_live(self) -> None:
+        """One sealed block and ``open_ticks`` of the open one, then the
+        queriers beside the writer in real time: tick ``first + i`` is due
+        at ``t_go + i * interval_secs``."""
+        cfg, tr = self.cfg, self.traffic
+        pace = cfg["interval_secs"]
+        first = self.n_points + tr["open_ticks"]
+        self.load_block()
+        t = time.perf_counter()
+        writers = self.spawn_writers(tr["writer"]["workers"], self.n_points, self.n_ticks)
+        # the open block's first tick carries the tags, as the sealed one's
+        # did: the index is kept by block, and a range that lies in the open
+        # block alone resolves its series there
+        all_calls(writers, [{"cmd": "register"}] * len(writers))
+        all_calls(writers, [{"cmd": "write", "first": 1, "last": tr["open_ticks"] - 1}] * len(writers))
+        self.phases["open_s"] = time.perf_counter() - t
+        plan = traffic_mod.query_plan(cfg, tr, self.t0, self.n_points, self.seed,
+                                      seconds=self.seconds)
+        queriers = self.spawn_queriers(plan, live={
+            "t0": self.t0, "interval_nanos": self.dt, "pace_secs": pace,
+            "first_tick": first, "last_tick": self.n_ticks - 1})
+        self.say_setup()
+
+        clients = writers + queriers
+        t_go = self.open_window(clients)
+        t_end = t_go + self.seconds
+        paced = {"cmd": "write", "first": tr["open_ticks"], "record": True, "t_go": t_go,
+                 "last": self.n_ticks - self.n_points - 1, "pace_secs": pace}
+        res = self.drive(clients, [paced] * len(writers)
+                         + [self.window_queries(t_go, t_end)] * len(queriers), t_go, t_end)
+        self.close_window(clients)
+        mem = self.window["stat1"].get("peak_bytes")
+
+        # the writers' acknowledgements: latency from the instant a tick
+        # was due, and which sample each request could ask for
+        sent = [c.dump()["sent"] for c in writers]
+        acked_to = [first + len(s_) for s_ in sent]
+        acked_at = np.full((len(writers), self.n_ticks - first), np.inf)
+        ack_ms, late = [], 0.0
+        for w, (c, s_) in enumerate(zip(writers, sent)):
+            ticks = s_[:, 0].astype(np.int64) + c.tick0
+            due = t_go + (ticks - first) * pace
+            acked_at[w, ticks - first] = s_[:, 2]
+            ack_ms.append((s_[:, 2] - due) * 1e3)
+            late = max(late, float((s_[:, 1] - due).max(initial=0.0)))
+        self.window["ack_ms"] = np.concatenate(ack_ms)
+        say(f"window: the paced writer sent {sorted(len(s_) for s_ in sent)} ticks "
+            f"({first}..), acknowledged after a median {np.median(self.window['ack_ms']):.1f} ms "
+            f"from due, the latest send {late * 1e3:.1f} ms late")
+
+        replies = self.judge_replies(queriers, plan, res[len(writers):])
+        past = reference.ends_past_acknowledged(
+            [r["end_tick"] if r["end_tick"] is not None else -1 for r in replies],
+            [r["send"] for r in replies], first, acked_at)
+        self.checks.add("window_requests_ending_past_the_acknowledged", past, 0)
+        ends = np.asarray([r["end_tick"] for r in replies if r["end_tick"] is not None])
+        if len(ends):
+            say("window: requests by the end tick they asked for: " + " ".join(
+                f"{k}x{n}" for k, n in zip(*np.unique(ends, return_counts=True))))
+
+        # after the window, before anything else is sealed: the selectors
+        # over the sealed block and the open one up to the last tick every
+        # writer had acknowledged, and every acknowledged point of a
+        # seeded sample of series through ``read``
+        self.read_back_selectors(min(acked_to), "the sealed block and the open one")
+        self.read_back_points(self.sample_series(writers, acked_to), "readback")
+        self.window["memory_peak_bytes"] = mem
 
     # -- a write cell ------------------------------------------------------
 
     def run_write(self) -> None:
         cfg, tr, node = self.cfg, self.traffic, self.node
-        ns = cfg["namespace"]
-        n_ticks = self.n_points * tr["blocks"]
+        n_ticks = self.n_ticks
         plan = traffic_mod.write_plan(
             self.shard_counts, cfg["dbnode"]["ingest_sync_batch"], self.n_points, tr["blocks"])
         k = plan["warmup_ticks"]
@@ -438,7 +585,7 @@ class Cell:
             f"{len(plan['replays'])} boundary replays in namespace {SCRATCH_NS!r}; "
             f"tiles per shard (lanes, slots): {plan['tiles']}")
         t = time.perf_counter()
-        clients = self.spawn_writers(tr["workers"], n_ticks)
+        clients = self.spawn_writers(tr["workers"], 0, n_ticks)
         self.phases["build_s"] = time.perf_counter() - t
         t = time.perf_counter()
         all_calls(clients, [{"cmd": "register"}] * len(clients))
@@ -453,8 +600,8 @@ class Cell:
 
         t_go = self.open_window(clients)
         t_end = t_go + self.seconds
-        all_calls(clients, [{"cmd": "write", "first": k, "last": n_ticks - 1, "record": True,
-                             "t_go": t_go, "t_end": t_end}] * len(clients))
+        self.drive(clients, [{"cmd": "write", "first": k, "last": n_ticks - 1, "record": True,
+                              "t_go": t_go, "t_end": t_end}] * len(clients), t_go, t_end)
         self.close_window(clients)
         log1 = node.commitlog_bytes()
         mem = self.window["stat1"].get("peak_bytes")
@@ -485,32 +632,40 @@ class Cell:
         # the acknowledged points of a seeded sample of series, read back
         # before the seal (ingest buffer path) and again after a flush
         # (device-resident block)
-        vals = self.values(n_ticks)
+        sample = self.sample_series(clients, acked_to)
+        self.read_back_points(sample, "readback_before_seal")
+        # the first block only: the read-back then spans a device-resident
+        # block and the open one, and every run pays one block's seal, not two
+        self.seal(self.t0 + cfg["block_secs"] * NANOS)
+        self.read_back_points(sample, "readback_after_flush")
+
+    def sample_series(self, writers: list[Client], acked_to: list[int]) -> list[tuple[int, int]]:
+        """(series, ticks acknowledged) of ``readback_series`` series drawn
+        from the seed, every writer's share alike."""
         rng = fleet.rng_for(self.seed, fleet.STREAM_READBACK)
         sample = []
-        for w, c in enumerate(clients):
-            pick = rng.choice(len(c.series), size=min(tr["readback_series"] // workers,
-                                                      len(c.series)), replace=False)
+        for w, c in enumerate(writers):
+            pick = rng.choice(len(c.series), size=min(
+                self.traffic["readback_series"] // len(writers), len(c.series)), replace=False)
             sample += [(c.series[int(i)], acked_to[w]) for i in pick]
-        end = self.t0 + n_ticks * self.dt
-        for phase in ("before_seal", "after_flush"):
-            t = time.perf_counter()
-            if phase == "after_flush":
-                # the first block only: the read-back then spans a
-                # device-resident block and the open one, and every run
-                # pays one block's seal, not two
-                self.seal(self.t0 + cfg["block_secs"] * NANOS)
-            bad = 0
-            for i, n in sample:
-                dps = node.client.read(ns, self.sids[i], self.t0, end)
-                bad += reference.read_mismatches(
-                    [d.timestamp for d in dps], [d.value for d in dps],
-                    self.t0 + self.dt * np.arange(n), vals[i, :n])
-            self.checks.add(f"readback_{phase}_points_differ", bad, 0)
-            self.phases[f"readback_{phase}_s"] = time.perf_counter() - t
-        say(f"read-back: {len(sample)} series, before the seal "
-            f"{self.phases['readback_before_seal_s']:.1f}s, seal and after "
-            f"{self.phases['readback_after_flush_s']:.1f}s")
+        return sample
+
+    def read_back_points(self, sample: list[tuple[int, int]], name: str) -> None:
+        """Every acknowledged point of the sampled series through ``read``
+        (check ``<name>_points_differ``, phase ``<name>_s``)."""
+        t = time.perf_counter()
+        vals = self.values()
+        end = self.t0 + self.n_ticks * self.dt
+        bad = 0
+        for i, n in sample:
+            dps = self.node.client.read(self.cfg["namespace"], self.sids[i], self.t0, end)
+            bad += reference.read_mismatches(
+                [d.timestamp for d in dps], [d.value for d in dps],
+                self.t0 + self.dt * np.arange(n), vals[i, :n])
+        self.checks.add(f"{name}_points_differ", bad, 0)
+        self.phases[f"{name}_s"] = time.perf_counter() - t
+        say(f"read-back ({name}): every acknowledged point of {len(sample)} series in "
+            f"{self.phases[f'{name}_s']:.1f}s")
 
     def say_setup(self) -> None:
         say("set-up phases (s): " + " ".join(
@@ -540,14 +695,12 @@ class Cell:
             say(f"FAIL the cell asks for {self.workload['chips']} chips, jax found {count}")
             return None
         self.build_fleet()
-        if self.traffic["kind"] == "write":
-            self.run_write()
-        else:
-            self.run_query()
+        {"write": self.run_write, "query": self.run_query,
+         "live": self.run_live}[self.traffic["kind"]]()
         # the program's state is freed before anything else is read
         self.close()
         if self.trace:
-            self.trace_summary = reduce_trace(self.trace_dir)
+            self.trace_summary = reduce_trace(self.trace_dir, self.window["traced_s"])
         return self.result(platform, count, kind)
 
     def close(self) -> None:
@@ -575,7 +728,11 @@ class Cell:
                 out["breakdown"] = {"device_ops": ts["device_ops"],
                                     "idle_gaps": ts["idle_gaps"]}
                 say(f"trace: busy {ts['busy_s']:.3f}s of {self.window['traced_s']:.3f}s, "
-                    f"{ts['n_ops']} device operations, xplane {ts['xplane_bytes']} bytes")
+                    f"{ts['n_ops']} device operations, xplane {ts['xplane_bytes']} bytes; "
+                    f"collected for {ts['collected_s']:.3f}s from the first operation, the "
+                    f"{ts['n_ops_after']} operations after the slice's end left out")
+                say("trace: busy share by second: " + " ".join(
+                    f"{b:.2f}" for b in ts["by_second"]))
             for m in bench["per_layer"]:
                 if name not in m.get("workloads", [name]):
                     continue
@@ -630,13 +787,15 @@ def load_reader(name: str):
     return mod
 
 
-def reduce_trace(trace_dir: str) -> dict:
+def reduce_trace(trace_dir: str, slice_s: float) -> dict:
     """The reduction runs in a process of its own, on the CPU: this one
-    never imports jax."""
+    never imports jax. The trace is cut ``slice_s`` after its first device
+    operation (``trace_reduce.py``)."""
     out = trace_dir + ".json"
     try:
         subprocess.run(
-            [sys.executable, os.path.join(HERE, "trace_reduce.py"), trace_dir, out],
+            [sys.executable, os.path.join(HERE, "trace_reduce.py"), trace_dir, out,
+             repr(slice_s)],
             env=dict(os.environ, JAX_PLATFORMS="cpu"), check=True, timeout=240,
             cwd=ROOT)
         with open(out) as f:
@@ -647,15 +806,18 @@ def reduce_trace(trace_dir: str) -> dict:
             os.remove(out)
 
 
-def run_cell(workload: str, seed: int, seconds: float, trace: bool,
+def run_cell(workload: str | dict, seed: int, seconds: float, trace: bool,
              rehearse: bool = False, hosts: int | None = None,
              fault: str | None = None) -> dict | None:
+    """``workload`` names a cell of ``BENCHMARK.json``; a test may hand in
+    the cell itself (``name``, ``config``, ``traffic``, ``chips``)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
-    if workload not in cells:
+    if isinstance(workload, str) and workload not in cells:
         raise SystemExit(f"unknown workload {workload!r}; BENCHMARK.json has {sorted(cells)}")
-    cell = Cell(bench, cells[workload], seed, seconds, trace, rehearse, hosts, fault)
+    cell = Cell(bench, cells[workload] if isinstance(workload, str) else workload,
+                seed, seconds, trace, rehearse, hosts, fault)
     try:
         result = cell.run()
     except BaseException:

@@ -18,7 +18,12 @@
   requests left when a window of ``run_seconds`` closes, and the first
   ``requests_per_worker`` requests of every worker are the ones the
   generator dealt before the list was extended (``DEALT_BEFORE``: digests
-  taken from PR 30's ``traffic.py``).
+  taken from PR 30's ``traffic.py``);
+- every ``live`` mix under ``traffic/``, named by a cell or not, has the
+  keys of its kind, and against every configuration that holds its
+  metrics: the open block does not roll over inside the window, no range
+  reaches behind the first loaded tick, the classes come in equal shares
+  and two seeds deal them in different orders.
 """
 
 from __future__ import annotations
@@ -126,14 +131,14 @@ def problems() -> list[str]:
                                  minlength=cfg["dbnode"]["num_shards"]).tolist()
             terms = {(k, v) for h, m, _ in table for k, v in fleet.series_tags(hosts[h], m)}
             n = fleet.points_per_block(cfg)
-            vals = fleet.values(cfg, seed, n)
-            if tr["kind"] == "query":
+            vals = fleet.values(cfg, seed, traffic_mod.total_ticks(cfg, tr, n, bench["run_seconds"]))
+            if tr["kind"] in ("query", "live"):
                 plan = traffic_mod.query_plan(cfg, tr, fleet.t0_nanos(cfg), n, seed)
-                draws = [(r["query"], r["start"]) for reqs in plan["window"] for r in reqs[:50]]
                 # the list follows the window, and starts as it always did
-                n_base = tr["requests_per_worker"]
+                n_base = tr.get("requests_per_worker", 0)
                 sized = traffic_mod.query_plan(cfg, tr, fleet.t0_nanos(cfg), n, seed,
                                                seconds=bench["run_seconds"])
+                draws = [(r["query"], r["start"]) for reqs in sized["window"] for r in reqs[:50]]
                 short = [len(reqs) for reqs in sized["window"]
                          if len(reqs) * FAST_CLIENT_SECS <= bench["run_seconds"]]
                 if short:
@@ -146,7 +151,7 @@ def problems() -> list[str]:
                                "are not the ones the generator dealt before")
                 tail = [(r["query"], r["start"]) for reqs in sized["window"]
                         for r in reqs[n_base:n_base + 50]]
-                if len(set(tail)) < len(tail) // 2:
+                if tr["kind"] == "query" and len(set(tail)) < len(tail) // 2:
                     bad.append(f"{w['name']} seed {seed}: the requests after the first "
                                f"{n_base} repeat each other")
                 rb = [r["query"] for r in traffic_mod.readback_requests(
@@ -169,6 +174,8 @@ def problems() -> list[str]:
         print(f"{w['name']}: {len(a[0])} series, shard counts {a[1]}, "
               f"{a[2]} index terms, the same at seeds {SEEDS}; values and draws differ")
 
+    bad += live_problems(bench)
+
     # a one-host class (no cell has one yet: PERF.md section 7, the needle)
     one_host = {"workers": 4, "warmup_per_worker": 5, "requests_per_worker": 100,
                 "align_secs": 10, "classes": [{
@@ -182,6 +189,59 @@ def problems() -> list[str]:
         if sorted(dealt) != list(range(400)):
             bad.append(f"seed {seed}: a one-host class asks for a host again before "
                        "it has gone once round the fleet")
+    return bad
+
+
+def live_problems(bench: dict) -> list[str]:
+    """Every ``live`` mix under ``traffic/`` against every configuration
+    whose fleet holds the metrics its classes ask for."""
+    bad: list[str] = []
+    configs = [fleet.load_config(c["name"]) for c in bench["configs"]]
+    for name in sorted(os.listdir(os.path.join(HERE, "traffic"))):
+        tr = fleet.load_json("traffic", name)
+        if tr.get("kind") != "live":
+            continue
+        missing = [k for k in ("open_ticks", "workers", "timeout_s", "warmup_per_worker",
+                               "readback_per_class", "readback_series", "classes")
+                   if k not in tr] + ["writer.workers"] * (
+                       not (tr.get("writer") or {}).get("workers", 0) > 0)
+        if missing or not tr["open_ticks"] >= 1:
+            bad.append(f"traffic/{name}: a live mix needs {missing or 'open_ticks >= 1'}")
+            continue
+        metrics = {c["metric"] for c in tr["classes"]}
+        fits = [cfg for cfg in configs
+                if metrics <= {m for _, m, _ in fleet.series_table(dict(cfg, hosts=1))}]
+        if not fits:
+            bad.append(f"traffic/{name}: no configuration holds {sorted(metrics)}")
+        for cfg in fits:
+            n = fleet.points_per_block(cfg)
+            n_ticks = traffic_mod.total_ticks(cfg, tr, n, bench["run_seconds"])
+            first = n + tr["open_ticks"]
+            if n_ticks > 2 * n:
+                bad.append(f"traffic/{name} on {cfg['name']}: the open block rolls over "
+                           f"inside the window ({n_ticks} ticks, a block holds {n})")
+            orders = []
+            for seed in SEEDS:
+                plan = traffic_mod.query_plan(cfg, tr, fleet.t0_nanos(cfg), n, seed,
+                                              seconds=bench["run_seconds"])
+                reqs = plan["window"][0]
+                orders.append(reqs.which[:50].tolist())
+                share = np.bincount(reqs.which, minlength=len(tr["classes"])) / len(reqs)
+                if share.max() - share.min() > 0.001:
+                    bad.append(f"traffic/{name} seed {seed}: classes in shares {share.tolist()}")
+                for req in reqs[:len(tr["classes"]) * 4]:
+                    # the earliest end a request can ask for: the window's first
+                    low = traffic_mod.at_end_tick(
+                        req, 0, 1, first - traffic_mod.LIVE_LAG_TICKS)["first_idx"] \
+                        - req["window_steps"] * req["stride"]
+                    if low < 0:
+                        bad.append(f"traffic/{name} on {cfg['name']}: {req['query']} reaches "
+                                   f"{-low} ticks behind the first loaded")
+            if orders[0] == orders[1]:
+                bad.append(f"traffic/{name}: two seeds dealt the classes in the same order")
+            print(f"traffic/{name} on {cfg['name']}: {n_ticks} ticks ({n} sealed, "
+                  f"{tr['open_ticks']} open, {n_ticks - first} in the window), "
+                  f"{len(tr['classes'])} classes in equal shares")
     return bad
 
 

@@ -20,6 +20,17 @@ Worker w owns the series of the dbnode shards s with s % workers == w (a
 remote-write client owns a slice of the series by hash, and one ingest
 buffer is fed by one client, in order).
 
+``kind: "live"`` — the live edge: one sealed block and ``open_ticks`` ticks
+of the open one loaded by set-up, then closed-loop queriers beside a
+writer in real time. ``writer: {workers}``: the write clients send tick k
+at ``t_go + (k - first) * interval_secs`` on the wall clock (the
+configuration's scrape interval), open loop (a late tick goes at once,
+none is skipped). The classes are a query file's, but no start is drawn:
+every request ends at now. The client makes its range at the send, ending
+``LIVE_LAG_TICKS`` behind the tick the writer's schedule has reached
+(``end_tick``), and records that end tick, from which ``at_end_tick``
+makes the request the reference answers.
+
 The write warm-up is worked out here from what the configuration states
 (``ingest_sync_batch``) and the fleet's per-shard series counts: a shard's
 ingest buffer syncs to the device whenever ``sync_batch`` rows are staged,
@@ -99,6 +110,11 @@ class Requests:
         """(query, start, end, step) of every request, in order."""
         return [(r["query"], r["start"], r["end"], r["step"]) for r in self]
 
+    def wire_now(self) -> list[tuple]:
+        """(query, span, step) of every request of a live mix, in order:
+        the client makes the range at the send."""
+        return [(r["query"], r["end"] - r["start"], r["step"]) for r in self]
+
 
 # No served request answers faster: one round trip through the dbnode's
 # Python RPC server and one device dispatch. The window's list is sized by it.
@@ -129,7 +145,10 @@ def query_plan(cfg: dict, traffic: dict, t0: int, n_points: int, seed: int,
         if cls["hosts"] not in (1, "all"):
             raise ValueError(f"query class hosts {cls['hosts']!r}: 1 or \"all\"")
     deal = rng.permutation(cfg["hosts"])
-    slots = [_start_slots(cfg, n_points, cls, traffic["align_secs"]) for cls in classes]
+    align_secs = traffic.get("align_secs", cfg["interval_secs"])
+    # a live mix draws no start: its requests end at now
+    slots = [np.zeros(1, np.int64) if traffic.get("kind") == "live"
+             else _start_slots(cfg, n_points, cls, align_secs) for cls in classes]
 
     def dealt_hosts(n: int, worker: int, dealt: int) -> np.ndarray:
         """The k-th request of a worker takes deal position
@@ -158,7 +177,7 @@ def query_plan(cfg: dict, traffic: dict, t0: int, n_points: int, seed: int,
         return which, first
 
     n_warm = traffic["warmup_per_worker"]
-    n_base = traffic["requests_per_worker"]
+    n_base = traffic.get("requests_per_worker", 0)
     n_window = n_base if seconds is None else max(
         n_base, int(np.ceil(seconds / LATENCY_FLOOR_SECS)))
     plan: dict = {"warmup": [], "window": []}
@@ -195,6 +214,49 @@ def readback_requests(cfg: dict, table: list, t0: int, n_points: int,
             req["class"] = cls
             reqs.append(req)
     return reqs
+
+
+# ---------------------------------------------------------------------------
+# the live edge
+# ---------------------------------------------------------------------------
+
+
+# A request that ends at now ends one tick behind the one the writer's
+# schedule has reached: the newest sample every writer can have had
+# acknowledged (the tick that is due is on its way).
+LIVE_LAG_TICKS = 1
+
+
+def total_ticks(cfg: dict, traffic: dict, n_points: int, seconds: float) -> int:
+    """Ticks of data time one run makes: a block for a query mix,
+    ``blocks`` of them for a write mix; for a live mix the sealed block,
+    the ``open_ticks`` set-up writes and the ticks the writer's schedule
+    (one every ``interval_secs``) reaches before the window closes."""
+    if traffic["kind"] == "live":
+        return n_points + traffic["open_ticks"] + int(
+            np.ceil(seconds / cfg["interval_secs"]))
+    return n_points * traffic.get("blocks", 1)
+
+
+def end_tick(t_send: float, t_go: float, pace_secs: float, first_tick: int,
+             last_tick: int) -> int:
+    """The sample a request of a live mix ends on when it is sent at
+    ``t_send``: ``LIVE_LAG_TICKS`` behind the tick the paced writer's
+    schedule has reached (tick k is due at ``t_go + (k - first_tick) *
+    pace_secs``, ``last_tick`` the last it sends). Before the window
+    (warm-up) that is the last tick set-up wrote."""
+    if t_send < t_go:
+        return first_tick - 1
+    return min(first_tick + int((t_send - t_go) // pace_secs), last_tick) - LIVE_LAG_TICKS
+
+
+def at_end_tick(req: dict, t0: int, interval_nanos: int, end_tick: int) -> dict:
+    """The request of a live mix as the client sent it, with its last
+    step on sample ``end_tick``."""
+    first_idx = end_tick - (req["n_steps"] - 1) * req["stride"]
+    start = t0 + first_idx * interval_nanos
+    return dict(req, first_idx=first_idx, start=start,
+                end=start + (req["n_steps"] - 1) * req["step"])
 
 
 # ---------------------------------------------------------------------------
