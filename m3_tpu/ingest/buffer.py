@@ -39,6 +39,9 @@ from ..utils.instrument import DEFAULT as METRICS
 from ..utils.trace import TRACER
 
 SPILL_REASONS = ("window", "lanes", "slots")
+# slots a tile of a sync: a tail of one tick's rows and one of a few
+# dozen ticks run the same scatter program, one tile or a few
+_SYNC_TILE_SLOTS = 32
 
 
 @dataclass(frozen=True)
@@ -49,8 +52,10 @@ class IngestOptions:
     lanes: int = 1024  # series lanes per block-window frame
     slots: int = 1024  # samples per lane per window
     windows: int = 2  # block windows open at once (ring depth)
-    # staged appends that trigger a device-plane sync; the seal path
-    # syncs explicitly, so this only bounds aggregation-feed staleness
+    # staged appends that trigger a device-plane sync from the write path;
+    # a query plan that reads the planes syncs the staged tail itself
+    # before it dispatches (query/plan.py), so this bounds only how much
+    # one write batch may have to move
     sync_batch: int = 8192
 
     def __post_init__(self):
@@ -70,7 +75,7 @@ class _Frame:
 
     __slots__ = (
         "block_start", "lane_of", "sids", "times", "values", "units",
-        "counts", "clean", "last_time", "synced",
+        "counts", "clean", "last_time", "synced", "spilled", "unlaned",
     )
 
     def __init__(self, block_start: int, lanes: int, slots: int) -> None:
@@ -85,6 +90,11 @@ class _Frame:
         self.last_time = np.full(lanes, np.iinfo(np.int64).min, np.int64)
         # per-lane slot count already mirrored to the device planes
         self.synced = np.zeros(lanes, np.int32)
+        # lanes that refused a row for want of slots, and series refused
+        # a lane for want of lanes: the planes lack rows the SeriesBuffer
+        # holds, so a plan must not read them (read_lanes)
+        self.spilled = np.zeros(lanes, bool)
+        self.unlaned: set[bytes] = set()
 
 
 class ColumnWriteBuffer:
@@ -101,10 +111,16 @@ class ColumnWriteBuffer:
         # block_start -> dict of uint32[lanes, slots] planes + counts
         self._planes: dict[int, dict] = {}
         self._staged_since_sync = 0
-        # donation/epoch discipline (resident/pool.py): aggregation
-        # readers lease the planes across their reductions; a sync
-        # donates the plane buffers to its scatter only when no lease
-        # is active, and new leases fence on the in-flight donation
+        # block starts that refused rows because no ring window was free:
+        # their frame, if one opens later, lacks those rows
+        self._window_spilled: set[int] = set()
+        # one sync at a time: a query's sync and the write path's may race,
+        # and each reads the planes it scatters into
+        self._sync_lock = threading.Lock()
+        # donation/epoch discipline (resident/pool.py): a query plan
+        # leases the planes across its dispatch; a sync donates the plane
+        # buffers to its scatter only when no lease is active, and new
+        # leases fence on the in-flight donation
         self._leases = 0
         self._donating = False
         self._fence = threading.Condition(self._lock)
@@ -212,6 +228,7 @@ class ColumnWriteBuffer:
         if frame is None:
             if len(self._frames) >= self.options.windows:
                 self._spill_locked("window", n_rows)
+                self._window_spilled.add(bs)
                 return None
             frame = _Frame(bs, self.options.lanes, self.options.slots)
             self._frames[bs] = frame
@@ -236,6 +253,7 @@ class ColumnWriteBuffer:
                     if lane is None:
                         if len(frame.sids) >= o.lanes:
                             raw[j] = -1
+                            frame.unlaned.add(sid)
                             continue
                         lane = len(frame.sids)
                         lane_of[sid] = lane
@@ -265,6 +283,7 @@ class ColumnWriteBuffer:
         fit = slot < o.slots
         if not fit.all():
             self._spill_locked("slots", int((~fit).sum()))
+            frame.spilled[ls[~fit]] = True
             # overflow is always a per-lane TAIL (slots ascend within a
             # lane), so groups stay contiguous after the filter
             order, ls, t, v, u, slot = (
@@ -297,126 +316,179 @@ class ColumnWriteBuffer:
         self.spills[reason] += count
         self._m_spilled[reason].inc(count)
 
-    # ---------- device planes (aggregation feed) ----------
+    # ---------- device planes ----------
 
-    def sync(self) -> int:
-        """Mirror the staged column tail to the device planes — one
-        scatter per open window, donated when no lease is active.
-        Returns rows moved."""
+    def sync(self, donate: bool = True) -> int:
+        """Mirror the staged column tail to the device planes, donating the
+        plane buffers to the scatter when ``donate`` and no lease is
+        active. The write path calls it every ``sync_batch`` appends and a
+        query plan before it reads the planes (``donate=False``: another
+        query's lease decides whether a donation may happen, and a donated
+        scatter is another program, which a query must not compile
+        inside a window). Syncs run one at a time, so a caller returns only
+        once every row acknowledged before its call is on the device,
+        whichever sync moved it. The tiles and the counts they mark synced
+        are taken under one lock: a row appended meanwhile waits for the
+        next sync. Returns rows moved."""
         import jax
         import jax.numpy as jnp
 
-        moved = 0
-        with self._lock:
-            work = []
-            for bs, frame in self._frames.items():
-                dirty = np.nonzero(frame.synced < frame.counts)[0]
-                if len(dirty):
-                    work.append((bs, frame, dirty))
-            if not work:
-                self._staged_since_sync = 0
-                return 0
-            donate = self._leases == 0
-            if donate:
-                self._donating = True
-        try:
-            for bs, frame, dirty in work:
-                planes = self._planes.get(bs)
-                if planes is None:
-                    o = self.options
-                    planes = {
-                        # ts_hi / ts_lo / val_hi / val_lo as one stacked
-                        # tensor: the sync moves ONE host->device staging
-                        # buffer and runs ONE scatter for all four
-                        "cols": jnp.zeros(
-                            (4, o.lanes, o.slots), jnp.uint32
-                        ),
-                        "counts": jnp.zeros(o.lanes, jnp.int32),
-                    }
-                # stage only the dirty slot TAIL — one rectangular tile
-                # covering [lo, lo+w) across the dirty lanes, w and the
-                # lane count padded to powers of two so the scatter jit
-                # compiles O(log^2) variants, not one per shape. Padding
-                # restages rows/slots already on device with identical
-                # values, which keeps the duplicate-index scatter exact.
-                o = self.options
-                lo = int(frame.synced[dirty].min())
-                hi = int(frame.counts[dirty].max())
-                w = 1 << max(hi - lo - 1, 0).bit_length()
-                w = min(w, o.slots)
-                lo = min(lo, o.slots - w)
-                nd = 1 << max(len(dirty) - 1, 0).bit_length()
-                pad = np.concatenate(
-                    [dirty, np.repeat(dirty[-1], nd - len(dirty))]
-                )
-                ts = frame.times[pad, lo:lo + w].view(np.uint64)
-                vb = frame.values[pad, lo:lo + w].view(np.uint64)
-                m32 = np.uint64(0xFFFFFFFF)
-                host = np.stack(
-                    [
-                        (ts >> np.uint64(32)).astype(np.uint32),
-                        (ts & m32).astype(np.uint32),
-                        (vb >> np.uint64(32)).astype(np.uint32),
-                        (vb & m32).astype(np.uint32),
-                    ]
-                )
-                counts_host = frame.counts[pad].copy()
-                # m3lint: disable=M3L010 -- sanctioned host->device staging: dirty host tiles must cross PCIe once per sync; a donation-to-infeed path (ROADMAP) would cut this copy
-                idx = jax.device_put(pad.astype(np.int32))
-                # m3lint: disable=M3L010 -- sanctioned host->device staging (same boundary as idx above)
-                lo_dev = jax.device_put(np.int32(lo))
-                # m3lint: disable=M3L010 -- sanctioned host->device staging (same boundary as idx above)
-                staged = jax.device_put(host)
-                # m3lint: disable=M3L010 -- sanctioned host->device staging (same boundary as idx above)
-                staged_c = jax.device_put(counts_host)
-                nbytes = host.nbytes + counts_host.nbytes
-                scatter = _scatter_tile4_donate if donate else _scatter_tile4
-                new_cols, new_counts = scatter(
-                    planes["cols"], planes["counts"], idx, lo_dev,
-                    staged, staged_c,
-                )
-                new = {"cols": new_cols, "counts": new_counts}
-                moved += int(
-                    (frame.counts[dirty] - frame.synced[dirty]).sum()
-                )
-                with self._lock:
-                    self._planes[bs] = new
-                    frame.synced[dirty] = frame.counts[dirty]
-                    self.epoch += 1
-                    self.device_syncs += 1
-                    self.device_sync_bytes += nbytes
-                self._m_syncs.inc()
-                self._m_sync_bytes.inc(nbytes)
-        finally:
+        o = self.options
+        m32 = np.uint64(0xFFFFFFFF)
+        w = min(_SYNC_TILE_SLOTS, o.slots)
+        with self._sync_lock:
+            moved = 0
             with self._lock:
+                work = []
+                for bs, frame in self._frames.items():
+                    dirty = np.nonzero(frame.synced < frame.counts)[0]
+                    if not len(dirty):
+                        continue
+                    # stage only the dirty slot TAIL — rectangular tiles of
+                    # ``w`` slots covering [lo, hi) across the dirty lanes,
+                    # the lane count padded to a power of two, so a tick's
+                    # rows and set-up's leftovers run one scatter program
+                    # (O(log) variants by lane count, not one per shape).
+                    # Padding restages rows/slots already on device with
+                    # identical values, which keeps the duplicate-index
+                    # scatter exact.
+                    lo = int(frame.synced[dirty].min())
+                    hi = int(frame.counts[dirty].max())
+                    nd = 1 << max(len(dirty) - 1, 0).bit_length()
+                    pad = np.concatenate(
+                        [dirty, np.repeat(dirty[-1], nd - len(dirty))]
+                    )
+                    tiles = []
+                    for start in range(lo, hi, w):
+                        start = min(start, o.slots - w)
+                        ts = frame.times[pad, start:start + w].view(np.uint64)
+                        vb = frame.values[pad, start:start + w].view(np.uint64)
+                        tiles.append((start, np.stack(
+                            [
+                                (ts >> np.uint64(32)).astype(np.uint32),
+                                (ts & m32).astype(np.uint32),
+                                (vb >> np.uint64(32)).astype(np.uint32),
+                                (vb & m32).astype(np.uint32),
+                            ]
+                        )))
+                    now = frame.counts[dirty].copy()
+                    moved += int((now - frame.synced[dirty]).sum())
+                    work.append((bs, frame, dirty, now, pad, tiles,
+                                 frame.counts[pad].copy()))
                 self._staged_since_sync = 0
+                if not work:
+                    return 0
+                donate = donate and self._leases == 0
                 if donate:
-                    self._donating = False
-                    self._fence.notify_all()
+                    self._donating = True
+            try:
+                for bs, frame, dirty, now, pad, tiles, counts_host in work:
+                    planes = self._planes.get(bs)
+                    if planes is None:
+                        planes = {
+                            # ts_hi / ts_lo / val_hi / val_lo as one stacked
+                            # tensor: a tile moves ONE host->device staging
+                            # buffer and runs ONE scatter for all four
+                            "cols": jnp.zeros(
+                                (4, o.lanes, o.slots), jnp.uint32
+                            ),
+                            "counts": jnp.zeros(o.lanes, jnp.int32),
+                        }
+                    cols, counts = planes["cols"], planes["counts"]
+                    # m3lint: disable=M3L001,M3L010 -- sanctioned host->device staging: dirty host tiles must cross PCIe once per sync; under _sync_lock, which only syncs take (a query's and the write path's scatter into the planes the other reads), never the shard's or the buffer's lock
+                    idx = jax.device_put(pad.astype(np.int32))
+                    # m3lint: disable=M3L001,M3L010 -- sanctioned host->device staging (same boundary as idx above)
+                    staged_c = jax.device_put(counts_host)
+                    scatter = _scatter_tile4_donate if donate else _scatter_tile4
+                    nbytes = counts_host.nbytes
+                    for start, host in tiles:
+                        # m3lint: disable=M3L001,M3L010 -- sanctioned host->device staging (same boundary as idx above)
+                        lo_dev = jax.device_put(np.int32(start))
+                        # m3lint: disable=M3L001,M3L010 -- sanctioned host->device staging (same boundary as idx above)
+                        staged = jax.device_put(host)
+                        nbytes += host.nbytes
+                        cols, counts = scatter(cols, counts, idx, lo_dev,
+                                               staged, staged_c)
+                    with self._lock:
+                        # a window sealed or dropped meanwhile keeps no planes
+                        if self._frames.get(bs) is frame:
+                            self._planes[bs] = {"cols": cols, "counts": counts}
+                            frame.synced[dirty] = now
+                        self.epoch += 1
+                        self.device_syncs += 1
+                        self.device_sync_bytes += nbytes
+                    self._m_syncs.inc()
+                    self._m_sync_bytes.inc(nbytes)
+            finally:
+                if donate:
+                    with self._lock:
+                        self._donating = False
+                        self._fence.notify_all()
         return moved
 
     def lease(self):
         """Context manager: hold the device planes stable across a
-        reader's reductions (syncs downgrade to functional copies)."""
+        reader's dispatch (syncs downgrade to functional copies)."""
         return _Lease(self)
 
-    def window_planes(self, block_start: int):
-        """Device planes + lane sid list for one open window (the
-        aggregation tier's feed), or None before the first sync."""
+    # ---------- the query plan's read face (query/plan.py) ----------
+
+    def _stamp_locked(self, block_start: int):
+        frame = self._frames.get(block_start)
+        spilled = block_start in self._window_spilled
+        if frame is None:
+            return ("spilled",) if spilled else None
+        return id(frame), len(frame.sids), spilled
+
+    def frame_stamp(self, block_start: int):
+        """What moves when the window's lane table does: its frame, the
+        lanes handed out, and whether rows for it were refused for want
+        of a ring window; None where the window has no frame and refused
+        nothing."""
         with self._lock:
-            planes = self._planes.get(block_start)
+            return self._stamp_locked(block_start)
+
+    def lanes_for(self, block_start: int, sids: list):
+        """``(stamp, lanes)`` of one open window: the lane of every sid
+        (-1: none yet) and the window's :meth:`frame_stamp` they were read
+        under; None where the window has no frame."""
+        with self._lock:
             frame = self._frames.get(block_start)
-            if planes is None or frame is None:
+            if frame is None:
                 return None
-            cols = planes["cols"]
-            view = {
-                "ts_hi": cols[0],
-                "ts_lo": cols[1],
-                "val_hi": cols[2],
-                "val_lo": cols[3],
-                "counts": planes["counts"],
-            }
-            return view, list(frame.sids)
+            get = frame.lane_of.get
+            lanes = np.fromiter((get(s, -1) for s in sids), np.int32, len(sids))
+            return self._stamp_locked(block_start), lanes
+
+    def read_lanes(self, block_start: int, stamp, lanes: np.ndarray,
+                   laneless: list):
+        """What a plan reads of one open window, to be called under
+        :meth:`lease` after :meth:`sync`: ``(None, cols, counts, width)``
+        (the planes as the last sync left them, and the most synced rows
+        of any of ``lanes``), or ``(reason, ...)`` where the planes cannot
+        stand for what the shard holds: ``raced`` (the lane table moved
+        since ``stamp``, its :meth:`frame_stamp`), ``dirty-lane`` (rows
+        out of order: only the SeriesBuffer merge has them right),
+        ``spilled-row`` (the window, a lane, or a series of ``laneless``
+        refused rows that the SeriesBuffer holds).
+        The checks and the planes are taken under one lock, so a lane that
+        turns dirty after the planes were read is not in them."""
+        with self._lock:
+            frame = self._frames.get(block_start)
+            if frame is None or self._stamp_locked(block_start) != stamp:
+                return "raced", None, None, 0
+            if stamp[2] or frame.spilled[lanes].any() or (
+                    frame.unlaned and not frame.unlaned.isdisjoint(laneless)):
+                return "spilled-row", None, None, 0
+            if not frame.clean[lanes].all():
+                return "dirty-lane", None, None, 0
+            if not len(lanes):
+                return None, None, None, 0
+            planes = self._planes.get(block_start)
+            if planes is None:
+                return "raced", None, None, 0
+            return (None, planes["cols"], planes["counts"],
+                    int(frame.synced[lanes].max()))
 
     # ---------- seal ----------
 
@@ -429,6 +501,7 @@ class ColumnWriteBuffer:
         with self._lock:
             frame = self._frames.pop(block_start, None)
             self._planes.pop(block_start, None)
+            self._window_spilled.discard(block_start)
             if frame is None:
                 return [], []
             clean: list[SealLane] = []
@@ -458,6 +531,7 @@ class ColumnWriteBuffer:
         with self._lock:
             self._frames.pop(block_start, None)
             self._planes.pop(block_start, None)
+            self._window_spilled.discard(block_start)
 
     def open_windows(self) -> list[int]:
         with self._lock:

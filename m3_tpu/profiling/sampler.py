@@ -198,8 +198,9 @@ class StackSampler:
         t0 = time.perf_counter()
         if now is None:
             now = self.clock()
+        taken = frames is None
         try:
-            if frames is None:
+            if taken:
                 frames = sys._current_frames()
             own = self._thread.ident if self._thread is not None else None
             folded: list[tuple[str, int]] = []
@@ -210,6 +211,15 @@ class StackSampler:
         except Exception:
             self._m_errors.inc()
             return 0
+        finally:
+            # the snapshot holds this very frame, whose locals hold the
+            # snapshot: left so, the cycle keeps every sampled thread's
+            # frames alive, and with them each finished call's locals (a
+            # query's per-series lists), until a pass of the collector
+            # frees them, and those passes stop every thread
+            frame = None
+            if taken:
+                frames.clear()
         bucket_idx = int(now // self.bucket_seconds)
         recorded = 0
         with self._lock:

@@ -87,7 +87,11 @@ class M3Storage:
         (the same finalize arithmetic as the staged path — bit-identical
         results) and attaches tags. Returns a consolidated
         ``(metas, values f64[S, T])`` or None to run the staged path —
-        every ineligibility cause lands in EXPLAIN routing.
+        every ineligibility cause lands in EXPLAIN routing. A range that
+        reaches into the open block is the same one program, reading the
+        shards' ingest planes beside the sealed pages (the plan's
+        overlay); what the overlay cannot serve comes back None, and the
+        staged path's per-series ``buffered-overlay`` route streams it.
 
         ``grid`` is the engine's consolidation timestamp vector (i64
         nanos); ``[start_nanos, end_nanos)`` the raw fetch window
@@ -175,8 +179,9 @@ class M3Storage:
         reader/lock round trips. Decode then runs the same array path
         Shard.read_arrays uses (native read, iterator fallback); callers
         use this only where no buffer overlays the range (the residency
-        and plan gates exclude overlays), so fileset streams are the
-        whole truth."""
+        gate excludes overlays, and a plan that read the open block falls
+        back staged on a lane the decoder bailed on), so fileset streams
+        are the whole truth."""
         from ..codec.iterator import MultiReaderIterator
         from ..codec.native_read import read_segments_arrays
         from ..storage.fs import FilesetID

@@ -26,6 +26,26 @@ them inside ONE jit program per query *shape*:
         to per-element loops, see functions/temporal.py "window index
         machinery")
 
+A range that reaches into the OPEN block (the live edge a dashboard
+asks for) is served by the same program with an overlay stage: the
+shards' ingest planes (ingest/buffer.py) hold every acknowledged row of
+the open window as (ts, value) u32 pairs, so the program gathers the
+matched series' lanes from them, masks them to the fetch range and
+appends them after each series' decoded sealed points before stage 5.
+Blocks partition time, so that is a concatenation, not a merge. Matchers
+resolve over the sealed segment on the device as above and over the
+open index block's mutable docs on the host; a series only the open
+block knows takes a slot of its own, served from the planes alone, and a
+range wholly inside the open block is served from the planes with no
+sealed stage at all. Before the dispatch the plan syncs each matched
+shard's staged tail (every acknowledged row is on the device when the
+program reads it) and holds the planes under the buffer's lease. The
+plan key names the open window's block start, never its extent: the
+overlay's slot extent is a power-of-two bucket of the lanes' synced
+counts, so a new tick changes no program. What the planes cannot stand
+for falls back staged, counted by reason
+(``m3tpu_query_plan_overlay_fallbacks_total``, ``OVERLAY_FALLBACKS``).
+
 The program returns the CONSOLIDATED grid as raw (hi, lo) value pairs
 plus validity masks; the host then runs the exact same float64
 reconstruction the staged path uses (ops/decode.finalize_decode math)
@@ -42,7 +62,7 @@ fileset epochs, and index-segment identity — a segment swap, volume
 bump, or resident eviction invalidates the plan (regression-tested).
 Ineligible queries fall back to the staged executor transparently with
 an EXPLAIN routing reason per cause (host-regexp leaf, non-resident
-block, buffer overlay, multi-segment index, ...).
+block, a buffer over a sealed block, multi-segment index, ...).
 
 Knobs:
 
@@ -95,6 +115,43 @@ _M_COALESCED = METRICS.counter(
 )
 
 
+_M_OVERLAY_LANES = METRICS.counter(
+    "query_plan_overlay_lanes_total",
+    "open-block lanes plan dispatches read from the shards' ingest planes",
+)
+_M_OVERLAY_SYNC_ROWS = METRICS.counter(
+    "query_plan_overlay_sync_rows_total",
+    "acknowledged rows a plan synced to the ingest planes before its "
+    "dispatch (the staged tail the write path had not synced yet)",
+)
+
+# why a range that reaches into the open block went staged: a buffer over
+# a sealed block (a merge, not a concatenation), more than one buffered
+# block in range, a shard without ingest planes, an out-of-order lane, a
+# row the planes refused (no lane, no slot, no window), a lane the device
+# decoder bailed on (its host re-read knows no planes), or a lane table
+# that moved between the overlay's lookup and its read
+OVERLAY_FALLBACKS = ("buffer-overlay", "open-windows", "ingest-off",
+                     "dirty-lane", "spilled-row", "err-lane", "raced")
+_M_OVERLAY_FALLBACKS = {
+    r: METRICS.counter(
+        "query_plan_overlay_fallbacks_total",
+        "ranges reaching into buffered data that the plan's overlay could "
+        "not serve, by reason (the staged path served them)",
+        labels={"reason": r},
+    )
+    for r in OVERLAY_FALLBACKS
+}
+
+
+def _overlay_fallback(reason: str) -> "Ineligible":
+    """Count one overlay fallback; the Ineligible to raise for it (a
+    buffer over a sealed block keeps the plan's older reason)."""
+    _M_OVERLAY_FALLBACKS[reason].inc()
+    return Ineligible(
+        "buffer-overlay" if reason == "buffer-overlay" else f"overlay:{reason}")
+
+
 def _plan_builds(cap: int):
     return METRICS.counter(
         "query_plan_builds_total", "device query plans built, by decode "
@@ -130,6 +187,10 @@ PROF = KernelProfiler("query_plan")
 
 _SENTINEL_GRID = 8  # minimum padded grid length
 _MIN_CAP = 8  # minimum decode capacity (matched-series slots)
+# the overlay reads the first ``width`` slots of each matched lane: the
+# power-of-two bucket of the lanes' synced rows, at least this many, so a
+# tick (one more row a lane) compiles nothing until the bucket fills
+_MIN_OVERLAY_WIDTH = 8
 # grid steps per compare-and-reduce pass of stage 5, at least: a power of
 # two no larger than _SENTINEL_GRID, so it divides every padded grid
 _GRID_TILE = 8
@@ -362,13 +423,73 @@ def _consolidate_last(ts, planes, valid, g, flo, fhi, lb):
 
 
 
+def _overlay_points(overlay, width: int):
+    """The overlay stage, traced: the matched series' open-block rows from
+    the shards' ingest planes, one row per decode slot. ``overlay`` is
+    (cols, counts, lanes, slot): per source shard its ``[4, lanes, slots]``
+    u32 planes (ts hi, ts lo, value hi, value lo) and ``[lanes]`` synced
+    counts, the ``[n_src, cap_s]`` lanes each source's matched series sit
+    in, and per slot its row of the sources' gathered lanes (the last row,
+    all zero, where a slot has no lane). Returns (ts pair, value hi,
+    value lo, valid), each ``[cap, width]``: the first ``width`` slots of
+    each lane, valid below its synced count.
+
+    Every gather takes whole rows of a 2-D plane, the one gather form the
+    chip does at memory speed (PERF.md section 6): a gather of
+    ``[4, lanes, slots]`` along its middle axis made the compiler lay the
+    whole 16 MiB plane out anew, a shard a request (PERF.md section 5)."""
+    import jax
+    import jax.numpy as jnp
+
+    cols, counts, lanes, slot = overlay
+    i32 = jnp.int32
+    planes = []
+    for p in range(4):
+        rows = [jnp.take(c[p], lanes[i], axis=0)[:, :width]
+                for i, c in enumerate(cols)]
+        rows.append(jnp.zeros((1, width), jnp.uint32))
+        planes.append(jnp.take(jnp.concatenate(rows), slot, axis=0))
+    ns = [n[lanes[i]] for i, n in enumerate(counts)] + [jnp.zeros(1, i32)]
+    n = jnp.concatenate(ns)[slot]
+    valid = jax.lax.broadcasted_iota(i32, (slot.shape[0], width), 1) < n[:, None]
+    return (planes[0], planes[1]), planes[2], planes[3], valid
+
+
+def _unpack_request_traced(request, t_grid: int):
+    """What ``_pack_request`` packed: the step grid as (hi, lo) and the
+    fetch bounds and lookback as (hi, lo) pairs."""
+    g = (request[:t_grid], request[t_grid:2 * t_grid])
+    flo, fhi, lb = (
+        (request[2 * t_grid + 2 * i], request[2 * t_grid + 2 * i + 1])
+        for i in range(3)
+    )
+    return g, flo, fhi, lb
+
+
+def _pack_outputs(*outs):
+    """The program's outputs as ONE u32 array (``_unpack_reply``)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.concatenate([
+        x.reshape(-1) if x.dtype == jnp.uint32
+        else jax.lax.bitcast_convert_type(x.astype(jnp.int32), jnp.uint32).reshape(-1)
+        for x in outs
+    ])
+
+
 @functools.lru_cache(maxsize=64)
-def _build_program(ast, dims):
+def _build_program(ast, dims, odims=None):
     """ONE jitted program for a (query shape, plan shapes) class. ``ast``
     is the hashable shape tree (leaf slots + static slab bounds baked
     in); ``dims`` the static dimension tuple. Runtime VALUES (query
     keys, range bounds, pool buffers, plan tables, grid) are inputs, so
-    one compilation serves every query of the same shape."""
+    one compilation serves every query of the same shape.
+
+    ``odims`` (cap, sources, cap_s, width, t_grid) adds the overlay
+    stage: the program then takes ``overlay=`` (``_overlay_points``) and
+    appends its rows after the decoded points. Without ``odims`` the
+    program is the sealed-only one, op for op."""
     import jax
     import jax.numpy as jnp
 
@@ -396,15 +517,11 @@ def _build_program(ast, dims):
                 q_keys, q_lens, q_lo, q_hi, r_lo, r_hi,
                 pool_words, side_words,
                 t_pages, t_sides, t_chunks, t_bits, t_bhi, t_blo,
-                request):
+                request, overlay=None):
         i32 = jnp.int32
         # what a request brings, in one array (one transfer to the device,
         # where two arrays and six scalars were eight: _pack_request)
-        g_hi, g_lo = request[:t_grid], request[t_grid:2 * t_grid]
-        flo, fhi, lb = (
-            (request[2 * t_grid + 2 * i], request[2 * t_grid + 2 * i + 1])
-            for i in range(3)
-        )
+        (g_hi, g_lo), flo, fhi, lb = _unpack_request_traced(request, t_grid)
 
         # ---- stage 1: batched term match (every exact leaf, one search)
         if q_keys.shape[0]:
@@ -490,21 +607,56 @@ def _build_program(ast, dims):
         pif, mlt = rs(res.point_is_float), rs(res.mult)
         valid = rs(res.valid)
         err = jnp.any(res.err.reshape(cap, n_blocks * c), axis=1)
+        pif = pif.astype(i32)
+
+        if odims is not None:
+            # ---- overlay: the open block's rows after the sealed ones
+            # (blocks partition time, so every row stays time-ascending);
+            # values travel as float64 bit patterns: float mode, no mult
+            ots, ovh, ovl, ovalid = _overlay_points(overlay, odims[3])
+            cat = lambda a, b: jnp.concatenate([a, b], axis=1)
+            ts = (cat(ts[0], ots[0]), cat(ts[1], ots[1]))
+            vhi, vlo = cat(vhi, ovh), cat(vlo, ovl)
+            pif = cat(pif, jnp.ones(ovh.shape, i32))
+            mlt = cat(mlt, jnp.zeros(ovh.shape, mlt.dtype))
+            valid = cat(valid, ovalid)
 
         # ---- stage 5: consolidation onto the step grid
         counts, (g_vh, g_vl, g_pf, g_ml), ok = _consolidate_last(
-            ts, (vhi, vlo, pif.astype(i32), mlt), valid,
+            ts, (vhi, vlo, pif, mlt), valid,
             (g_hi, g_lo), flo, fhi, lb,
         )
         # ONE array out, as one came in (_unpack_reply): every output read
         # back on its own is a blocking call that hands the interpreter
         # lock away, and beside other handler threads each one ends in a
         # wait to get it back (PERF.md section 6, PR 34)
-        return jnp.concatenate([
-            x.reshape(-1) if x.dtype == jnp.uint32
-            else jax.lax.bitcast_convert_type(x.astype(i32), jnp.uint32).reshape(-1)
-            for x in (n_matched, bitmap, counts, err, g_vh, g_vl, g_pf, g_ml, ok)
-        ])
+        return _pack_outputs(n_matched, bitmap, counts, err,
+                             g_vh, g_vl, g_pf, g_ml, ok)
+
+    _M_COMPILES.inc()
+    return jax.jit(program)
+
+
+@functools.lru_cache(maxsize=64)
+def _build_overlay_program(odims):
+    """The plan program of a range wholly inside the open block: no
+    sealed stage, the overlay's rows alone onto the step grid (outputs
+    as the sealed program's, with an empty bitmap and no error lanes)."""
+    import jax
+    import jax.numpy as jnp
+
+    cap, _n_src, _cap_s, width, t_grid = odims
+
+    def program(request, overlay):
+        i32 = jnp.int32
+        g, flo, fhi, lb = _unpack_request_traced(request, t_grid)
+        ts, vhi, vlo, valid = _overlay_points(overlay, width)
+        counts, (g_vh, g_vl, g_pf, g_ml), ok = _consolidate_last(
+            ts, (vhi, vlo, jnp.ones(vhi.shape, i32), jnp.zeros(vhi.shape, i32)),
+            valid, g, flo, fhi, lb,
+        )
+        return _pack_outputs(jnp.int32(0), jnp.zeros(0, jnp.uint32), counts,
+                             jnp.zeros(cap, bool), g_vh, g_vl, g_pf, g_ml, ok)
 
     _M_COMPILES.inc()
     return jax.jit(program)
@@ -576,7 +728,22 @@ class _PlanEntry:
 
     __slots__ = (
         "ast", "dims", "fn", "seg", "arrays", "inputs", "tables",
-        "cap", "stamp", "chunk_k", "matched",
+        "cap", "stamp", "chunk_k", "matched", "post", "t_grid", "overlay",
+    )
+
+
+class _Overlay:
+    """What a plan reads of the open block, for one state of it (``stamp``:
+    the open index block's docs and every shard's lane table): the
+    matched series in slot order (the sealed segment's, then those only
+    the open block knows), and for every shard holding some of them its
+    lane table's stamp, their lanes and the series without one. Rebuilt
+    when the stamp moves (a new series, a new lane); a tick moves
+    nothing."""
+
+    __slots__ = (
+        "stamp", "n_sealed", "matched", "cap", "reads", "sources", "cap_s",
+        "lanes", "slot", "n_lanes",
     )
 
 
@@ -686,28 +853,46 @@ class Planner:
             ns = namespaces[self.namespace]
             if ns.index is None:
                 raise Ineligible("no-index")
-            seg, arrays = self._single_device_segment(ns.index, fetch_lo, fetch_hi)
+            open_bs, buffered = self._open_block(ns, fetch_lo, fetch_hi)
+            seg, arrays, mutable = self._index_segments(
+                ns.index, fetch_lo, fetch_hi, open_bs)
             blocks = self._block_set(ns, pool, fetch_lo, fetch_hi)
-            if not blocks:
+            if open_bs is not None and blocks and blocks[-1][1] >= open_bs:
+                # a buffer over a sealed block: a merge the plan cannot do
+                raise _overlay_fallback("buffer-overlay")
+            if not blocks and (seg is not None or open_bs is None):
                 raise Ineligible("no-sealed-blocks")
-            for shard in ns.shards:
-                if shard.has_buffered_overlap(fetch_lo, fetch_hi):
-                    raise Ineligible("buffer-overlay")
+            if blocks and seg is None:
+                raise Ineligible("no-index-segment")
+            live = None if open_bs is None else (open_bs, buffered, mutable)
 
             q = matchers_to_index_query(matchers)
             t_grid = pad_pow2(len(grid), _SENTINEL_GRID)
+            # an open block is named by its start, never by its extent: a
+            # new tick finds the same plan
             key = (
                 self.namespace,
                 tuple((m.name, m.op, m.value) for m in matchers),
                 tuple(blocks),
                 t_grid,
+            ) + (() if open_bs is None else (open_bs,))
+            if live is not None:
+                # no coalescing at the live edge: a write acknowledged
+                # after the leader synced would be missing from a
+                # follower's answer
+                leader, fl = True, None
+            else:
+                fkey = key + (fetch_lo, fetch_hi, grid.tobytes(), lookback_nanos)
+                with self._lock:
+                    fl = self._flights.get(fkey)
+                    leader = fl is None
+                    if leader:
+                        fl = self._flights[fkey] = _Flight()
+        if fl is None:
+            return self._run_leader(
+                key, q, seg, arrays, ns, pool, blocks, t_grid,
+                fetch_lo, fetch_hi, grid, lookback_nanos, live,
             )
-            fkey = key + (fetch_lo, fetch_hi, grid.tobytes(), lookback_nanos)
-            with self._lock:
-                fl = self._flights.get(fkey)
-                leader = fl is None
-                if leader:
-                    fl = self._flights[fkey] = _Flight()
         if not leader:
             # join the in-flight identical scan: this query dispatches
             # nothing (device_dispatches ticks on the leader's thread)
@@ -743,7 +928,7 @@ class Planner:
 
     def _run_leader(self, key, q, seg, arrays, ns, pool, blocks, t_grid,
                     fetch_lo: int, fetch_hi: int, grid: np.ndarray,
-                    lookback_nanos: int):
+                    lookback_nanos: int, live=None):
         from . import stats
 
         with TRACER.stage("plan.lookup"):
@@ -756,49 +941,81 @@ class Planner:
             self.hits += 1
             _M_HITS.inc()
             stats.add(plan_hits=1)
-            return self._execute(
-                entry, ns, fetch_lo, fetch_hi, grid, lookback_nanos
-            )
-        with TRACER.stage("plan.build"):
-            entry = self._build(q, seg, arrays, ns, pool, blocks, t_grid,
-                                self._reported.get(key, 0))
-        with self._lock:
-            self._cache[key] = entry
-            self._cache.move_to_end(key)
-            while len(self._cache) > _cache_cap():
-                self._cache.popitem(last=False)
-        self.misses += 1
-        _M_MISSES.inc()
-        stats.add(plan_misses=1)
+        else:
+            with TRACER.stage("plan.build"):
+                if seg is None:
+                    entry = self._build_open_only(ns, pool, t_grid)
+                else:
+                    entry = self._build(q, seg, arrays, ns, pool, blocks,
+                                        t_grid, self._reported.get(key, 0))
+            with self._lock:
+                self._cache[key] = entry
+                self._cache.move_to_end(key)
+                while len(self._cache) > _cache_cap():
+                    self._cache.popitem(last=False)
+            self.misses += 1
+            _M_MISSES.inc()
+            stats.add(plan_misses=1)
+        if live is not None:
+            return self._execute_overlay(entry, ns, q, live, fetch_lo,
+                                         fetch_hi, grid, lookback_nanos)
         return self._execute(entry, ns, fetch_lo, fetch_hi, grid,
                              lookback_nanos)
 
     # -- eligibility pieces ------------------------------------------------
 
     @staticmethod
-    def _single_device_segment(index, fetch_lo: int, fetch_hi: int):
-        """The range's ONE sealed, device-resident index segment (the v1
-        plan scope; more segments or mutable docs degrade staged)."""
+    def _open_block(ns, fetch_lo: int, fetch_hi: int):
+        """``(block start, shard ids)`` of the one block in range that
+        buffers hold points in, and the shards that hold them; ``(None,
+        frozenset())`` where no buffer overlaps the range."""
+        opened: set[int] = set()
+        shards = []
+        for shard in ns.shards:
+            got = shard.buffered_blocks(fetch_lo, fetch_hi)
+            if got:
+                opened.update(got)
+                shards.append(shard.id)
+        if not opened:
+            return None, frozenset()
+        if len(opened) > 1:
+            raise _overlay_fallback("open-windows")
+        return opened.pop(), frozenset(shards)
+
+    @staticmethod
+    def _index_segments(index, fetch_lo: int, fetch_hi: int, open_bs):
+        """The range's ONE sealed, device-resident index segment (None
+        where the range lies wholly in the open block), and the open
+        block's mutable segment, which the host resolves (None where it
+        holds no docs). Mutable docs of any other block, or more sealed
+        segments, degrade staged."""
         with index.lock:
             segs = []
-            mutable_docs = 0
+            mutable = None
+            stray = 0
             for bs in sorted(index.blocks):
                 if bs + index.block_size <= fetch_lo or bs >= fetch_hi:
                     continue
                 blk = index.blocks[bs]
-                mutable_docs += len(blk.mutable)
+                if len(blk.mutable):
+                    if bs == open_bs:
+                        mutable = blk.mutable
+                    else:
+                        stray += len(blk.mutable)
                 segs.extend(blk.sealed)
-        if mutable_docs:
+        if stray:
             raise Ineligible("mutable-index-block")
         if not segs:
-            raise Ineligible("no-index-segment")
+            if open_bs is None:
+                raise Ineligible("no-index-segment")
+            return None, None, mutable
         if len(segs) > 1:
             raise Ineligible("multi-segment")
         seg = segs[0]
         arrays = getattr(seg, "_arrays", None)
         if arrays is None:
             raise Ineligible("index-not-resident")
-        return seg, arrays
+        return seg, arrays, mutable
 
     def _block_set(self, ns, pool, fetch_lo: int, fetch_hi: int):
         """Sorted ((shard, block_start, volume)) of every sealed fileset
@@ -953,7 +1170,10 @@ class Planner:
         # share a bucket share a compiled program, and a full match is
         # the whole-segment program. n_reported: what this key's last
         # plan counted in its own program when that exceeded its cap
-        n_matched = max(len(search_segment(seg, q)), n_reported)
+        entry.post = search_segment(seg, q)
+        entry.t_grid = t_grid
+        entry.overlay = None
+        n_matched = max(len(entry.post), n_reported)
         entry.cap = min(n_docs_pad, pad_pow2(n_matched, _MIN_CAP))
         _plan_builds(entry.cap).inc()
         entry.chunk_k = chunk_k
@@ -986,7 +1206,126 @@ class Planner:
         entry.matched = None
         return entry
 
+    def _build_open_only(self, ns, pool, t_grid) -> _PlanEntry:
+        """The plan of a range wholly inside the open block: nothing
+        sealed to decode, so nothing but the keying and the stamp; what it
+        reads is its overlay's (``_overlay_for``)."""
+        entry = _PlanEntry()
+        entry.ast = entry.dims = entry.fn = entry.seg = entry.arrays = None
+        entry.inputs = entry.tables = entry.matched = entry.overlay = None
+        entry.cap = entry.chunk_k = 0
+        entry.post = np.zeros(0, np.int32)
+        entry.t_grid = t_grid
+        entry.stamp = self._stamp(None, None, ns, pool)
+        return entry
+
+    def _overlay_for(self, entry, ns, q, live) -> _Overlay:
+        """The entry's overlay for the open block as it stands: the cached
+        one while its stamp holds, else rebuilt. Matchers resolve over the
+        open index block's mutable docs on the host and join the sealed
+        segment's matches by series id; each matched series' lane is read
+        from its shard's lane table."""
+        import jax.numpy as jnp
+
+        from ..block.core import SeriesMeta
+        from ..index.query import search_segment
+
+        open_bs, buffered, mutable = live
+        tables = tuple(
+            None if sh.ingest is None else sh.ingest.frame_stamp(open_bs)
+            for sh in ns.shards
+        )
+        stamp = (open_bs, buffered, id(mutable),
+                 0 if mutable is None else len(mutable), tables)
+        ov = entry.overlay
+        if ov is not None and ov.stamp == stamp:
+            return ov
+
+        docs = ([entry.seg.docs[int(i)] for i in entry.post]
+                if entry.seg is not None else [])
+        n_sealed = len(docs)
+        if mutable is not None:
+            seen = {d.id for d in docs}
+            for i in search_segment(mutable, q):
+                d = mutable.docs[int(i)]
+                if d.id not in seen:
+                    seen.add(d.id)
+                    docs.append(d)
+        slots_of: dict[int, list[int]] = {}
+        for slot, d in enumerate(docs):
+            slots_of.setdefault(ns.shard_for(d.id).id, []).append(slot)
+        reads, sources = [], []
+        for shard_id, slots in sorted(slots_of.items()):
+            buf = ns.shards[shard_id].ingest
+            got = None if buf is None else buf.lanes_for(
+                open_bs, [docs[i].id for i in slots])
+            if got is None:
+                if shard_id in buffered:
+                    # buffered rows the shard has no planes for
+                    raise _overlay_fallback(
+                        "ingest-off" if buf is None else "spilled-row")
+                continue
+            table, lanes = got
+            have = lanes >= 0
+            reads.append((shard_id, table, lanes[have],
+                          [docs[i].id for i, h in zip(slots, have) if not h]))
+            if have.any():
+                sources.append((shard_id, [i for i, h in zip(slots, have) if h],
+                                lanes[have]))
+
+        ov = _Overlay()
+        ov.stamp = stamp
+        ov.n_sealed = n_sealed
+        ov.matched = (docs, [SeriesMeta(tags=d.fields) for d in docs])
+        n = len(docs)
+        # the sealed plan's capacity where the open block adds no series
+        ov.cap = (entry.cap if entry.seg is not None and n <= entry.cap
+                  else pad_pow2(n, _MIN_CAP))
+        ov.reads = reads
+        ov.sources = tuple(shard_id for shard_id, _, _ in sources)
+        ov.cap_s = pad_pow2(max((len(l) for _, _, l in sources), default=0),
+                            _MIN_CAP)
+        lane_rows = np.zeros((len(sources), ov.cap_s), np.int32)
+        slot_row = np.full(ov.cap, len(sources) * ov.cap_s, np.int32)
+        for k, (_, slots, lanes) in enumerate(sources):
+            lane_rows[k, : len(lanes)] = lanes
+            slot_row[slots] = k * ov.cap_s + np.arange(len(lanes))
+        ov.n_lanes = int(sum(len(l) for _, _, l in sources))
+        ov.lanes, ov.slot = jnp.asarray(lane_rows), jnp.asarray(slot_row)
+        entry.overlay = ov
+        return ov
+
     # -- execute -----------------------------------------------------------
+
+    def _dispatch(self, entry, ns, fn, key, request, **overlay):
+        """One dispatch of a plan program with sealed stages, under the
+        pool's read lease."""
+        pool = self.db.resident_pool
+        with pool.read_lease():
+            # buffer snapshots under the lease (same discipline as the
+            # staged resident scan); the plan tables reference page
+            # indices, so the validity stamp re-checks INSIDE the lease:
+            # an eviction + re-admission racing between run()'s check and
+            # this snapshot could otherwise hand reused pages to stale
+            # table rows. Under the lease the snapshot is immutable
+            # (admissions take the functional-copy path), so a stamp that
+            # holds here holds for the whole dispatch.
+            with pool._lock:
+                if pool._words is None or pool._side is None:
+                    raise Ineligible("resident-pool-empty")
+                words, side = pool._words, pool._side
+            if entry.stamp != self._stamp(entry.seg, entry.arrays, ns, pool):
+                raise Ineligible("raced-invalidation")
+            with PROF.dispatch(key) as d:
+                return d.done(fn(
+                    entry.arrays.term_keys, entry.arrays.term_lens,
+                    entry.arrays.post_idx, entry.arrays.post_data,
+                    entry.arrays.all_words,
+                    *entry.inputs,
+                    words, side,
+                    *entry.tables,
+                    request, **overlay,
+                ))
 
     def _execute(self, entry, ns, fetch_lo: int, fetch_hi: int,
                  grid: np.ndarray, lookback_nanos: int):
@@ -996,38 +1335,11 @@ class Planner:
         # plan.enqueue: the lease, the arguments and the dispatch
         # returning; plan.device_wait: the blocked read-back
         with TRACER.stage("plan.enqueue"):
-            pool = self.db.resident_pool
             t_grid = entry.dims[-1]
             request = _pack_request(
                 grid, t_grid, fetch_lo, fetch_hi, lookback_nanos)
-
-            with pool.read_lease():
-                # buffer snapshots under the lease (same discipline as the
-                # staged resident scan); the plan tables reference page
-                # indices, so the validity stamp re-checks INSIDE the lease:
-                # an eviction + re-admission racing between run()'s check and
-                # this snapshot could otherwise hand reused pages to stale
-                # table rows. Under the lease the snapshot is immutable
-                # (admissions take the functional-copy path), so a stamp that
-                # holds here holds for the whole dispatch.
-                with pool._lock:
-                    if pool._words is None or pool._side is None:
-                        raise Ineligible("resident-pool-empty")
-                    words, side = pool._words, pool._side
-                if entry.stamp != self._stamp(
-                    entry.seg, entry.arrays, ns, pool
-                ):
-                    raise Ineligible("raced-invalidation")
-                with PROF.dispatch((entry.ast, entry.dims)) as d:
-                    outs = d.done(entry.fn(
-                        entry.arrays.term_keys, entry.arrays.term_lens,
-                        entry.arrays.post_idx, entry.arrays.post_data,
-                        entry.arrays.all_words,
-                        *entry.inputs,
-                        words, side,
-                        *entry.tables,
-                        request,
-                    ))
+            outs = self._dispatch(entry, ns, entry.fn, (entry.ast, entry.dims),
+                                  request)
         with TRACER.stage("plan.device_wait"):
             (bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml, ok) = (
                 _unpack_reply(
@@ -1070,3 +1382,94 @@ class Planner:
                 window_words=cw,
             )
         return matched, values, datapoints, err_rows
+
+    def _execute_overlay(self, entry, ns, q, live, fetch_lo: int,
+                         fetch_hi: int, grid: np.ndarray,
+                         lookback_nanos: int):
+        """``_execute`` for a range that reaches into the open block: the
+        overlay's host part (plan.overlay: lane lookup, the staged tail's
+        sync, the buffers' leases and their planes), then the one
+        dispatch of the sealed stages with the overlay, or of the overlay
+        alone."""
+        from contextlib import ExitStack
+
+        from . import stats
+
+        open_bs = live[0]
+        t_grid = entry.t_grid
+        with ExitStack() as held:
+            with TRACER.stage("plan.overlay"):
+                ov = self._overlay_for(entry, ns, q, live)
+                # every row acknowledged before this request onto the
+                # device: a sync in flight (another query's, the write
+                # path's) is waited for, and one with nothing left to move
+                # returns at once
+                synced = sum(ns.shards[shard_id].ingest.sync(donate=False)
+                             for shard_id, *_ in ov.reads)
+                cols, counts, width = {}, {}, 0
+                for shard_id, table, lanes, laneless in ov.reads:
+                    buf = ns.shards[shard_id].ingest
+                    held.enter_context(buf.lease())
+                    why, c, n, w = buf.read_lanes(open_bs, table, lanes, laneless)
+                    if why is not None:
+                        raise _overlay_fallback(why)
+                    if c is not None:
+                        cols[shard_id], counts[shard_id] = c, n
+                        width = max(width, w)
+                # no wider than the planes, whose slots need not be a
+                # power of two
+                width = min(pad_pow2(width, _MIN_OVERLAY_WIDTH),
+                            min((c.shape[2] for c in cols.values()),
+                                default=_MIN_OVERLAY_WIDTH))
+                overlay = (
+                    tuple(cols[k] for k in ov.sources),
+                    tuple(counts[k] for k in ov.sources),
+                    ov.lanes, ov.slot,
+                )
+                _M_OVERLAY_SYNC_ROWS.inc(synced)
+            odims = (ov.cap, len(ov.sources), ov.cap_s, width, t_grid)
+            with TRACER.stage("plan.enqueue"):
+                request = _pack_request(
+                    grid, t_grid, fetch_lo, fetch_hi, lookback_nanos)
+                if entry.seg is None:
+                    fn = _build_overlay_program(odims)
+                    with PROF.dispatch((None, odims)) as d:
+                        outs = d.done(fn(request, overlay))
+                else:
+                    dims = entry.dims[:2] + (ov.cap,) + entry.dims[3:]
+                    outs = self._dispatch(
+                        entry, ns, _build_program(entry.ast, dims, odims),
+                        (entry.ast, dims, odims), request, overlay=overlay)
+        n_words = 0 if entry.seg is None else entry.dims[0]
+        with TRACER.stage("plan.device_wait"):
+            (_bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml, ok) = (
+                _unpack_reply(
+                    # m3lint: disable=M3L010 -- sanctioned end-of-query host finalize: the ONE device->host readback after the fused program dispatch
+                    np.asarray(outs), n_words, ov.cap, t_grid)
+            )
+        with TRACER.stage("plan.finalize"):
+            if entry.seg is not None and int(n_matched) != ov.n_sealed:
+                # the device's match is not the one the slots were laid
+                # out for (the two resolves of one frozen segment disagree:
+                # not expected): fall back and rebuild, as _execute does
+                self._drop(entry, int(n_matched))
+                raise Ineligible("plan-capacity")
+            n = len(ov.matched[0])
+            if err[:n].any():
+                # the host re-read of a lane the decoder bailed on reads
+                # sealed streams only
+                raise _overlay_fallback("err-lane")
+            t = len(grid)
+            values = _finalize_grid(
+                g_vh[:n, :t], g_vl[:n, :t], g_pf[:n, :t], g_ml[:n, :t],
+                ok[:n, :t],
+            )
+            datapoints = int(counts[:n].sum())
+            _M_OVERLAY_LANES.inc(ov.n_lanes)
+            n_blocks = 0 if entry.seg is None else entry.dims[3]
+            cw = 0 if entry.seg is None else entry.dims[6]
+            stats.add_plan(
+                lanes_decoded=ov.cap * n_blocks, series_matched=n,
+                window_words=cw, overlay_lanes=ov.n_lanes,
+            )
+        return ov.matched, values, datapoints, np.zeros(0, np.int64)

@@ -108,6 +108,8 @@ class QueryStats:
     plan_lanes_decoded: int = 0
     plan_series_matched: int = 0
     plan_window_words: int = 0
+    # open-block lanes the plan's overlay read from the ingest planes
+    plan_overlay_lanes: int = 0
     # profiled device-kernel dispatches charged to this query (the
     # KernelProfiler seam, utils/instrument.set_dispatch_counter): the
     # fused pipeline's acceptance metric — a warm plan-served query is
@@ -154,6 +156,7 @@ class QueryStats:
             "planLanesDecoded": self.plan_lanes_decoded,
             "planSeriesMatched": self.plan_series_matched,
             "planWindowWords": self.plan_window_words,
+            "planOverlayLanes": self.plan_overlay_lanes,
             "deviceDispatches": self.device_dispatches,
             "traceId": self.trace_id,
             "error": self.error,
@@ -373,7 +376,8 @@ def add(
     st.plan_coalesced += plan_coalesced
 
 
-def add_plan(lanes_decoded: int, series_matched: int, window_words: int) -> None:
+def add_plan(lanes_decoded: int, series_matched: int, window_words: int,
+             overlay_lanes: int = 0) -> None:
     """One plan dispatch of this thread's active query (query/plan.py
     ``_execute``): sums, and the widest window of the query's fetches."""
     st = current()
@@ -382,6 +386,7 @@ def add_plan(lanes_decoded: int, series_matched: int, window_words: int) -> None
     st.plan_lanes_decoded += lanes_decoded
     st.plan_series_matched += series_matched
     st.plan_window_words = max(st.plan_window_words, window_words)
+    st.plan_overlay_lanes += overlay_lanes
 
 
 def _count_dispatch(_kernel: str) -> None:

@@ -17,6 +17,7 @@ without a TPU is a start-up error unless ``JAX_PLATFORMS`` names ``cpu``.
 from __future__ import annotations
 
 import argparse
+import gc
 import signal
 import sys
 
@@ -24,6 +25,17 @@ from ..net.server import NodeServer, NodeService
 from ..storage.database import Database, NamespaceOptions
 from ..storage.mediator import Mediator, MediatorOptions
 from ..storage.series import NANOS
+
+# allocations of container objects, less deallocations, that start a pass
+# of the collector's youngest generation (700 by default). A query's reply
+# holds thousands of containers at once (each series' tags, meta and
+# values; 400 series a dashboard panel), so at 700 every reply set off
+# passes that promoted it while it was still in flight, and those
+# promotions soon called for a full pass over the whole heap (jax's, the
+# index's, the open block's series buffers), which stops every handler
+# thread at once for tens of milliseconds. At 100,000 a pass waits for
+# that much growth, and a reply dies young.
+_GC_GEN0_THRESHOLD = 100_000
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -218,6 +230,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    gc.set_threshold(_GC_GEN0_THRESHOLD, *gc.get_threshold()[1:])
 
     from .. import device
 

@@ -178,7 +178,7 @@ class Shard:
         self.series: dict[bytes, SeriesBuffer] = {}
         self._flushed_blocks: set[int] = set()
         # block_start -> live bucket count across ALL series buffers: the
-        # O(distinct buffered blocks) summary behind has_buffered_overlap.
+        # O(distinct buffered blocks) summary behind buffered_blocks.
         # Buckets exist only while they hold points (created on first
         # write, removed whole by flush/tick eviction), so a nonzero
         # count is exactly "some series has buffered data in this block".
@@ -510,21 +510,20 @@ class Shard:
             buffered = buf is not None and buf.has_points(start, end)
             return keys, buffered
 
-    def has_buffered_overlap(self, start: int, end: int) -> bool:
-        """True when ANY live series buffer holds points in [start, end)
-        — the shard-level buffer-overlay gate the device query planner
-        checks per execution (a fused plan reads sealed residency only,
-        so one buffered point in range degrades the whole query to the
-        staged path, which applies the per-series overlay rule). Served
-        from the maintained block-start summary: O(distinct buffered
-        blocks) regardless of how many series are ingesting, so a
-        heavily ingesting shard answering historical queries pays a few
-        integer compares, not a walk of every live buffer."""
+    def buffered_blocks(self, start: int, end: int) -> list[int]:
+        """Block starts whose live series buffers hold points in [start,
+        end) — the gate the device query planner checks per execution
+        (query/plan.py): none, and a plan reads sealed residency alone;
+        one, the open block, and its overlay reads the shard's ingest
+        planes; more, and the query runs staged. Served from the
+        maintained block-start summary: O(distinct buffered blocks)
+        regardless of how many series are ingesting, so a heavily
+        ingesting shard answering historical queries pays a few integer
+        compares, not a walk of every live buffer."""
         bsz = self.opts.block_size_nanos
         with self.lock:
-            return any(
-                bs + bsz > start and bs < end for bs in self._buffered_blocks
-            )
+            return [bs for bs in self._buffered_blocks
+                    if bs + bsz > start and bs < end]
 
     def scan_segments(self, sid: bytes, start: int, end: int) -> list[tuple]:
         """[(stream, datapoint_bound, chunk_k)] for the STREAMED scan
@@ -731,7 +730,7 @@ class Shard:
                 if buf.evict_block(fid.block_start):
                     self._buffered_dec(fid.block_start)
         # drop buffers the flush emptied (tick would anyway): keeps the
-        # sealed-only fast path O(1) for has_buffered_overlap instead of
+        # sealed-only fast path O(1) for buffered_blocks instead of
         # walking thousands of empty buckets per query
         for sid in [s for s, buf in self.series.items() if not buf.buckets]:
             del self.series[sid]

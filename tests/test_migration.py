@@ -5,7 +5,7 @@ chunked resumable fetch, checkpoint-last commit, digest verification),
 Database.admit_imported_fileset warm admission, the decoded peers stream's
 exclude_blocks dedupe, the resident pool's heat-driven rebalance and
 source-side drop_shard, the O(1) buffered-block summary behind
-has_buffered_overlap, and the ClusterDatabase handoff orchestration
+buffered_blocks, and the ClusterDatabase handoff orchestration
 end-to-end over fake peers — including source death mid-stream falling
 back to the decoded rebuild without wedging INITIALIZING.
 """
@@ -181,7 +181,7 @@ def test_admit_imported_fileset_warms_pool_and_reads_bit_exact(tmp_path):
     st = dst.resident_stats()
     assert st["entries"] > 0, "import must warm the resident pool"
     sh = dst.namespaces["ns"].shards[0]
-    assert not sh.has_buffered_overlap(T0, T0 + 4 * HOUR)  # nothing re-buffered
+    assert not sh.buffered_blocks(T0, T0 + 4 * HOUR)  # nothing re-buffered
     span = (T0 - HOUR, T0 + 4 * HOUR)
     moved = 0
     for sid in sids:
@@ -233,23 +233,23 @@ def test_stream_shard_excludes_migrated_blocks_but_keeps_buffered(tmp_path):
 def test_buffered_summary_tracks_fill_flush_and_expiry(tmp_path):
     db = _mkdb(tmp_path / "db", resident=False)
     sh = db.namespaces["ns"].shards[0]
-    assert not sh.has_buffered_overlap(T0, T0 + 24 * HOUR)
+    assert not sh.buffered_blocks(T0, T0 + 24 * HOUR)
     sids = _ingest(db)
-    assert sh.has_buffered_overlap(T0, T0 + HOUR)
-    assert not sh.has_buffered_overlap(T0 + 4 * HOUR, T0 + 6 * HOUR)
+    assert sh.buffered_blocks(T0, T0 + HOUR)
+    assert not sh.buffered_blocks(T0 + 4 * HOUR, T0 + 6 * HOUR)
     db.flush("ns", T0 + 4 * HOUR)  # warm+cold flush evicts every bucket
-    assert not sh.has_buffered_overlap(T0, T0 + 24 * HOUR)
+    assert not sh.buffered_blocks(T0, T0 + 24 * HOUR)
     assert sh._buffered_blocks == {}
     # a cold write re-fills exactly one block's summary entry
     cold_sid = next(s for s in sids if db.namespaces["ns"].shard_for(s).id == 0)
     db.write("ns", cold_sid, T0 + 7 * NANOS, 1.0)
-    assert sh.has_buffered_overlap(T0, T0 + HOUR)
+    assert sh.buffered_blocks(T0, T0 + HOUR)
     assert len(sh._buffered_blocks) == 1
     db.flush("ns", T0 + 4 * HOUR)  # cold flush bumps the volume, evicts
     assert sh._buffered_blocks == {}
     # retention tick expiry decrements the summary too
     db.write("ns", cold_sid, T0 + 6 * HOUR, 2.0)
-    assert sh.has_buffered_overlap(T0 + 6 * HOUR, T0 + 8 * HOUR)
+    assert sh.buffered_blocks(T0 + 6 * HOUR, T0 + 8 * HOUR)
     db.tick(T0 + 6 * HOUR + db.namespaces["ns"].opts.retention_nanos + 4 * HOUR)
     assert sh._buffered_blocks == {}
     db.close()
@@ -474,7 +474,7 @@ def test_cluster_handoff_migrates_warm_then_cuts_over(tmp_path):
         # a migrated block is resident-eligible (warm before cutover)
         for shard in moved:
             sh = dst.namespaces["ns"].shards[shard]
-            assert not sh.has_buffered_overlap(T0, T0 + 4 * HOUR)
+            assert not sh.buffered_blocks(T0, T0 + 4 * HOUR)
         assert dst.resident_stats()["entries"] > 0
         # bit-identical reads on the new owner
         span = (T0 - HOUR, T0 + 4 * HOUR)

@@ -4,6 +4,9 @@
 the fleet merge (dead peers counted, per-instance tags), the per-shard
 heat satellite, and the selfmon round-trip of m3tpu_profile_*."""
 
+import gc
+import weakref
+
 import numpy as np
 import pytest
 
@@ -151,6 +154,32 @@ def test_profile_golden_contains_synthetic_hot_frame():
     assert stack.index("test_profile_golden") < stack.index(
         "_synthetic_hot_frame_xyz"
     ) < stack.index("sample_once")
+
+
+def test_a_real_sample_keeps_no_sampled_frame_alive():
+    """A sample of live threads must not leave their frames in a reference
+    cycle: a call that was sampled frees its locals when it returns, by
+    reference counting alone, and no pass of the collector is needed."""
+    s = StackSampler(hz=0, clock=lambda: 0.0)
+
+    class Local:
+        pass
+
+    refs = []
+
+    def sampled_call():
+        held = Local()
+        refs.append(weakref.ref(held))
+        assert s.sample_once(now=0.0) >= 1
+
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        sampled_call()
+        assert refs[0]() is None
+    finally:
+        if was:
+            gc.enable()
 
 
 def test_folded_text_format():

@@ -178,15 +178,35 @@ def test_device_index_kernels(sds):
     ).compile()
 
 
+def _overlay_args(sds, cap: int, n_src: int, cap_s: int):
+    """The overlay's arguments (query/plan._overlay_points): ``n_src``
+    shards' ingest planes at the deployment's 1,024 lanes x 1,024 slots,
+    their synced counts, the matched lanes a shard and the slot rows."""
+    lanes = slots = 1024
+    return (tuple(sds((4, lanes, slots), U32) for _ in range(n_src)),
+            tuple(sds((lanes,), I32) for _ in range(n_src)),
+            sds((n_src, cap_s), I32), sds((cap,), I32))
+
+
+def _no_plane_relayout(text: str) -> None:
+    """No operation of the program lays a shard's ingest planes out anew:
+    the overlay gathers whole rows of them (query/plan._overlay_points), and
+    a gather along the planes' middle axis had the compiler copy all 16 MiB
+    a shard a request."""
+    copies = re.findall(r"^\s*(?:ROOT )?%\S+ = u32\[4,1024,1024\]\S* (\w+)\(", text, re.M)
+    assert not [op for op in copies if op != "parameter"], copies
+
+
 def _compile_plan_program(sds, monkeypatch, n_docs: int, cw: int, lane_pages: int,
-                          t_grid: int, cap: int | None = None):
+                          t_grid: int, cap: int | None = None, overlay=None):
     """query/plan._build_program for ``metric{tag="v"}`` (two exact leaves
     ANDed) over an ``n_docs`` segment, one block of CHUNKS chunks whose
     widest lane spans ``cw`` window words and ``lane_pages`` pool pages,
     onto a ``t_grid``-step grid, decoding ``cap`` matched-series slots (the
-    whole segment where not given): the compiled program, held to the
-    device's memory and to no gather over a [cap, t_pts] plane of decoded
-    points."""
+    whole segment where not given), with the open block's overlay where
+    ``overlay`` gives its (sources, cap_s, width): the compiled program,
+    held to the device's memory and to no gather over a [cap, t_pts] plane
+    of decoded points."""
     from m3_tpu import device
     from m3_tpu.query import plan
 
@@ -204,7 +224,12 @@ def _compile_plan_program(sds, monkeypatch, n_docs: int, cw: int, lane_pages: in
     dims = (n_words, n_docs, cap, 1, CHUNKS, CHUNK_K, cw, lp, sl,
             o.page_words, o.side_page_chunks, t_grid)
     leaves = sds((2,), I32)
-    compiled = plan._build_program(ast, dims).lower(
+    odims = extra = None
+    if overlay is not None:
+        n_src, cap_s, width = overlay
+        odims = (cap, n_src, cap_s, width, t_grid)
+        extra = {"overlay": _overlay_args(sds, cap, n_src, cap_s)}
+    compiled = plan._build_program(ast, dims, odims).lower(
         sds((N_TERMS, KEY_WORDS), U32), sds((N_TERMS,), I32),
         sds((N_TERMS, 2), I32), sds((N_FIELDS * n_docs + slab,), I32),
         sds((n_words,), U32),
@@ -212,6 +237,7 @@ def _compile_plan_program(sds, monkeypatch, n_docs: int, cw: int, lane_pages: in
         sds((1,), I32), sds((1,), I32),
         *pool, *tables,
         sds((2 * t_grid + 6,), U32),
+        **(extra or {}),
     ).compile()
     plan._build_program.cache_clear()  # nothing later meets the chip's program
     mem = compiled.memory_analysis()
@@ -275,3 +301,31 @@ def test_plan_program_at_the_capacity_of_what_matched(sds, monkeypatch, n_docs, 
     compiled = _compile_plan_program(sds, monkeypatch, n_docs, cw, lane_pages, 128, cap=cap)
     # the decode is one operation (the point kernel), not a scan's loop
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_plan_program_with_the_open_block_overlay(sds, monkeypatch):
+    """``cpu-only.dashboard-now``'s panel (BENCHMARK.json): the
+    ``cpu-only.haystack`` program (512 slots of 4,000 series, 15-word
+    windows) with the open block's rows of eight shards' ingest planes
+    (about 50 matched lanes a shard: 64, and 185 ticks: 256 slots)
+    appended before stage 5."""
+    compiled = _compile_plan_program(sds, monkeypatch, 4_000, 15, 1, 128, cap=512,
+                                     overlay=(8, 64, 256))
+    assert "tpu_custom_call" in compiled.as_text()
+    _no_plane_relayout(compiled.as_text())
+
+
+def test_overlay_program_alone(sds):
+    """``cpu-only.dashboard-now``'s lastpoint: a range wholly inside the
+    open block, the overlay's rows alone onto a 31-step grid."""
+    from m3_tpu.query import plan
+
+    cap, n_src, cap_s, width, t_grid = 512, 8, 64, 256, 32
+    compiled = plan._build_overlay_program((cap, n_src, cap_s, width, t_grid)).lower(
+        sds((2 * t_grid + 6,), U32), _overlay_args(sds, cap, n_src, cap_s),
+    ).compile()
+    plan._build_overlay_program.cache_clear()  # nothing later meets the chip's program
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30
+    _no_plane_relayout(compiled.as_text())
+
