@@ -25,6 +25,12 @@ from . import wire
 from .faults import plan_from_env
 from .resilience import UnavailableError
 
+_M_REPLY_ARRAY_CELLS = METRICS.counter(
+    "query_reply_array_cells_total",
+    "query_range reply cells sent as one wire array (every reply's "
+    "[series, steps] grid)",
+)
+
 
 class OpHandles(NamedTuple):
     """One op's metric handles. ``label`` is the op, or ``_overflow`` past
@@ -619,9 +625,11 @@ class NodeService:
                         req["query"], req["start"], req["end"], req["step"]
                     )
             with TRACER.stage("reply.build"):
+                # the [series, steps] grid goes on the wire as one array
                 values = np.asarray(r.values, np.float64)
+                _M_REPLY_ARRAY_CELLS.inc(values.size)
                 reply = {
-                    "values": [list(map(float, row)) for row in values],
+                    "values": values,
                     "metas": [
                         [[bytes(k), bytes(v)] for k, v in m.tags]
                         for m in r.metas

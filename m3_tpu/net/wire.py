@@ -11,6 +11,13 @@ the reference speaks TChannel+Thrift; this framework defines its own framing:
              | 'i' i64 | 'd' f64
              | 'b' u32 len bytes | 's' u32 len utf8
              | 'l' u32 count value* | 'm' u32 count (value value)*
+             | 'a' u8 dtype u8 ndim u32*ndim shape  raw
+    dtype   := index into ARRAY_DTYPES (f64, f32, i64, i32, u32, u8, bool)
+    raw     := the elements little-endian, C order (prod(shape) x itemsize)
+
+An 'a' value is a numpy array: it encodes an ``ndarray`` of one of those
+dtypes in one buffer write and decodes to a writable array that owns its
+memory (a kept reply does not pin the frame it came in).
 
 Every RPC request is a map {"op": str, ...args}; every response is a map
 {"ok": bool, "result": ... | "error": str}.
@@ -18,8 +25,11 @@ Every RPC request is a map {"op": str, ...args}; every response is a map
 
 from __future__ import annotations
 
+import math
 import struct
 from io import BytesIO
+
+import numpy as np
 
 from ..codec.m3tsz import Datapoint
 from ..index.query import (
@@ -39,6 +49,12 @@ _I64 = struct.Struct("<q")
 _F64 = struct.Struct("<d")
 
 MAX_FRAME = 256 * 1024 * 1024
+
+# an 'a' value's dtype code is its index here; the encoder looks a dtype up
+# by kind and width ('f8', 'b1', ...), whatever its byte order
+ARRAY_DTYPES = tuple(
+    np.dtype(d) for d in ("<f8", "<f4", "<i8", "<i4", "<u4", "u1", "?"))
+_ARRAY_CODES = {d.str[1:]: code for code, d in enumerate(ARRAY_DTYPES)}
 
 
 def encode_value(v, out: BytesIO) -> None:
@@ -75,6 +91,13 @@ def encode_value(v, out: BytesIO) -> None:
         for k, val in v.items():
             encode_value(k, out)
             encode_value(val, out)
+    elif isinstance(v, np.ndarray):
+        # last: no other value pays for this check
+        code = _ARRAY_CODES.get(v.dtype.str[1:])
+        if code is None:
+            raise TypeError(f"unencodable array dtype {v.dtype!r}")
+        out.write(b"a" + bytes((code, v.ndim)) + struct.pack(f"<{v.ndim}I", *v.shape))
+        out.write(np.ascontiguousarray(v, dtype=ARRAY_DTYPES[code]).data)
     else:
         raise TypeError(f"unencodable type {type(v)!r}")
 
@@ -116,6 +139,25 @@ def decode_value(buf: bytes, pos: int = 0):
             v, pos = decode_value(buf, pos)
             d[k] = v
         return d, pos
+    if tag == b"a":
+        head = buf[pos : pos + 2]
+        if len(head) != 2:
+            raise ValueError("truncated value")
+        code, ndim = head
+        if code >= len(ARRAY_DTYPES):
+            raise ValueError(f"bad array dtype {code} at {pos}")
+        pos += 2
+        if len(buf) < pos + 4 * ndim:
+            raise ValueError("truncated value")
+        shape = struct.unpack_from(f"<{ndim}I", buf, pos)
+        pos += 4 * ndim
+        dtype = ARRAY_DTYPES[code]
+        count = math.prod(shape)
+        end = pos + count * dtype.itemsize
+        if len(buf) < end:
+            raise ValueError("truncated value")
+        # the copy is writable and owns its memory
+        return np.frombuffer(buf, dtype, count, pos).reshape(shape).copy(), end
     raise ValueError(f"bad value tag {tag!r} at {pos - 1}")
 
 

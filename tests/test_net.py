@@ -5,6 +5,7 @@ tchannelthrift/node/service.go:449,626, dtest-style process cluster)."""
 
 import math
 
+import numpy as np
 import pytest
 
 from m3_tpu.codec.m3tsz import Datapoint
@@ -38,6 +39,57 @@ def test_wire_value_roundtrip():
     # NaN needs special compare
     out = wire.loads(wire.dumps(float("nan")))
     assert math.isnan(out)
+
+
+def _grid(dtype, shape, seed=38):
+    rng = np.random.default_rng(seed)
+    dtype = np.dtype(dtype)
+    if dtype == np.bool_:
+        return np.asarray(rng.random(shape) < 0.5)
+    if dtype.kind == "f":
+        return np.asarray(rng.standard_normal(shape), dtype)
+    info = np.iinfo(dtype)
+    return np.asarray(rng.integers(info.min, info.max, shape, dtype=dtype, endpoint=True))
+
+
+def _nan_grid():
+    """A float64 reply grid holding a NaN with a payload of its own."""
+    a = _grid(np.float64, (400, 61))
+    a[200, 30] = np.uint64(0x7FF8_0000_DEAD_BEEF).view(np.float64)
+    return a
+
+
+ARRAY_CASES = [
+    pytest.param(_grid(dtype, shape), id=f"{dtype.str[1:]}-{shape}")
+    for dtype in wire.ARRAY_DTYPES for shape in [(), (0,), (0, 61), (400, 61)]
+] + [
+    pytest.param(_grid(np.float64, (61, 400)).T, id="f8-not-contiguous"),
+    pytest.param(_grid(np.float64, (400, 61)).astype(">f8"), id="f8-big-endian"),
+    pytest.param(_grid(np.int32, (3, 5)).astype(">i4"), id="i4-big-endian"),
+    pytest.param(_nan_grid(), id="f8-nan-payload"),
+]
+
+
+@pytest.mark.parametrize("v", ARRAY_CASES)
+def test_wire_array_value_roundtrip(v):
+    got = wire.loads(wire.dumps({"values": v, "n": 1}))["values"]
+    assert got.shape == v.shape and got.dtype == v.dtype.newbyteorder("<")
+    # bit for bit, NaN payloads included
+    bits = f"u{v.dtype.itemsize}"
+    assert np.array_equal(got.view(bits), v.astype(got.dtype).view(bits))
+    assert got.flags.writeable and got.base is None
+    # a short buffer is refused, wherever it is cut: tag, header, shape, data
+    raw = wire.dumps(v)
+    for cut in (1, 2, 3, len(raw) - 1):
+        with pytest.raises(ValueError):
+            wire.loads(raw[:cut])
+
+
+@pytest.mark.parametrize("v", [np.array(["x", None], dtype=object), np.array(["ab"]),
+                               np.zeros(2, np.complex128)], ids=["object", "str", "complex"])
+def test_wire_array_value_refuses_other_dtypes(v):
+    with pytest.raises(TypeError):
+        wire.dumps({"values": v})
 
 
 def test_wire_query_roundtrip():

@@ -137,6 +137,50 @@ def test_fused_vs_staged_bitexact_smoke(plan_db):
     _assert_bitexact(eng, 'avg_over_time(pm{job=~"app.*",s!="003"}[2m])', SPAN)
 
 
+def test_query_range_reply_grid_is_one_float64_array_on_the_wire(plan_db):
+    """``query_range`` over a served dbnode: ``values`` arrive as ONE
+    float64 [series, steps] array whose bits are the engine's own, on the
+    plan path and the staged one; a query that matches nothing keeps both
+    dimensions; every cell is counted as sent in one array."""
+    from m3_tpu.net.client import RemoteNode
+    from m3_tpu.net.server import NodeServer, NodeService
+
+    def cells_sent() -> float:
+        fam = METRICS.collect().get("m3tpu_query_reply_array_cells_total",
+                                    {"children": []})
+        return sum(c["value"] for c in fam["children"])
+
+    _seed(plan_db)
+    eng = Engine(M3Storage(plan_db, "ns"))
+    query = 'avg_over_time(pm{job=~"app.*",s!="003"}[2m])'
+    steps = (SPAN[1] - SPAN[0]) // SPAN[2] + 1
+    server = NodeServer(NodeService(plan_db))
+    server.start()
+    client = RemoteNode("127.0.0.1", server.port)
+    try:
+        for staged in (False, True):
+            want, metas, _st = _run(eng, query, SPAN, staged=staged)
+            before = cells_sent()
+            reply = client.query_range("ns", query, *SPAN, force_staged=staged)
+            got = reply["values"]
+            assert isinstance(got, np.ndarray) and got.dtype == np.float64
+            assert got.shape == want.shape == (len(metas), steps) and len(metas) == 23
+            assert (got.view(np.uint64) == np.asarray(want, np.float64).view(np.uint64)).all()
+            assert cells_sent() - before == got.size
+            st = reply["stats"]
+            if staged:
+                assert st["planHits"] + st["planMisses"] == 0
+            else:
+                assert st["planHits"] + st["planMisses"] == 1 and st["planFallbacks"] == 0
+        before = cells_sent()
+        reply = client.query_range("ns", 'avg_over_time(pm{job="nope"}[2m])', *SPAN)
+        assert reply["values"].shape == (0, steps) and reply["metas"] == []
+        assert cells_sent() == before
+    finally:
+        client.close()
+        server.stop()
+
+
 @pytest.mark.slow
 def test_fused_vs_staged_bitexact_across_shapes(plan_db):
     _seed(plan_db)

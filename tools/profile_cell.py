@@ -16,8 +16,9 @@ the window's start and close. After the run it reduces the capture with
   ``stages_before_window`` and ``stages_after_window`` the same for set-up
   (load, seal, warm-up) and for what follows the window (the write cell's
   seal and read-backs): wall and calls only, no capture runs there;
-- ``counters``: the window's growth of the commit-log, RPC byte, recv-wait
-  and compile counters, and the stack sampler's overhead ratio at its close;
+- ``counters``: the window's growth of the commit-log, RPC byte, recv-wait,
+  compile and reply-array-cell counters, and the stack sampler's overhead
+  ratio at its close;
 - ``labelled``: set-up's seal by encoder and refusal reason
   (``m3tpu_seal_lanes_total``, ``m3tpu_seal_host_lanes_total``) and the
   admitted chunks by decode body (``m3tpu_resident_chunks_total``), child by
@@ -56,6 +57,7 @@ FAMILIES = (
     "m3tpu_rpc_response_bytes_total", "m3tpu_jit_compiles_total",
     "m3tpu_jit_cache_hits_total", "m3tpu_profile_samples_total",
     "m3tpu_profile_overhead_seconds_total",
+    "m3tpu_query_reply_array_cells_total",
 )
 GAUGES = ("m3tpu_profile_overhead_ratio", "m3tpu_commitlog_queue_depth",
           "m3tpu_device_peak_bytes_in_use",
