@@ -55,11 +55,18 @@ therefore structural: both paths reconstruct values with the same f64
 arithmetic and pick grid samples with the same upper-bound rule — the
 property suite asserts exact equality, not tolerance.
 
-Plan cache: an LRU keyed by (namespace, matchers, block set, grid
-shape). Entries carry the uploaded plan-vector tables and revalidate
-per execution against pool eviction/invalidation counters, shard
-fileset epochs, and index-segment identity — a segment swap, volume
-bump, or resident eviction invalidates the plan (regression-tested).
+Plan cache, in two parts. The segment's part (``_SegmentPlan``: the
+uploaded per-(doc, block) plan-vector tables and the dimensions they
+fix) follows the segment, the block set and the pool, never the
+matchers, and is kept per (namespace, block set, segment). The
+matchers' part (``_PlanEntry``: query keys, the resolve's count and its
+``cap``, the compiled program) is an LRU keyed by (namespace, matchers,
+block set, grid shape) and points at a segment part, so a new matcher
+value (a dashboard's next host) costs its own index resolve and not a
+walk over every doc. Both revalidate against pool eviction/invalidation
+counters, shard fileset epochs, and index-segment identity — a segment
+swap, volume bump, or resident eviction invalidates the plan
+(regression-tested) and rebuilds the segment's part once.
 Ineligible queries fall back to the staged executor transparently with
 an EXPLAIN routing reason per cause (host-regexp leaf, non-resident
 block, a buffer over a sealed block, multi-segment index, ...).
@@ -150,6 +157,14 @@ def _overlay_fallback(reason: str) -> "Ineligible":
     _M_OVERLAY_FALLBACKS[reason].inc()
     return Ineligible(
         "buffer-overlay" if reason == "buffer-overlay" else f"overlay:{reason}")
+
+
+_M_TABLE_BUILDS = METRICS.counter(
+    "query_plan_table_builds_total",
+    "segment parts of device query plans built: a walk over every doc and "
+    "block of the segment and an upload of its lane tables (a plan built "
+    "over a segment part that still holds walks nothing)",
+)
 
 
 def _plan_builds(cap: int):
@@ -721,14 +736,28 @@ def _finalize_grid(vhi, vlo, pif, mult, ok) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+class _SegmentPlan:
+    """What a plan derives from its (segment, block set) and the pool,
+    whatever its matchers: the uploaded per-(doc, block) plan-vector
+    tables and the dimensions they fix (``dims``: the plan's dims without
+    ``cap`` and ``t_grid``), stamped before the walk that filled them. It
+    holds its segment and the segment's arrays, so the ids its key and
+    stamp hold stay theirs. A range wholly inside the open block has a
+    part with no segment: ``seg``, ``arrays``, ``tables`` and ``dims``
+    None, and the stamp alone."""
+
+    __slots__ = ("seg", "arrays", "stamp", "tables", "dims")
+
+
 class _PlanEntry:
-    """One cached plan: the compiled program, the uploaded plan-vector
-    tables for its (segment, block set), pre-built query-key inputs, and
-    the validity stamp it revalidates against per execution."""
+    """One cached plan: the compiled program, pre-built query-key inputs,
+    the resolve's matches and ``cap``, and the segment part whose tables
+    it reads and whose stamp it revalidates against per execution
+    (``part``)."""
 
     __slots__ = (
-        "ast", "dims", "fn", "seg", "arrays", "inputs", "tables",
-        "cap", "stamp", "chunk_k", "matched", "post", "t_grid", "overlay",
+        "ast", "dims", "fn", "inputs", "part",
+        "cap", "matched", "post", "t_grid", "overlay",
     )
 
 
@@ -768,7 +797,13 @@ class Planner:
         self.db = db
         self.namespace = namespace
         self._cache: "OrderedDict[tuple, _PlanEntry]" = OrderedDict()
+        # segment parts by (namespace, block set, id(segment)), as many as
+        # plans at most; a part holds its segment, so the id stays its own
+        self._parts: "OrderedDict[tuple, _SegmentPlan]" = OrderedDict()
         self._lock = threading.Lock()
+        # one walk at a time: concurrent misses over one stale or new
+        # segment part build it once
+        self._parts_lock = threading.Lock()
         # scan coalescing (singleflight): identical concurrent fetches
         # keyed by (plan key, window, grid) share ONE gathered dispatch
         self._flights: dict[tuple, _Flight] = {}
@@ -783,13 +818,14 @@ class Planner:
         self.coalesced = 0
 
     def evict_stale(self) -> int:
-        """Drop cached plans whose pool/fileset stamp no longer holds —
-        called by the fallback path so entries built against evicted or
-        invalidated state release their pinned device tables (and the
-        index-segment arrays they keep alive) instead of lingering until
-        LRU displacement. Segment-identity staleness is covered too: a
-        swapped segment's plan was stamped with pool/epoch state that
-        moved with the swap's invalidations. O(cache), cache is small."""
+        """Drop cached plans and segment parts whose pool/fileset stamp no
+        longer holds — called by the fallback path so entries built
+        against evicted or invalidated state release their pinned device
+        tables (and the index-segment arrays they keep alive) instead of
+        lingering until LRU displacement. Segment-identity staleness is
+        covered too: a swapped segment's plan was stamped with pool/epoch
+        state that moved with the swap's invalidations. O(cache), cache is
+        small."""
         pool = getattr(self.db, "resident_pool", None)
         namespaces = getattr(self.db, "namespaces", None)
         if pool is None or namespaces is None or self.namespace not in namespaces:
@@ -801,12 +837,14 @@ class Planner:
         )
         with self._lock:
             stale = [
-                k for k, e in self._cache.items() if e.stamp[2:] != live
+                k for k, e in self._cache.items() if e.part.stamp[2:] != live
             ]
             for k in stale:
                 del self._cache[k]
             if stale:
                 self._reported.clear()  # counts of segments that are gone
+            for k in [k for k, p in self._parts.items() if p.stamp[2:] != live]:
+                del self._parts[k]
         return len(stale)
 
     def _drop(self, entry, n_matched: int) -> None:
@@ -836,8 +874,9 @@ class Planner:
 
         # stages (utils/trace.py; PERF.md section 3): plan.lookup here and
         # at the cache in _run_leader, then plan.coalesce_wait (a
-        # follower) or plan.build (a miss), plan.enqueue,
-        # plan.device_wait, plan.finalize
+        # follower) or plan.build (a miss: plan.tables where the segment's
+        # part is built, plan.resolve), plan.enqueue, plan.device_wait,
+        # plan.finalize
         with TRACER.stage("plan.lookup"):
             if not plan_enabled():
                 raise Ineligible("plan-disabled")
@@ -1045,17 +1084,123 @@ class Planner:
         )
 
     def _valid(self, entry, seg, arrays, ns, pool) -> bool:
-        return entry.stamp == self._stamp(seg, arrays, ns, pool)
+        return entry.part.stamp == self._stamp(seg, arrays, ns, pool)
 
     # -- build -------------------------------------------------------------
 
     def _build(self, q, seg, arrays, ns, pool, blocks, t_grid,
                n_reported: int = 0) -> _PlanEntry:
+        """The matchers' part of a plan over the segment's part (built
+        first where none holds): query keys, one index resolve for
+        ``cap``, the program."""
+        import jax.numpy as jnp
+
+        from ..index.device import kernels
+        from ..index.query import search_segment
+
+        leaves: list = []
+        ranges: list = []
+        ast = _ast_shape(q, arrays, leaves, ranges)
+        part = self._segment_part(seg, arrays, ns, pool, blocks)
+
+        # query-key inputs (values are fixed per entry: matchers carry
+        # them, and the entry is keyed by matchers)
+        bq = len(leaves)
+        bq_pad = kernels.pad_pow2(bq) if bq else 0
+        values = [v for _, v in leaves] + [b""] * (bq_pad - bq)
+        if bq:
+            q_keys, q_lens = kernels.build_query_keys(values, arrays.k_words)
+        else:
+            q_keys = np.zeros((0, arrays.k_words), np.uint32)
+            q_lens = np.zeros(0, np.int32)
+        q_lo = np.zeros(bq_pad, np.int32)
+        q_hi = np.zeros_like(q_lo)
+        for i, (field, _v) in enumerate(leaves):
+            start, count = arrays.fields.get(field, (0, 0, 0, 0))[:2]
+            q_lo[i], q_hi[i] = start, start + count
+        r_lo = np.asarray([lo for lo, _ in ranges] or [0], np.int32)
+        r_hi = np.asarray([hi for _, hi in ranges] or [0], np.int32)
+
+        entry = _PlanEntry()
+        entry.ast = ast
+        entry.part = part
+        # decode capacity follows what matched, not the segment: the
+        # matched set is a pure function of the segment arrays and the
+        # matcher values, both frozen while the stamp holds, so one index
+        # resolve a build counts it (on the device; on the host where the
+        # tier answers None). Stage 3 compacts the matched docs into the
+        # first n of cap slots; a power of two, so matchers whose counts
+        # share a bucket share a compiled program, and a full match is
+        # the whole-segment program. n_reported: what this key's last
+        # plan counted in its own program when that exceeded its cap
+        with TRACER.stage("plan.resolve"):
+            entry.post = search_segment(seg, q)
+        entry.t_grid = t_grid
+        entry.overlay = None
+        n_docs_pad = part.dims[1]
+        n_matched = max(len(entry.post), n_reported)
+        entry.cap = min(n_docs_pad, pad_pow2(n_matched, _MIN_CAP))
+        _plan_builds(entry.cap).inc()
+        entry.dims = part.dims[:2] + (entry.cap,) + part.dims[2:] + (t_grid,)
+        entry.inputs = (
+            jnp.asarray(q_keys), jnp.asarray(q_lens),
+            jnp.asarray(q_lo), jnp.asarray(q_hi),
+            jnp.asarray(r_lo), jnp.asarray(r_hi),
+        )
+        entry.fn = _build_program(ast, entry.dims)
+        n_blocks, c, cw = part.dims[2], part.dims[3], part.dims[5]
+        slots = entry.cap * n_blocks
+        for name, value in (("window_words", cw), ("chunks", c),
+                            ("decode_slots", slots),
+                            ("gather_words", slots * c * cw)):
+            _G_PLAN_DIMS[name].set(value)
+        # matched-doc cache: the matched set is a pure function of the
+        # segment arrays and the matcher values, both frozen while the
+        # stamp holds — so the per-doc tag materialization (the cost that
+        # dominated large fan-outs host-side) is paid ONCE per plan, not
+        # per query
+        entry.matched = None
+        return entry
+
+    def _segment_part(self, seg, arrays, ns, pool, blocks) -> _SegmentPlan:
+        """The (segment, block set)'s part while its stamp holds, else
+        built (``plan.tables``) and kept, displacing the least recently
+        used past the plan cache's capacity."""
+        key = (self.namespace, tuple(blocks), id(seg))
+
+        def kept():
+            with self._lock:
+                part = self._parts.get(key)
+                if part is not None:
+                    self._parts.move_to_end(key)
+            if part is not None and part.stamp == self._stamp(seg, arrays, ns, pool):
+                return part
+            return None
+
+        part = kept()
+        if part is not None:
+            return part
+        with self._parts_lock:
+            part = kept()  # another miss built it while this one waited
+            if part is not None:
+                return part
+            with TRACER.stage("plan.tables"):
+                part = self._build_tables(seg, arrays, ns, pool, blocks)
+            _M_TABLE_BUILDS.inc()
+            with self._lock:
+                self._parts[key] = part
+                self._parts.move_to_end(key)
+                while len(self._parts) > _cache_cap():
+                    self._parts.popitem(last=False)
+        return part
+
+    def _build_tables(self, seg, arrays, ns, pool, blocks) -> _SegmentPlan:
+        """Walk every doc of the segment and every block of the set
+        through the pool's page table into the per-(doc, block) lane
+        tables, and upload them."""
         import jax.numpy as jnp
 
         from ..cache.block_cache import BlockKey
-        from ..index.device import kernels
-        from ..index.query import search_segment
         from ..ops.chunked import window_words
 
         # stamp BEFORE the page-table walk: an eviction racing the walk
@@ -1064,9 +1209,6 @@ class Planner:
         # current counters — the in-lease re-check in _execute must see
         # a stamp OLDER than any such churn and refuse to serve
         stamp = self._stamp(seg, arrays, ns, pool)
-        leaves: list = []
-        ranges: list = []
-        ast = _ast_shape(q, arrays, leaves, ranges)
 
         docs = list(seg.docs)
         n_docs = len(docs)
@@ -1139,84 +1281,33 @@ class Planner:
             t_bhi[i] = (int(bs) >> 32) & 0xFFFFFFFF
             t_blo[i] = int(bs) & 0xFFFFFFFF
 
-        # query-key inputs (values are fixed per entry: matchers carry
-        # them, and the entry is keyed by matchers)
-        bq = len(leaves)
-        bq_pad = kernels.pad_pow2(bq) if bq else 0
-        values = [v for _, v in leaves] + [b""] * (bq_pad - bq)
-        if bq:
-            q_keys, q_lens = kernels.build_query_keys(values, arrays.k_words)
-        else:
-            q_keys = np.zeros((0, arrays.k_words), np.uint32)
-            q_lens = np.zeros(0, np.int32)
-        q_lo = np.zeros(bq_pad, np.int32)
-        q_hi = np.zeros_like(q_lo)
-        for i, (field, _v) in enumerate(leaves):
-            start, count = arrays.fields.get(field, (0, 0, 0, 0))[:2]
-            q_lo[i], q_hi[i] = start, start + count
-        r_lo = np.asarray([lo for lo, _ in ranges] or [0], np.int32)
-        r_hi = np.asarray([hi for _, hi in ranges] or [0], np.int32)
-
-        entry = _PlanEntry()
-        entry.ast = ast
-        entry.seg = seg
-        entry.arrays = arrays
-        # decode capacity follows what matched, not the segment: the
-        # matched set is a pure function of the segment arrays and the
-        # matcher values, both frozen while the stamp holds, so one index
-        # resolve a build counts it (on the device; on the host where the
-        # tier answers None). Stage 3 compacts the matched docs into the
-        # first n of cap slots; a power of two, so matchers whose counts
-        # share a bucket share a compiled program, and a full match is
-        # the whole-segment program. n_reported: what this key's last
-        # plan counted in its own program when that exceeded its cap
-        entry.post = search_segment(seg, q)
-        entry.t_grid = t_grid
-        entry.overlay = None
-        n_matched = max(len(entry.post), n_reported)
-        entry.cap = min(n_docs_pad, pad_pow2(n_matched, _MIN_CAP))
-        _plan_builds(entry.cap).inc()
-        entry.chunk_k = chunk_k
-        entry.stamp = stamp
-        entry.dims = (
-            arrays.n_words, n_docs_pad, entry.cap, n_blocks, c, chunk_k,
-            cw, lp, sl, o.page_words, o.side_page_chunks, t_grid,
-        )
-        entry.inputs = (
-            jnp.asarray(q_keys), jnp.asarray(q_lens),
-            jnp.asarray(q_lo), jnp.asarray(q_hi),
-            jnp.asarray(r_lo), jnp.asarray(r_hi),
-        )
-        entry.tables = (
+        part = _SegmentPlan()
+        part.seg = seg
+        part.arrays = arrays
+        part.stamp = stamp
+        part.dims = (arrays.n_words, n_docs_pad, n_blocks, c, chunk_k, cw,
+                     lp, sl, o.page_words, o.side_page_chunks)
+        part.tables = (
             jnp.asarray(t_pages), jnp.asarray(t_sides),
             jnp.asarray(t_chunks), jnp.asarray(t_bits),
             jnp.asarray(t_bhi), jnp.asarray(t_blo),
         )
-        entry.fn = _build_program(ast, entry.dims)
-        slots = entry.cap * n_blocks
-        for name, value in (("window_words", cw), ("chunks", c),
-                            ("decode_slots", slots),
-                            ("gather_words", slots * c * cw)):
-            _G_PLAN_DIMS[name].set(value)
-        # matched-doc cache: the matched set is a pure function of the
-        # segment arrays and the matcher values, both frozen while the
-        # stamp holds — so the per-doc tag materialization (the cost that
-        # dominated large fan-outs host-side) is paid ONCE per plan, not
-        # per query
-        entry.matched = None
-        return entry
+        return part
 
     def _build_open_only(self, ns, pool, t_grid) -> _PlanEntry:
         """The plan of a range wholly inside the open block: nothing
         sealed to decode, so nothing but the keying and the stamp; what it
         reads is its overlay's (``_overlay_for``)."""
+        part = _SegmentPlan()
+        part.seg = part.arrays = part.tables = part.dims = None
+        part.stamp = self._stamp(None, None, ns, pool)
         entry = _PlanEntry()
-        entry.ast = entry.dims = entry.fn = entry.seg = entry.arrays = None
-        entry.inputs = entry.tables = entry.matched = entry.overlay = None
-        entry.cap = entry.chunk_k = 0
+        entry.ast = entry.dims = entry.fn = None
+        entry.inputs = entry.matched = entry.overlay = None
+        entry.part = part
+        entry.cap = 0
         entry.post = np.zeros(0, np.int32)
         entry.t_grid = t_grid
-        entry.stamp = self._stamp(None, None, ns, pool)
         return entry
 
     def _overlay_for(self, entry, ns, q, live) -> _Overlay:
@@ -1241,8 +1332,8 @@ class Planner:
         if ov is not None and ov.stamp == stamp:
             return ov
 
-        docs = ([entry.seg.docs[int(i)] for i in entry.post]
-                if entry.seg is not None else [])
+        seg = entry.part.seg
+        docs = [seg.docs[int(i)] for i in entry.post] if seg is not None else []
         n_sealed = len(docs)
         if mutable is not None:
             seen = {d.id for d in docs}
@@ -1279,7 +1370,7 @@ class Planner:
         ov.matched = (docs, [SeriesMeta(tags=d.fields) for d in docs])
         n = len(docs)
         # the sealed plan's capacity where the open block adds no series
-        ov.cap = (entry.cap if entry.seg is not None and n <= entry.cap
+        ov.cap = (entry.cap if seg is not None and n <= entry.cap
                   else pad_pow2(n, _MIN_CAP))
         ov.reads = reads
         ov.sources = tuple(shard_id for shard_id, _, _ in sources)
@@ -1314,16 +1405,18 @@ class Planner:
                 if pool._words is None or pool._side is None:
                     raise Ineligible("resident-pool-empty")
                 words, side = pool._words, pool._side
-            if entry.stamp != self._stamp(entry.seg, entry.arrays, ns, pool):
+            part = entry.part
+            if part.stamp != self._stamp(part.seg, part.arrays, ns, pool):
                 raise Ineligible("raced-invalidation")
+            arrays = part.arrays
             with PROF.dispatch(key) as d:
                 return d.done(fn(
-                    entry.arrays.term_keys, entry.arrays.term_lens,
-                    entry.arrays.post_idx, entry.arrays.post_data,
-                    entry.arrays.all_words,
+                    arrays.term_keys, arrays.term_lens,
+                    arrays.post_idx, arrays.post_data,
+                    arrays.all_words,
                     *entry.inputs,
                     words, side,
-                    *entry.tables,
+                    *part.tables,
                     request, **overlay,
                 ))
 
@@ -1362,7 +1455,7 @@ class Planner:
                 from ..block.core import SeriesMeta
 
                 doc_ids = kernels.bitmap_to_docids(bitmap)[:n]
-                docs = entry.seg.docs
+                docs = entry.part.seg.docs
                 matched_docs = [docs[int(i)] for i in doc_ids]
                 matched = (
                     matched_docs,
@@ -1397,6 +1490,7 @@ class Planner:
 
         open_bs = live[0]
         t_grid = entry.t_grid
+        sealed = entry.part.seg is not None
         with ExitStack() as held:
             with TRACER.stage("plan.overlay"):
                 ov = self._overlay_for(entry, ns, q, live)
@@ -1431,7 +1525,7 @@ class Planner:
             with TRACER.stage("plan.enqueue"):
                 request = _pack_request(
                     grid, t_grid, fetch_lo, fetch_hi, lookback_nanos)
-                if entry.seg is None:
+                if not sealed:
                     fn = _build_overlay_program(odims)
                     with PROF.dispatch((None, odims)) as d:
                         outs = d.done(fn(request, overlay))
@@ -1440,7 +1534,7 @@ class Planner:
                     outs = self._dispatch(
                         entry, ns, _build_program(entry.ast, dims, odims),
                         (entry.ast, dims, odims), request, overlay=overlay)
-        n_words = 0 if entry.seg is None else entry.dims[0]
+        n_words = entry.dims[0] if sealed else 0
         with TRACER.stage("plan.device_wait"):
             (_bitmap, n_matched, counts, err, g_vh, g_vl, g_pf, g_ml, ok) = (
                 _unpack_reply(
@@ -1448,7 +1542,7 @@ class Planner:
                     np.asarray(outs), n_words, ov.cap, t_grid)
             )
         with TRACER.stage("plan.finalize"):
-            if entry.seg is not None and int(n_matched) != ov.n_sealed:
+            if sealed and int(n_matched) != ov.n_sealed:
                 # the device's match is not the one the slots were laid
                 # out for (the two resolves of one frozen segment disagree:
                 # not expected): fall back and rebuild, as _execute does
@@ -1466,8 +1560,8 @@ class Planner:
             )
             datapoints = int(counts[:n].sum())
             _M_OVERLAY_LANES.inc(ov.n_lanes)
-            n_blocks = 0 if entry.seg is None else entry.dims[3]
-            cw = 0 if entry.seg is None else entry.dims[6]
+            n_blocks = entry.dims[3] if sealed else 0
+            cw = entry.dims[6] if sealed else 0
             stats.add_plan(
                 lanes_decoded=ov.cap * n_blocks, series_matched=n,
                 window_words=cw, overlay_lanes=ov.n_lanes,
