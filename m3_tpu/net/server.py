@@ -11,11 +11,13 @@ connection.
 
 from __future__ import annotations
 
+import operator
 import os
 import socket
 import socketserver
 import threading
 import time
+from collections import OrderedDict
 from typing import NamedTuple
 
 from ..utils.instrument import DEFAULT as METRICS
@@ -30,6 +32,49 @@ _M_REPLY_ARRAY_CELLS = METRICS.counter(
     "query_range reply cells sent as one wire array (every reply's "
     "[series, steps] grid)",
 )
+_M_REPLY_TAG_TABLES = {
+    memo: METRICS.counter(
+        "query_reply_tag_table_total",
+        "query_range replies' series labels sent as one tag table, by "
+        "whether the table came from the memo of matched sets",
+        labels={"memo": memo},
+    )
+    for memo in ("hit", "miss")
+}
+# tag tables a TagTableMemo keeps: the plan cache's default size
+_TAG_TABLE_MEMO_ENTRIES = 64
+
+
+class TagTableMemo:
+    """The series labels of ``query_range`` replies as encoded tag tables
+    (``wire.tag_table``), kept by the identity of the ``SeriesMeta`` objects
+    a reply holds, in order. A device plan hands every request it serves the
+    same objects for its matched set, so a warm reply's labels are encoded
+    once; a reply that holds new objects (another plan, a rebuilt one, a
+    staged or aggregated result) misses and encodes a table of its own. An
+    entry holds its objects, so their ids are not reused while it lives."""
+
+    def __init__(self) -> None:
+        self._entries: OrderedDict = OrderedDict()
+        self._lock = threading.Lock()
+
+    def table(self, metas) -> wire.TagTable:
+        metas = tuple(metas)
+        key = tuple(map(id, metas))
+        with self._lock:
+            kept = self._entries.get(key)
+            if kept is not None and all(map(operator.is_, kept[0], metas)):
+                self._entries.move_to_end(key)
+                _M_REPLY_TAG_TABLES["hit"].inc()
+                return kept[1]
+        table = wire.tag_table([m.tags for m in metas])
+        with self._lock:
+            self._entries[key] = (metas, table)
+            self._entries.move_to_end(key)
+            while len(self._entries) > _TAG_TABLE_MEMO_ENTRIES:
+                self._entries.popitem(last=False)
+        _M_REPLY_TAG_TABLES["miss"].inc()
+        return table
 
 
 class OpHandles(NamedTuple):
@@ -267,6 +312,7 @@ class NodeService:
         self.db = db
         self.node_id = node_id
         self.assigned_shards: set[int] = set(assigned_shards or ())
+        self.tag_tables = TagTableMemo()
 
     def handle(self, req: dict):
         op = req.get("op")
@@ -625,15 +671,13 @@ class NodeService:
                         req["query"], req["start"], req["end"], req["step"]
                     )
             with TRACER.stage("reply.build"):
-                # the [series, steps] grid goes on the wire as one array
+                # the [series, steps] grid goes on the wire as one array,
+                # the series' labels as one tag table
                 values = np.asarray(r.values, np.float64)
                 _M_REPLY_ARRAY_CELLS.inc(values.size)
                 reply = {
                     "values": values,
-                    "metas": [
-                        [[bytes(k), bytes(v)] for k, v in m.tags]
-                        for m in r.metas
-                    ],
+                    "metas": self.tag_tables.table(r.metas),
                 }
         except Exception as exc:
             err = f"{type(exc).__name__}: {exc}"

@@ -12,12 +12,21 @@ the reference speaks TChannel+Thrift; this framework defines its own framing:
              | 'b' u32 len bytes | 's' u32 len utf8
              | 'l' u32 count value* | 'm' u32 count (value value)*
              | 'a' u8 dtype u8 ndim u32*ndim shape  raw
+             | 'g' u32 n (u32 len bytes)*n  u32 s u32*s pairs  u32*(2 x sum(pairs)) refs
     dtype   := index into ARRAY_DTYPES (f64, f32, i64, i32, u32, u8, bool)
     raw     := the elements little-endian, C order (prod(shape) x itemsize)
+    refs    := each pair's name then value, as indices into the n strings
 
 An 'a' value is a numpy array: it encodes an ``ndarray`` of one of those
 dtypes in one buffer write and decodes to a writable array that owns its
 memory (a kept reply does not pin the frame it came in).
+
+A 'g' value is a tag table: the tags of ``s`` series, each a sequence of
+``pairs`` (name, value) byte strings, with every distinct string written
+once. ``tag_table`` builds one as a ``TagTable``, which ``encode_value``
+writes as it is; it decodes to what the same series encode to as lists of
+``'b'`` values: a list of lists of two-element ``[name, value]`` lists
+(the ``bytes`` objects may be shared between pairs; the lists are not).
 
 Every RPC request is a map {"op": str, ...args}; every response is a map
 {"ok": bool, "result": ... | "error": str}.
@@ -25,7 +34,9 @@ Every RPC request is a map {"op": str, ...args}; every response is a map
 
 from __future__ import annotations
 
+import itertools
 import math
+import operator
 import struct
 from io import BytesIO
 
@@ -98,6 +109,8 @@ def encode_value(v, out: BytesIO) -> None:
             raise TypeError(f"unencodable array dtype {v.dtype!r}")
         out.write(b"a" + bytes((code, v.ndim)) + struct.pack(f"<{v.ndim}I", *v.shape))
         out.write(np.ascontiguousarray(v, dtype=ARRAY_DTYPES[code]).data)
+    elif isinstance(v, TagTable):
+        out.write(v.encoded)
     else:
         raise TypeError(f"unencodable type {type(v)!r}")
 
@@ -158,7 +171,69 @@ def decode_value(buf: bytes, pos: int = 0):
             raise ValueError("truncated value")
         # the copy is writable and owns its memory
         return np.frombuffer(buf, dtype, count, pos).reshape(shape).copy(), end
+    if tag == b"g":
+        return _decode_tag_table(buf, pos)
     raise ValueError(f"bad value tag {tag!r} at {pos - 1}")
+
+
+class TagTable:
+    """An encoded ``'g'`` value (``tag_table`` builds one): ``encode_value``
+    writes ``encoded`` as it is, so a table built once can go out in many
+    frames."""
+
+    __slots__ = ("encoded",)
+
+    def __init__(self, encoded: bytes) -> None:
+        self.encoded = encoded
+
+
+def tag_table(series) -> TagTable:
+    """The ``'g'`` value of ``series``: each a sequence of (name, value)
+    byte strings, as a ``SeriesMeta``'s tags are."""
+    index: dict = {}
+    counts = [len(tags) for tags in series]
+    refs = [index.setdefault(s, len(index))
+            for tags in series for pair in tags for s in pair]
+    if len(refs) != 2 * sum(counts):
+        raise ValueError("a tag is not a (name, value) pair")
+    parts = [b"g", _U32.pack(len(index))]
+    for s in index:
+        parts.append(_U32.pack(len(s)))
+        parts.append(s)
+    parts.append(struct.pack(f"<I{len(counts)}I", len(counts), *counts))
+    parts.append(struct.pack(f"<{len(refs)}I", *refs))
+    return TagTable(b"".join(parts))
+
+
+def _u32s(buf, pos: int, n: int) -> tuple[tuple, int]:
+    end = pos + 4 * n
+    if len(buf) < end:
+        raise ValueError("truncated value")
+    return struct.unpack_from(f"<{n}I", buf, pos), end
+
+
+def _decode_tag_table(buf, pos: int):
+    (n,), pos = _u32s(buf, pos, 1)
+    strings = []
+    for _ in range(n):
+        (size,), pos = _u32s(buf, pos, 1)
+        s = buf[pos : pos + size]
+        if len(s) != size:
+            raise ValueError("truncated value")
+        strings.append(s)
+        pos += size
+    (n_series,), pos = _u32s(buf, pos, 1)
+    counts, pos = _u32s(buf, pos, n_series)
+    refs, pos = _u32s(buf, pos, 2 * sum(counts))
+    try:
+        # one C call for every string of every pair; a tuple of two or more
+        # refs, since a pair is two
+        it = iter(operator.itemgetter(*refs)(strings) if refs else ())
+    except IndexError:
+        raise ValueError(f"bad tag table index (of {n} strings)") from None
+    pairs = list(map(list, zip(it, it)))
+    ends = list(itertools.accumulate(counts))
+    return [pairs[a:b] for a, b in zip([0, *ends], ends)], pos
 
 
 def dumps(v) -> bytes:

@@ -27,6 +27,7 @@ import fleet  # noqa: E402  (benchmark/fleet.py)
 import reference  # noqa: E402
 import traffic  # noqa: E402
 
+from m3_tpu.net import wire  # noqa: E402
 from m3_tpu.utils.instrument import DEFAULT as METRICS  # noqa: E402
 
 HOSTS = 4
@@ -110,8 +111,10 @@ class Served:
         cls = {"fn": fn, "metric": metric, "range_secs": 300, "step_secs": 60,
                "span_secs": 3600, "hosts": "all"}
         req = traffic._query_request(self.cfg, self.t0, cls, None, 120)
-        reply = self.call(op="query_range", ns=self.ns, query=req["query"],
-                          start=req["start"], end=req["end"], step=req["step"])
+        # the reply as a client reads it, off the wire
+        reply = wire.loads(wire.dumps(self.call(
+            op="query_range", ns=self.ns, query=req["query"],
+            start=req["start"], end=req["end"], step=req["step"])))
         idx = np.asarray([self.row_of[(h, metric)] for h in range(HOSTS)])
         bad = reference.mismatches(
             reference.rows_by_host(reply), [f"host_{h}" for h in range(HOSTS)],

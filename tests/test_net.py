@@ -92,6 +92,118 @@ def test_wire_array_value_refuses_other_dtypes(v):
         wire.dumps({"values": v})
 
 
+def _fleet_tags(hosts=400):
+    """TSBS cpu-only labels: 10 host tags and the metric name a host, a few
+    values of each region, os and team shared across the fleet."""
+    rng = np.random.default_rng(40)
+    out = []
+    for h in range(hosts):
+        tags = {
+            b"__name__": b"cpu_usage_user", b"hostname": b"host_%d" % h,
+            b"region": b"region-%d" % rng.integers(3), b"datacenter": b"dc-%d" % rng.integers(9),
+            b"rack": b"%d" % rng.integers(100), b"os": b"Ubuntu%d.04LTS" % rng.integers(15, 17),
+            b"arch": b"x%d" % rng.choice([64, 86]), b"team": b"team-%d" % rng.integers(4),
+            b"service": b"%d" % rng.integers(20), b"service_version": b"%d" % rng.integers(2),
+            b"service_environment": b"env-%d" % rng.integers(3),
+        }
+        out.append(tuple(sorted(tags.items())))
+    return out
+
+
+TAG_TABLE_CASES = [
+    pytest.param([], id="no-series"),
+    pytest.param([((b"__name__", b"cpu"), (b"host", b"a"))], id="one-series"),
+    pytest.param([(), ((b"a", b"1"),), ((b"a", b"1"), (b"b", b"2"), (b"c", b""))],
+                 id="ragged"),
+    pytest.param([((b"x", b"x"), (b"x", b"\x00\xff")),
+                  ((b"x", "☃".encode()), ("é".encode(), b"x"))], id="repeated-non-ascii"),
+    pytest.param(_fleet_tags(), id="fleet-400x11"),
+]
+
+
+@pytest.mark.parametrize("series", TAG_TABLE_CASES)
+def test_wire_tag_table_decodes_as_the_lists_it_replaces(series):
+    table = wire.tag_table(series)
+    raw = wire.dumps(table)
+    assert raw[:1] == b"g"
+    got = wire.loads(wire.dumps({"metas": table, "n": 1}))["metas"]
+    # equal to, and of the types of, the same labels sent as lists of 'b'
+    want = wire.loads(wire.dumps([[[k, v] for k, v in tags] for tags in series]))
+    assert got == want
+    assert type(got) is list and all(type(s) is list for s in got)
+    pairs = [p for s in got for p in s]
+    assert all(type(p) is list and len(p) == 2 for p in pairs)
+    assert all(type(x) is bytes for p in pairs for x in p)
+    assert len({id(p) for p in pairs}) == len(pairs)  # no pair list is shared
+    # the distinct strings are written once
+    strings = {x for tags in series for pair in tags for x in pair}
+    assert len(raw) == 9 + sum(4 + len(x) for x in strings) + 4 * len(series) + 8 * len(pairs)
+    # a short buffer is refused, wherever it is cut
+    cuts = range(1, len(raw)) if len(raw) < 512 else (1, 3, 5, 9, len(raw) // 2, len(raw) - 1)
+    for cut in cuts:
+        with pytest.raises(ValueError):
+            wire.loads(raw[:cut])
+
+
+def test_wire_tag_table_refuses_an_index_past_its_strings():
+    raw = bytearray(wire.dumps(wire.tag_table([((b"a", b"b"),)])))
+    assert raw[-4:] == b"\x01\x00\x00\x00"  # the value's index: the second string
+    raw[-4:] = b"\x02\x00\x00\x00"
+    with pytest.raises(ValueError):
+        wire.loads(bytes(raw))
+    with pytest.raises(ValueError):
+        wire.tag_table([((b"a", b"b", b"c"),)])
+
+
+def test_wire_plain_bytes_stay_a_b_value():
+    assert wire.dumps(b"g\x00") == b"b\x02\x00\x00\x00g\x00"
+    assert wire.dumps(bytearray(b"g")) == b"b\x01\x00\x00\x00g"
+    assert wire.loads(wire.dumps([b"g", b""])) == [b"g", b""]
+
+
+def test_tag_table_memo_hits_by_identity_under_contention():
+    """Handler threads share one memo: each gets the table of the metas it
+    passed, never another set's, while the sets outnumber the entries."""
+    import sys
+    import threading
+
+    from m3_tpu.block.core import SeriesMeta
+    from m3_tpu.net import server
+
+    memo = server.TagTableMemo()
+    fleet = _fleet_tags(8)
+    sets = [[SeriesMeta(tags=tags + ((b"set", b"%d" % k),)) for tags in fleet]
+            for k in range(server._TAG_TABLE_MEMO_ENTRIES + 16)]
+    # the same tags in new objects miss: the memo keys on identity alone
+    twin = [SeriesMeta(tags=m.tags) for m in sets[0]]
+    assert memo.table(sets[0]) is memo.table(sets[0])
+    assert memo.table(twin) is not memo.table(sets[0])
+    errors = []
+
+    def worker(seed):
+        rng = np.random.default_rng(seed)
+        try:
+            for k in rng.integers(len(sets), size=300):
+                got = wire.loads(memo.table(sets[k]).encoded)
+                assert got == [[list(p) for p in m.tags] for m in sets[k]]
+        except Exception as exc:  # read by the main thread
+            errors.append(exc)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(s,)) for s in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[0]
+    assert len(memo._entries) <= server._TAG_TABLE_MEMO_ENTRIES
+
+
 def test_wire_query_roundtrip():
     q = conj(
         term(b"name", b"cpu"),

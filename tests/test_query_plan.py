@@ -181,6 +181,57 @@ def test_query_range_reply_grid_is_one_float64_array_on_the_wire(plan_db):
         server.stop()
 
 
+def test_query_range_reply_labels_are_the_plan_s_tag_table_memo(plan_db):
+    """``query_range`` over a served dbnode: a plan-served reply's labels
+    decode equal to the staged reply's; the plan's next reply takes its tag
+    table from the memo; once a segment swap rebuilds the plan, the next
+    reply misses and carries the new series' labels."""
+    from m3_tpu.net.client import RemoteNode
+    from m3_tpu.net.server import NodeServer, NodeService
+
+    def tables(memo: str) -> float:
+        fam = METRICS.collect().get("m3tpu_query_reply_tag_table_total", {"children": []})
+        return sum(c["value"] for c in fam["children"] if c["labels"].get("memo") == memo)
+
+    def ask(**kw):
+        before = {memo: tables(memo) for memo in ("hit", "miss")}
+        reply = client.query_range("ns", 'pm{job=~"app.*"}', *SPAN, **kw)
+        return reply, {memo: tables(memo) - before[memo] for memo in before}
+
+    _seed(plan_db)
+    server = NodeServer(NodeService(plan_db))
+    server.start()
+    client = RemoteNode("127.0.0.1", server.port)
+    try:
+        first, memo = ask()
+        assert first["stats"]["planMisses"] == 1 and memo == {"hit": 0, "miss": 1}
+        warm, memo = ask()
+        assert warm["stats"]["planHits"] == 1 and memo == {"hit": 1, "miss": 0}
+        staged, memo = ask(force_staged=True)
+        assert memo == {"hit": 0, "miss": 1}  # the staged path's metas are its own
+        assert len(warm["metas"]) == 24
+        assert warm["metas"] == first["metas"] == staged["metas"]
+        assert all(type(p) is list and [type(x) for x in p] == [bytes, bytes]
+                   for m in warm["metas"] for p in m)
+        # a new doc in the index block, then a flush: the segment is swapped,
+        # the plan rebuilds with new SeriesMeta, and the memo's table of the
+        # old ones is not served
+        tags = ((b"__name__", b"pm"), (b"job", b"app9"), (b"s", b"zzz"))
+        plan_db.namespaces["ns"].index.write(encode_tags_id(tags), tags, T0 + 100 * NANOS)
+        plan_db.flush("ns", T0 + 4 * HOUR)
+        rebuilt, memo = ask()
+        assert rebuilt["stats"]["planMisses"] == 1 and memo == {"hit": 0, "miss": 1}
+        staged, _ = ask(force_staged=True)
+        assert rebuilt["metas"] == staged["metas"] and len(rebuilt["metas"]) == 25
+        assert [list(p) for p in tags] in rebuilt["metas"]
+        again, memo = ask()
+        assert again["stats"]["planHits"] == 1 and memo == {"hit": 1, "miss": 0}
+        assert again["metas"] == rebuilt["metas"]
+    finally:
+        client.close()
+        server.stop()
+
+
 @pytest.mark.slow
 def test_fused_vs_staged_bitexact_across_shapes(plan_db):
     _seed(plan_db)

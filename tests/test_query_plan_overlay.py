@@ -32,6 +32,7 @@ import fleet  # noqa: E402  (benchmark/fleet.py)
 import reference  # noqa: E402
 import traffic  # noqa: E402
 
+from m3_tpu.net import wire  # noqa: E402
 from m3_tpu.utils.instrument import DEFAULT as METRICS  # noqa: E402
 
 CONFIG = "tsbs-cpu-only-400-live-1node"
@@ -130,8 +131,10 @@ class Live:
                                    self.t0, self.dt, end_tick)
 
     def send(self, req: dict, **extra) -> dict:
-        return self.call(op="query_range", ns=self.ns, query=req["query"],
-                         start=req["start"], end=req["end"], step=req["step"], **extra)
+        """The reply as a client reads it, off the wire."""
+        return wire.loads(wire.dumps(self.call(
+            op="query_range", ns=self.ns, query=req["query"],
+            start=req["start"], end=req["end"], step=req["step"], **extra)))
 
     def ask(self, cls: dict, end_tick: int | None = None, **extra) -> tuple[dict, int]:
         """One request of ``cls`` (``request``) and the cells of its reply

@@ -434,9 +434,13 @@ class MetricNameDiscipline(Checker):
     # matched-series count between 8 and the segment's bitmap width — at
     # most log2(docs) values a segment, ten at 4,064 docs
     # (m3tpu_query_plan_builds_total{cap}).
+    # "memo": whether a reply's tag table came from the server's memo,
+    # "hit" or "miss" — two constants of net/server.py
+    # (m3tpu_query_reply_tag_table_total{memo}).
     LABEL_KEYS = {"component", "op", "peer", "to", "kernel", "kind", "stage",
                   "ns", "group", "tenant", "scope", "shard", "reason",
-                  "objective", "window", "file", "encoder", "body", "cap"}
+                  "objective", "window", "file", "encoder", "body", "cap",
+                  "memo"}
 
     def check_file(self, ctx: FileContext):
         for node in ast.walk(ctx.tree):

@@ -19,6 +19,8 @@ the window's start and close. After the run it reduces the capture with
 - ``counters``: the window's growth of the commit-log, RPC byte, recv-wait,
   compile and reply-array-cell counters, and the stack sampler's overhead
   ratio at its close;
+- ``window_labelled``: the window's growth child by child of the replies'
+  tag tables by memo hit and miss and of the response bytes by op;
 - ``labelled``: set-up's seal by encoder and refusal reason
   (``m3tpu_seal_lanes_total``, ``m3tpu_seal_host_lanes_total``) and the
   admitted chunks by decode body (``m3tpu_resident_chunks_total``), child by
@@ -69,6 +71,9 @@ GAUGES = ("m3tpu_profile_overhead_ratio", "m3tpu_commitlog_queue_depth",
 # refusal reason, the admitted chunks by decode body (PR 29)
 LABELLED = ("m3tpu_seal_lanes_total", "m3tpu_seal_host_lanes_total",
             "m3tpu_resident_chunks_total")
+# read child by child as the window's growth: the replies' tag tables by
+# whether the memo held them, and the response bytes by op
+WINDOW_LABELLED = ("m3tpu_query_reply_tag_table_total", "m3tpu_rpc_response_bytes_total")
 
 
 def parse_exposition(text: str) -> dict[str, float]:
@@ -191,6 +196,8 @@ def main(argv=None) -> int:
         },
         "labelled": {key: value for key, value in sorted(m1.items())
                      if key.startswith(tuple(name + "{" for name in LABELLED))},
+        "window_labelled": {key: value - m0.get(key, 0.0) for key, value in sorted(m1.items())
+                            if key.startswith(tuple(name + "{" for name in WINDOW_LABELLED))},
         "compiles_before_window": {
             "jax_events_hook": s0["compiles"], "m3tpu_jit_compiles": s0["m3tpu_jit_compiles"]},
         "device_profile_stop": cell.profile_stat,
