@@ -10,6 +10,7 @@ ops the device can fuse.
 
 from __future__ import annotations
 
+import functools
 import re as _re
 import threading
 import time
@@ -456,31 +457,53 @@ class Engine:
 
         return vals, inner.metas, window, sub_step / NANOS, post
 
+    def _temporal(self, name: str, arg: Expr, bounds: Bounds, device_fn) -> Result:
+        """Range function ``name`` over ``arg``. The ``query.temporal``
+        stage runs from the range's grid in hand to the built Result. In
+        it, a selection over float64 samples that float32 cannot hold is
+        taken exactly on the host (``query.temporal.select``); anything
+        else is ``device_fn(values, window, step_seconds)``, whose host
+        side (float32 conversion, upload, the fused kernel's or the eager
+        operations' dispatch) is ``query.temporal.dispatch`` and whose
+        blocking read-back (the device queue ahead of it, the work, the
+        transfer) is ``query.temporal.wait``."""
+        from . import stats
+
+        vals, metas, w, step_s, post = self._range_arg(arg, bounds)
+        with stats.stage("query.temporal"):
+            if TF.exact_selection(name, vals):
+                with stats.stage("query.temporal.select"):
+                    out = TF.select_over_time(name, vals, w)
+            else:
+                with stats.stage("query.temporal.dispatch"):
+                    out = device_fn(vals, w, step_s)
+                with stats.stage("query.temporal.wait"):
+                    out = np.asarray(out)
+            return Result(post(out[:, w - 1 :]), metas)
+
     def _call(self, e: Call, bounds: Bounds) -> Result:
         name = e.func
         if name in self._TEMPORAL:
-            vals, metas, w, step_s, post = self._range_arg(e.args[0], bounds)
+            fn = self._TEMPORAL[name]
             if name in TF.FUSABLE:
                 # one VMEM-resident pallas pass on TPU (temporal_fused.py)
-                out = np.asarray(TF.temporal_apply(name, vals, w, step_s))
-            else:
-                out = np.asarray(self._TEMPORAL[name](vals, w, step_s))
-            return Result(post(out[:, w - 1 :]), metas)
+                fn = functools.partial(TF.temporal_apply, name)
+            return self._temporal(name, e.args[0], bounds, fn)
         if name == "quantile_over_time":
             q = _number(e.args[0])
-            vals, metas, w, step_s, post = self._range_arg(e.args[1], bounds)
-            out = np.asarray(T.quantile_over_time(vals, w, q))
-            return Result(post(out[:, w - 1 :]), metas)
+            return self._temporal(
+                name, e.args[1], bounds,
+                lambda v, w, s: T.quantile_over_time(v, w, q))
         if name == "predict_linear":
-            vals, metas, w, step_s, post = self._range_arg(e.args[0], bounds)
             t = _number(e.args[1])
-            out = np.asarray(T.predict_linear(vals, w, step_s, t))
-            return Result(post(out[:, w - 1 :]), metas)
+            return self._temporal(
+                name, e.args[0], bounds,
+                lambda v, w, s: T.predict_linear(v, w, s, t))
         if name == "holt_winters":
-            vals, metas, w, step_s, post = self._range_arg(e.args[0], bounds)
             sf, tf = _number(e.args[1]), _number(e.args[2])
-            out = np.asarray(T.holt_winters(vals, w, sf, tf))
-            return Result(post(out[:, w - 1 :]), metas)
+            return self._temporal(
+                name, e.args[0], bounds,
+                lambda v, w, s: T.holt_winters(v, w, sf, tf))
         if name == "label_replace":
             return self._label_replace(e, bounds)
         if name == "label_join":

